@@ -239,8 +239,7 @@ def _telemetry_drain(cfg, params, smoke: bool) -> obs.Telemetry:
     rng = np.random.default_rng(7)
     tele = obs.Telemetry()
     engine = ServeEngine(cfg, params, max_len=24, quant="q8_0",
-                         offload=OffloadEngine(interpret=True,
-                                               prefer_pallas=False),
+                         offload=OffloadEngine(prefer_pallas=False),
                          eos_id=-1, telemetry=tele)
     sched = ContinuousBatchingScheduler(engine, n_slots=2, n_frames=16)
     for _ in range(4 if smoke else 6):
@@ -259,7 +258,7 @@ def run(smoke: bool = False, trace_out: str = None,
     for name, quant, off in [
             ("dense", "none", None),
             ("q8_0+offload", "q8_0",
-             OffloadEngine(interpret=True, prefer_pallas=False))]:
+             OffloadEngine(prefer_pallas=False))]:
         rng = np.random.default_rng(0)          # same trace both variants
         variants.append(_variant(name, cfg, params, quant, off, smoke, rng))
     tele = _telemetry_drain(cfg, params, smoke)

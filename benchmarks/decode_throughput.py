@@ -68,7 +68,7 @@ def run(smoke: bool = False) -> dict:
     mel = np.random.default_rng(0).standard_normal(
         (b, frames, cfg.n_mels)).astype(np.float32)
 
-    off_engine = OffloadEngine(interpret=True, prefer_pallas=False)
+    off_engine = OffloadEngine(prefer_pallas=False)
     eng_on = ServeEngine(cfg, params, max_len=max_new + 8, quant="q8_0",
                          offload=off_engine, eos_id=-1)
     eng_off = ServeEngine(cfg, params, max_len=max_new + 8, quant="q8_0",

@@ -241,7 +241,7 @@ def run(smoke: bool = False, trace_out: str = None,
         _variant("dense", cfg, params, "none", lambda: None, smoke,
                  mesh=mesh),
         _variant("q8_0+offload", cfg, params, "q8_0",
-                 lambda: OffloadEngine(interpret=True, prefer_pallas=False),
+                 lambda: OffloadEngine(prefer_pallas=False),
                  smoke, mesh=mesh, telemetry=tele),
     ]
 

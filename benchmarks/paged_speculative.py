@@ -217,7 +217,7 @@ def run(smoke: bool = False, trace_out: str = None,
     variants = [
         _variant("dense", "none", lambda: None, smoke),
         _variant("q8_0+offload", "q8_0",
-                 lambda: OffloadEngine(interpret=True, prefer_pallas=False),
+                 lambda: OffloadEngine(prefer_pallas=False),
                  smoke, telemetry=tele),
     ]
 

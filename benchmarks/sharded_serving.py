@@ -21,9 +21,12 @@ device-local slot ranges. The gates, asserted every run (CI via
   - plan-cache separation: sharded and unsharded engines at the same
     shapes hold disjoint plan keys (the mesh signature, DESIGN.md §13)
 
-When launched with fewer than 2 visible devices the benchmark re-execs
-itself in a subprocess with the forced-host flag (jax pins the device
-count at first init — same pattern as launch/dryrun.py).
+When launched on the CPU with fewer than 2 visible devices the benchmark
+re-execs itself in a subprocess with the forced-host flag (jax pins the
+device count at first init — same pattern as launch/dryrun.py). On an
+accelerator with fewer than 2 devices it fails: a CPU result must never
+be reported under the accelerator's name, and a child process could not
+reach the chip this process already holds.
 
 Per-request latency percentiles (p50/p95/p99) come from the shared
 ``obs.metrics`` histogram in exact (track_values) mode — the one
@@ -168,6 +171,11 @@ def run(smoke: bool = False) -> dict:
     import jax
 
     if len(jax.devices()) < 2:
+        if jax.default_backend() != "cpu":
+            return {"smoke": smoke, "gate_ok": False,
+                    "error": f"sharded serving needs >=2 "
+                             f"{jax.default_backend()} devices, have "
+                             f"{len(jax.devices())}"}
         return _reexec_forced(smoke)
 
     import jax.random  # noqa: F401
@@ -186,7 +194,7 @@ def run(smoke: bool = False) -> dict:
     variants = [
         _variant("dense", cfg, params, "none", lambda: None, mesh, smoke),
         _variant("q8_0+offload", cfg, params, "q8_0",
-                 lambda: OffloadEngine(interpret=True, prefer_pallas=False),
+                 lambda: OffloadEngine(prefer_pallas=False),
                  mesh, smoke),
     ]
 
@@ -225,6 +233,8 @@ def main(argv=None) -> int:
                     help="tiny workload for the CI gate")
     args = ap.parse_args(argv)
     out = run(smoke=args.smoke)
+    if "error" in out:
+        print(f"sharded_serving: {out['error']}", file=sys.stderr)
     return 0 if out["gate_ok"] else 1
 
 

@@ -134,7 +134,7 @@ def _variant(rung: str, quant: str, tiny_cfg, tiny_params, mel,
     cfg = _ladder_cfg(rung)
     params = _echo_params(
         model_lib.init_params(jax.random.PRNGKey(1), cfg), alpha)
-    off = (OffloadEngine(interpret=True) if quant == "q8_0" else None)
+    off = (OffloadEngine() if quant == "q8_0" else None)
     tele = obs.Telemetry()
     v = ServeEngine(cfg, params, max_len=max_new + k + 1, quant=quant,
                     offload=off, eos_id=-1, telemetry=tele)

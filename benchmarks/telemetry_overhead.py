@@ -131,12 +131,10 @@ def run(smoke: bool = False, trace_out: str = None,
     tele = obs.Telemetry()
     engines = {
         "off": ServeEngine(cfg, params, max_len=hi + 8, quant="q8_0",
-                           offload=OffloadEngine(interpret=True,
-                                                 prefer_pallas=False),
+                           offload=OffloadEngine(prefer_pallas=False),
                            eos_id=-1),
         "on": ServeEngine(cfg, params, max_len=hi + 8, quant="q8_0",
-                          offload=OffloadEngine(interpret=True,
-                                                prefer_pallas=False),
+                          offload=OffloadEngine(prefer_pallas=False),
                           eos_id=-1, telemetry=tele),
     }
 

@@ -4,7 +4,8 @@
 
 1. Quantize a weight matrix to GGML Q8_0 (blocks of 32 + fp16 scale).
 2. Run the mixed-execution dot product: burst-aligned main segment on the
-   Pallas TPU kernel (interpret mode on CPU), residual on the host path.
+   Pallas TPU kernel (native on a TPU, interpret mode on CPU), residual on
+   the host path.
 3. Ask the offload dispatcher whether the invocation fits the local-memory
    budget (the paper's LMM-coverage test) and account PDP.
 """
@@ -34,14 +35,13 @@ def main():
           f"{wq.nbytes()} bytes vs {w.size*2} fp16 bytes")
 
     # 2) mixed execution: aligned main on the kernel, residual on host
-    y = ops.matmul(x, wq, burst=128, prefer_pallas=True, interpret=True)
+    y = ops.matmul(x, wq, burst=128, prefer_pallas=True)
     y_ref = x @ w.T
     print(f"mixed-exec matmul: out {y.shape}, max|err| vs dense "
           f"{float(jnp.max(jnp.abs(y - y_ref))):.2e}")
 
     # 3) offload dispatch + PDP accounting (paper Eq. 1-2)
-    eng = OffloadEngine(vmem_budget_kb=32, burst=128, prefer_pallas=True,
-                        interpret=True)
+    eng = OffloadEngine(vmem_budget_kb=32, burst=128, prefer_pallas=True)
     y2 = eng.linear(x, wq, name="ffn.down")
     print(f"dispatcher: offloaded={eng.stats.offloaded_calls} "
           f"fallback={eng.stats.fallback_calls} "

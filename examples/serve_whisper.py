@@ -9,8 +9,9 @@ targets, on the TPU-native stack.
 Flow per the paper's Fig 1: mel frames -> encoder (once per utterance) ->
 per-layer cross-K/V projection (dec.cross.kv) -> autoregressive greedy
 decode against the self-attention KV cache. Every GEMM routes through the
-offload dispatcher: main segments on the (interpret-mode) Pallas kernels,
-residuals on the host path, with coverage-based fallback.
+offload dispatcher: main segments on the native Pallas kernels on a TPU
+(the same math on xla_ref elsewhere), residuals on the host path, with
+coverage-based fallback.
 
 ``--stream`` serves the same utterances through the continuous-batching
 scheduler (DESIGN.md §11) instead: requests are submitted STAGGERED —
@@ -28,6 +29,7 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.core import energy
 from repro.core.offload import OffloadEngine
+from repro.launch import compile_cache
 from repro.models import model as model_lib
 from repro.serve.engine import ServeEngine
 from repro.tuning import Autotuner
@@ -48,6 +50,7 @@ def main(argv=None):
                     help="slot-pool width for --stream")
     args = ap.parse_args(argv)
 
+    compile_cache.enable()
     cfg = get_config("whisper-tiny")
     print(f"whisper-tiny: {cfg.n_params()/1e6:.1f}M params, "
           f"{cfg.num_encoder_layers}+{cfg.num_layers} layers, "
@@ -63,9 +66,8 @@ def main(argv=None):
     tuner = Autotuner(cache_path=os.path.join("experiments", "tuning",
                                               "whisper_tiny.json"),
                       mode="analytic")
-    offload = OffloadEngine(vmem_budget_kb=8 * 1024, burst=128,
-                            prefer_pallas=False,  # XLA path of same math
-                            tuner=tuner)
+    # platform defaults: native Pallas kernels on a TPU, xla_ref elsewhere
+    offload = OffloadEngine(vmem_budget_kb=8 * 1024, burst=128, tuner=tuner)
     engine = ServeEngine(cfg, params, max_len=args.max_new + 8,
                          quant=quant, offload=offload, eos_id=-1)
 

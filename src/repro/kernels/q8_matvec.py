@@ -9,7 +9,10 @@ v5e ridge. The kernel therefore optimizes HBM bytes, not MXU utilization:
 * weights stream as int8 + scales (the Q8_0 2x cut — the paper's point),
 * the activation tile is loaded once and kept VMEM-resident across the whole
   N sweep (grid iterates N only; K is a single block),
-* the batch dim pads to the 8-sublane minimum in the ops wrapper.
+* the batch dim pads to the 8-sublane minimum in the backend wrapper,
+* per-block scales spread over their columns on the MXU
+  (``q8_matmul.expand_scales``) — no sub-lane reshape, which Mosaic
+  cannot lay out.
 """
 from __future__ import annotations
 
@@ -20,9 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 from repro.core.qformats import QBLOCK
+from repro.kernels.q8_matmul import expand_scales, scales_band_bytes
 
 DEFAULT_BLOCK_N = 512
 
@@ -32,21 +34,20 @@ def vmem_claim_bytes(b: int = 8, k: int = 384,
                      x_bytes: int = 2) -> int:
     """VMEM working set of one grid step (autotuner input, DESIGN.md §9.1):
     the whole (B, K) activation stays resident across the N sweep; the int8
-    payload + scales tiles double-buffer; the out tile is written per step."""
+    payload + scales tiles double-buffer; the f32 dequantized tile lives
+    for one step; the out tile is written per step."""
     db = 2
     return (b * k * x_bytes                          # resident activation
             + db * (block_n * k                      # int8 payload tile
-                    + block_n * (k // QBLOCK) * 4)   # scales tile
+                    + scales_band_bytes(block_n, k))  # scales tile
+            + block_n * k * 4                        # dequantized tile
             + b * block_n * 4)                       # out tile
 
 
 def _q8_matvec_kernel(x_ref, q_ref, s_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)                  # (B, K) resident
     q = q_ref[...]                                      # (bn, K) int8
-    s = s_ref[...]                                      # (bn, K//32)
-    bn, k = q.shape
-    w = q.astype(jnp.float32).reshape(bn, k // QBLOCK, QBLOCK) * s[..., None]
-    w = w.reshape(bn, k)
+    w = q.astype(jnp.float32) * expand_scales(s_ref[...], 0, q.shape[1])
     o_ref[...] = jax.lax.dot_general(
         x, w, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -76,6 +77,6 @@ def q8_matvec(x: jax.Array, qs: jax.Array, scales: jax.Array, *,
         out_specs=pl.BlockSpec((b, block_n), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(x, qs, scales)

@@ -11,28 +11,16 @@ Mesh shapes (assignment):
 from __future__ import annotations
 
 import jax
-
-try:                                   # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                    # older toolchains: no explicit axis
-    AxisType = None                    # types; make_mesh defaults are fine
+from jax.sharding import AbstractMesh, AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def abstract_mesh(shape, axes):
-    """AbstractMesh across jax versions: new-style (sizes, names) signature
-    vs the old single shape_tuple of (name, size) pairs."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """A device-free ``AbstractMesh`` of the given sizes and axis names."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

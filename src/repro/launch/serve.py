@@ -36,6 +36,7 @@ import numpy as np
 from repro import obs
 from repro.configs.registry import ALL_ARCHS, get_config, get_smoke_config
 from repro.core.offload import OffloadEngine
+from repro.launch import compile_cache
 from repro.models import model as model_lib
 from repro.serve.engine import ServeEngine
 
@@ -80,11 +81,13 @@ def main(argv=None):
     if args.speculative and args.mesh:
         ap.error("--speculative over a sharded mesh is not supported yet")
 
+    compile_cache.enable()
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     params = model_lib.init_params(jax.random.PRNGKey(args.seed), cfg,
                                    max_positions=512)
-    offload = OffloadEngine(interpret=True, prefer_pallas=False) \
-        if args.offload else None
+    # platform defaults: main segments resolve to the native Pallas
+    # kernels on a TPU and to xla_ref elsewhere (DESIGN.md §12.2)
+    offload = OffloadEngine() if args.offload else None
     mesh = None
     if args.mesh:
         from repro.launch.mesh import make_serve_mesh
@@ -98,9 +101,11 @@ def main(argv=None):
                          telemetry=telemetry)
 
     rng = np.random.default_rng(args.seed)
+    # --full serves the published 30 s encoder window
+    n_frames = cfg.encoder_ctx if args.full else 64
     if cfg.family == "audio":
         mel = rng.standard_normal(
-            (args.requests, 64, cfg.n_mels)).astype(np.float32)
+            (args.requests, n_frames, cfg.n_mels)).astype(np.float32)
         payloads = [mel[i:i + 1] for i in range(args.requests)]
     else:
         prompts = rng.integers(
@@ -110,7 +115,7 @@ def main(argv=None):
     attribution = None
     if args.continuous:
         sched = engine.scheduler(n_slots=args.slots,
-                                 n_frames=64 if cfg.family == "audio"
+                                 n_frames=n_frames if cfg.family == "audio"
                                  else None)
         rids = [sched.submit(p, max_new=args.max_new) for p in payloads]
         streamed = {r: 0 for r in rids}

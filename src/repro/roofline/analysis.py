@@ -205,9 +205,7 @@ def analyze_compiled(compiled, *, arch: str, shape_cfg: ShapeConfig,
     bodies once) is recorded as a cross-check."""
     from repro.roofline import hlo_cost
 
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # older jax wraps it in a list
-        cost = cost[0] if cost else {}
+    cost = compiled.cost_analysis() or {}
     xla_flops = float(cost.get("flops", 0.0))
     xla_bytes = float(cost.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()

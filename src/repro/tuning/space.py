@@ -4,7 +4,13 @@ The paper's design space is (LMM size) x (burst length); ours is
 (vmem_budget) x (block_m, block_n, block_k). A candidate is admissible iff
 
   * every block divides its dimension exactly (the kernels refuse partial
-    tiles — ragged sizes are the mixed_exec residual's job, DESIGN.md §5),
+    tiles — ragged sizes are the mixed_exec residual's job, DESIGN.md §5);
+    N is first padded up to a lane multiple (``lane_padded``), as the
+    Pallas backend pads the weight rows before dispatch,
+  * every block is one the TPU compiler accepts (DESIGN.md §6.3): a lane
+    dimension (block_n, block_k) is a multiple of 128 or the whole
+    dimension, a sublane dimension (block_m) a multiple of 8 or the whole
+    dimension,
   * block_k holds whole Q8_0 blocks on the quantized paths (burst rule),
   * the kernel's ``vmem_claim_bytes`` fits the budget (the 32KB-LMM analog).
 
@@ -16,7 +22,7 @@ VMEM claim. ``budget_grid()`` produces that sweep.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable, Dict, List
+from typing import Callable, Dict, List, Tuple
 
 from repro.core.qformats import QBLOCK
 
@@ -24,17 +30,19 @@ from repro.core.qformats import QBLOCK
 # are rejected well before this by the sweep's budgets.
 VMEM_FULL_BYTES = 16 * 2**20
 
-# Caps/floors on block sizes. The space is *every* divisor of the dimension
-# inside [floor, cap] (plus the whole dimension as a fallback), not just
-# powers of two — Whisper's 1500-frame encoder pads to 1504 = 2^5 x 47,
-# whose best M tiles (94, 188) are not MXU-aligned; the cost model charges
-# them the MXU padding tax instead of excluding them.
-BLOCK_M_FLOOR, BLOCK_M_CAP = 8, 256      # sublane multiple preferred
-BLOCK_N_FLOOR, BLOCK_N_CAP = 128, 1024   # lane multiple preferred
-BLOCK_K_FLOOR, BLOCK_K_CAP = 32, 1024    # burst-length analog
+# TPU vreg tiling: the last (lane) axis of a block comes in 128s, the
+# second-to-last (sublane) axis in 8s, unless the block spans the axis.
+LANE, SUBLANE = 128, 8
+
+# Caps on block sizes. The space is every tile-legal divisor of the
+# dimension up to the cap, not just powers of two — Whisper's 1500-frame
+# encoder pads to 1504 = 2^5 x 47, whose legal M tiles are 8, 16 and 32.
+BLOCK_M_CAP = 256
+BLOCK_N_CAP = 1024
+BLOCK_K_CAP = 1024                       # burst-length analog
 
 # Canonical power-of-two burst axis for sweep grids (benchmarks/tune_sweep).
-BLOCK_K_CANDIDATES = (32, 64, 128, 256, 512, 1024)
+BLOCK_K_CANDIDATES = (128, 256, 512, 1024)
 
 KERNELS = ("q8_matmul", "q8_matvec", "bf16_matmul")
 
@@ -55,12 +63,22 @@ class TileCandidate:
                 "block_k": self.block_k}
 
 
-def _divisors(dim: int, floor: int, cap: int, mult: int = 1) -> List[int]:
-    out = [d for d in range(floor, min(dim, cap) + 1)
-           if dim % d == 0 and d % mult == 0]
-    if not out and dim % mult == 0:
-        out = [dim]          # small dim: single whole-dim block
-    return out
+def round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def lane_padded(n: int) -> int:
+    """N after the Pallas backend pads weight rows to a lane multiple."""
+    return round_up(n, LANE)
+
+
+def legal_tiles(dim: int, cap: int, unit: int) -> List[int]:
+    """Block sizes along one axis that tile ``dim`` exactly and that the
+    TPU compiler accepts, largest first: the multiples of ``unit`` (LANE
+    or SUBLANE) up to ``cap`` that divide ``dim``, else the whole axis."""
+    out = [d for d in range(min(dim, cap) // unit * unit, 0, -unit)
+           if dim % d == 0]
+    return out or [dim]
 
 
 def _claim_fn(kernel: str) -> Callable[..., int]:
@@ -71,9 +89,13 @@ def _claim_fn(kernel: str) -> Callable[..., int]:
     from repro.kernels.bf16_matmul import vmem_claim_bytes as _bf16_claim
     from repro.kernels.q8_matmul import vmem_claim_bytes as _q8mm_claim
     from repro.kernels.q8_matvec import vmem_claim_bytes as _q8mv_claim
+
+    def bf16_claim(*, k: int = 0, **tiles) -> int:
+        return _bf16_claim(**tiles)     # no K-resident state: k is unused
+
     return {"q8_matmul": _q8mm_claim,
             "q8_matvec": _q8mv_claim,
-            "bf16_matmul": _bf16_claim}[kernel]
+            "bf16_matmul": bf16_claim}[kernel]
 
 
 def enumerate_candidates(kernel: str, m: int, n: int, k: int, *,
@@ -86,58 +108,54 @@ def enumerate_candidates(kernel: str, m: int, n: int, k: int, *,
     """
     if kernel not in KERNELS:
         raise ValueError(f"unknown kernel {kernel!r}; one of {KERNELS}")
+    if kernel.startswith("q8") and k % QBLOCK:
+        return []
     claim = _claim_fn(kernel)
-    kmult = QBLOCK if kernel.startswith("q8") else 1
+    bns = legal_tiles(lane_padded(n), BLOCK_N_CAP, LANE)
     out: List[TileCandidate] = []
     if kernel == "q8_matvec":
         # the matvec keeps the whole (B, K) activation resident: only the
         # N streaming granularity is tunable; K is a single block.
-        if k % QBLOCK:
-            return []
-        for bn in sorted(_divisors(n, BLOCK_N_FLOOR, BLOCK_N_CAP),
-                         reverse=True):
+        for bn in bns:
             v = claim(b=m, k=k, block_n=bn, x_bytes=x_bytes)
             if v <= vmem_budget_bytes:
                 out.append(TileCandidate(kernel, m, bn, k, v))
         return out
-    for bk in sorted(_divisors(k, BLOCK_K_FLOOR, BLOCK_K_CAP, kmult),
-                     reverse=True):
-        for bn in sorted(_divisors(n, BLOCK_N_FLOOR, BLOCK_N_CAP),
-                         reverse=True):
-            for bm in sorted(_divisors(m, BLOCK_M_FLOOR, BLOCK_M_CAP),
-                             reverse=True):
-                v = claim(block_m=bm, block_n=bn, block_k=bk, x_bytes=x_bytes)
+    for bk in legal_tiles(k, BLOCK_K_CAP, LANE):
+        for bn in bns:
+            for bm in legal_tiles(m, BLOCK_M_CAP, SUBLANE):
+                v = claim(block_m=bm, block_n=bn, block_k=bk, k=k,
+                          x_bytes=x_bytes)
                 if v <= vmem_budget_bytes:
                     out.append(TileCandidate(kernel, bm, bn, bk, v))
     return out
 
 
-def _largest_tile(dim: int, cap: int, mult: int = 1) -> int:
-    """Largest t <= cap with t % mult == 0 and dim % t == 0 (the same
-    fallback rule ``backends/pallas_tpu.py`` applies untuned)."""
-    t = min(cap, dim)
-    while t > 1 and (dim % t or (mult > 1 and t % mult)):
-        t -= mult if mult > 1 and t % mult == 0 else 1
-    return max(t, 1)
+def default_tiles(kernel: str, m: int, n: int, k: int,
+                  block_k: int = 256) -> Tuple[int, int, int]:
+    """The (block_m, block_n, block_k) dispatch uses with no tuned tiling:
+    the largest legal tiles under fixed caps (block_m 128, block_n 512 on
+    the matvec and 256 elsewhere, block_k ``block_k``; the matvec takes K
+    whole). ``m`` is the sublane-padded row count."""
+    if kernel == "q8_matvec":
+        return m, legal_tiles(lane_padded(n), 512, LANE)[0], k
+    return (legal_tiles(m, 128, SUBLANE)[0],
+            legal_tiles(lane_padded(n), 256, LANE)[0],
+            legal_tiles(k, block_k, LANE)[0])
 
 
 def default_candidate(kernel: str, m: int, n: int, k: int, *,
                       x_bytes: int = 2) -> TileCandidate:
-    """The tiling dispatch falls back to with no tuner attached — the
-    hard-coded caps of ``backends/pallas_tpu.py`` expressed as a
-    ``TileCandidate`` so benchmarks (tune_sweep's baseline column) and
-    replay features (DESIGN.md §14.1) can price the untuned path with the
-    same machinery as tuned ones."""
+    """``default_tiles`` as a ``TileCandidate``, so benchmarks
+    (tune_sweep's baseline column) and replay features (DESIGN.md §14.1)
+    can price the untuned path with the same machinery as tuned ones."""
     claim = _claim_fn(kernel)
+    bm, bn, bk = default_tiles(kernel, m, n, k)
     if kernel == "q8_matvec":
-        bn = _largest_tile(n, 512)
         return TileCandidate(kernel, m, bn, k,
                              claim(b=m, k=k, block_n=bn, x_bytes=x_bytes))
-    bm = _largest_tile(m, 128)
-    bn = _largest_tile(n, 256)
-    bk = _largest_tile(k, 256, mult=QBLOCK if kernel.startswith("q8") else 1)
     return TileCandidate(kernel, bm, bn, bk,
-                         claim(block_m=bm, block_n=bn, block_k=bk,
+                         claim(block_m=bm, block_n=bn, block_k=bk, k=k,
                                x_bytes=x_bytes))
 
 

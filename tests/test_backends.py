@@ -327,3 +327,22 @@ def test_platform_probe_cached(monkeypatch):
     assert plat.on_tpu() and not plat.default_interpret()
     plat.reset_probe_cache()
     assert plat.backend_platform() == jax.default_backend()
+
+
+def test_pallas_refuses_interpret_on_tpu():
+    """On a TPU, interpret=True would run the kernels as XLA emulation
+    while the ledger still says pallas_tpu: the backend refuses it, and an
+    unset flag resolves to native execution."""
+    from repro.backends import PallasTPUBackend
+    from repro.backends import platform as plat
+    be = PallasTPUBackend()
+    plat._PROBE["platform"] = "tpu"
+    try:
+        with pytest.raises(ValueError, match="interpret=True"):
+            be.build(_req(interpret=True))
+        assert be.cost_hints(_req())["interpret"] is False
+    finally:
+        plat.reset_probe_cache()
+    if jax.default_backend() != "tpu":
+        # off the chip, interpret mode is how the kernels are tested
+        assert be.cost_hints(_req(interpret=True))["interpret"] is True

@@ -69,9 +69,14 @@ def test_vmem_claim_model():
     assert vmem_claim_bytes(128, 256, 512) > base
     db_x = 2 * 128 * 256 * 2
     db_q = 2 * 256 * 256
-    db_s = 2 * 256 * 8 * 4
+    db_s = 2 * 256 * 128 * 4          # (256, 8) f32 band, lanes pad to 128
+    deq = 256 * 256 * 4               # f32 dequantized tile
     acc = 128 * 256 * 4 * 2
-    assert base == db_x + db_q + db_s + acc
+    assert base == db_x + db_q + db_s + deq + acc
+    # the resident scales band grows with the full contraction, one
+    # 128-lane tile per 128 Q8_0 blocks
+    assert vmem_claim_bytes(128, 256, 256, k=4096) == base
+    assert vmem_claim_bytes(128, 256, 256, k=8192) == base + db_s
 
 
 # ---------------------------------------------------------------------------

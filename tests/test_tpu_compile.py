@@ -1,0 +1,162 @@
+"""Compile rehearsals for a TPU v5e with no chip attached (DESIGN.md §6.3).
+
+The TPU compiler is installed with jax; it compiles for a described
+``v5e:2x2`` topology. These tests compile the main-path kernels at
+Whisper-tiny, -base and -small widths — every weight GEMM of the model,
+the tied vocab readout included — with the tilings the offload engine's
+plan and the untuned defaults resolve, and the first candidates the
+autotuner's space emits; and whisper-tiny's served prefill and slot step
+with the native kernels. Nothing runs: a pass says the chip's compiler
+accepts the program, not that its results are right (the interpret-mode
+parity tests say that).
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library at a time, and under pytest-xdist
+every worker imports this file."""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.backends.pallas_tpu import bf16_main, q8_main
+from repro.configs.registry import get_config
+from repro.core.offload import OffloadEngine
+from repro.core.qformats import QBLOCK, QTensor
+from repro.kernels.bf16_matmul import bf16_matmul
+from repro.kernels.q8_matmul import q8_matmul
+from repro.kernels.q8_matvec import q8_matvec
+from repro.tuning import enumerate_candidates
+
+ARCHS = ("whisper-tiny", "whisper-base", "whisper-small")
+# (kernel the plan must resolve, activation rows M, quantized weights):
+# decode batches pad to 8 and 16 rows; 1504 is the 1500-frame encoder
+VARIANTS = (("q8_matvec", 8, True), ("q8_matvec", 16, True),
+            ("q8_matmul", 1504, True), ("bf16_matmul", 1504, False))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _weight_shapes(cfg):
+    """(N, K) of every weight GEMM: the (d, d) attention projections, FFN
+    up and down, the tied vocab readout, and the fused qkv / cross-K/V
+    widths of whisper.cpp's graph (``coverage.enumerate_whisper``)."""
+    d, f = cfg.d_model, cfg.d_ff
+    return ((3 * d, d), (d, d), (2 * d, d), (f, d), (d, f),
+            (cfg.padded_vocab, d))
+
+
+def _q8_operands(m, n, k):
+    return ((m, k), jnp.bfloat16), ((n, k // QBLOCK, QBLOCK), jnp.int8), \
+        ((n, k // QBLOCK), jnp.float32)
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+@pytest.mark.parametrize("kernel,m,quantized", VARIANTS)
+def test_main_segments_compile(one_chip, no_persistent_cache, arch, kernel,
+                               m, quantized):
+    cfg = get_config(arch)
+    eng = OffloadEngine()
+    for n, k in _weight_shapes(cfg):
+        e = eng.plan_entry(m, k, n, quantized=quantized, name=f"{n}x{k}")
+        assert e.kernel == kernel and e.k_main
+        kw = dict(interpret=False, block_k=256, tiling=e.tiling)
+        if quantized:
+            _compile(one_chip,
+                     lambda x, q, s: q8_main(x, QTensor(q, s), **kw),
+                     *_q8_operands(m, n, e.k_main))
+        else:
+            _compile(one_chip, functools.partial(bf16_main, **kw),
+                     ((m, e.k_main), jnp.bfloat16),
+                     ((n, e.k_main), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("kernel", ["q8_matvec", "q8_matmul", "bf16_matmul"])
+def test_tuner_candidates_compile(one_chip, no_persistent_cache, kernel):
+    """The first candidates of the autotuner's space for whisper-tiny's
+    FFN up projection (in the order the tuner ranks ties)."""
+    m = 8 if kernel == "q8_matvec" else 1504
+    n, k = 1536, 384
+    cands = enumerate_candidates(kernel, m, n, k)[:3]
+    assert cands
+    for c in cands:
+        kw = dict(c.as_kwargs(), interpret=False)
+        if kernel == "bf16_matmul":
+            _compile(one_chip, functools.partial(bf16_matmul, **kw),
+                     ((m, k), jnp.bfloat16), ((n, k), jnp.bfloat16))
+            continue
+        fn = q8_matvec if kernel == "q8_matvec" else q8_matmul
+        x, q, s = _q8_operands(m, n, k)
+        _compile(one_chip, functools.partial(fn, **kw),
+                 x, ((n, k), jnp.int8), s)
+
+
+def test_whisper_tiny_served_programs_compile(one_chip, no_persistent_cache):
+    """whisper-tiny's batch-1 prefill at 1500 frames and its 4-slot decode
+    step, traced as on the chip (main segments on native pallas_tpu)."""
+    from repro.backends import platform
+    from repro.models import model as model_lib
+    from repro.serve.engine import ServeEngine
+
+    cfg = get_config("whisper-tiny")
+    params = model_lib.init_params(jax.random.PRNGKey(0), cfg, 448)
+    eng = ServeEngine(cfg, params, max_len=24, quant="q8_0",
+                      offload=OffloadEngine(), eos_id=None)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    p = on_chip(eng._serve_params)
+    mel = (cfg.encoder_ctx, cfg.n_mels)
+    state = on_chip(jax.eval_shape(
+        lambda pp, mm: eng._prefill_fn(pp, mm)[1], p,
+        jax.ShapeDtypeStruct((4, *mel), jnp.float32)))
+    platform._PROBE["platform"] = "tpu"      # route as the chip would
+    try:
+        for fn, args in (
+                (eng._prefill_jit,
+                 (p, jax.ShapeDtypeStruct((1, *mel), jnp.float32,
+                                          sharding=one_chip))),
+                (eng._step_jit,
+                 (p, *on_chip((jax.ShapeDtypeStruct((4, 1), jnp.int32),
+                               jax.ShapeDtypeStruct((4,), bool))), state))):
+            compiled = fn.lower(*args).compile()
+            assert compiled.memory_analysis() is not None
+            assert "tpu_custom_call" in compiled.as_text()
+    finally:
+        platform.reset_probe_cache()

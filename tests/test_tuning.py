@@ -7,7 +7,7 @@ import pytest
 
 from repro.core.offload import OffloadEngine
 from repro.core.mixed_exec import select_burst
-from repro.core.qformats import QBLOCK, quantize_q8_0
+from repro.core.qformats import QBLOCK, dequantize_q8_0, quantize_q8_0
 from repro.tuning import (
     Autotuner, TuningCache, TuningKey, TuningRecord, analytic_cost,
     enumerate_candidates, kernel_for, padded_m)
@@ -215,9 +215,13 @@ def test_tuned_parity_bf16_and_q8():
     np.testing.assert_allclose(np.asarray(y), np.asarray(x @ w.T),
                                rtol=2e-2, atol=2e-2)
     xq = jax.random.normal(jax.random.PRNGKey(4), (64, 128))
-    wq_f = jax.random.normal(jax.random.PRNGKey(5), (96, 128)) * 0.1
-    yq = eng.linear(xq, quantize_q8_0(wq_f), name="quant")
-    np.testing.assert_allclose(np.asarray(yq), np.asarray(xq @ wq_f.T),
+    wq = quantize_q8_0(
+        jax.random.normal(jax.random.PRNGKey(5), (96, 128)) * 0.1)
+    yq = eng.linear(xq, wq, name="quant")
+    # held to its own math: Q8_0's quantisation error is the format's,
+    # not the tiling's, so the reference uses the dequantized weight
+    np.testing.assert_allclose(np.asarray(yq),
+                               np.asarray(xq @ dequantize_q8_0(wq).T),
                                rtol=2e-2, atol=2e-2)
 
 
@@ -246,3 +250,30 @@ def test_whisper_warm_tuning_populates_cache():
     assert n > 0
     assert len(tun.cache) > 0
     assert warm_tuning(cfg, OffloadEngine()) == 0   # tunerless engine: no-op
+
+
+@pytest.mark.parametrize("arch", ["whisper-tiny", "whisper-base",
+                                  "whisper-small"])
+def test_space_emits_only_chip_legal_tiles(arch):
+    """Every tiling the space or the untuned default offers for a Whisper
+    weight GEMM is one the TPU compiler accepts: lane dims (block_n,
+    block_k) are multiples of 128 or the whole dim, the sublane dim
+    (block_m) a multiple of 8 or the whole dim — N after lane padding,
+    so the 51872-wide vocab readout gets 128-multiple tiles too."""
+    from repro.configs.registry import get_config
+    from repro.tuning import default_candidate
+    from repro.tuning.space import LANE, SUBLANE, lane_padded
+    cfg = get_config(arch)
+    d, f = cfg.d_model, cfg.d_ff
+    for n, k in ((3 * d, d), (d, d), (2 * d, d), (f, d), (d, f),
+                 (cfg.padded_vocab, d)):
+        for kernel, m in (("q8_matvec", 8), ("q8_matvec", 16),
+                          ("q8_matmul", 1504), ("bf16_matmul", 1504)):
+            cands = enumerate_candidates(kernel, m, n, k)
+            assert cands
+            for c in cands + [default_candidate(kernel, m, n, k)]:
+                assert c.block_n % LANE == 0
+                assert lane_padded(n) % c.block_n == 0
+                assert c.block_k % LANE == 0 or c.block_k == k
+                assert k % c.block_k == 0
+                assert c.block_m % SUBLANE == 0 and m % c.block_m == 0

@@ -11,12 +11,13 @@ from repro.core.qformats import QBLOCK
 from repro.tuning import (
     Autotuner, BackendCoefficients, CalibratedCoefficients, TuningCache,
     TuningKey, TuningRecord, enumerate_candidates)
-from repro.tuning.space import _claim_fn
+from repro.tuning.space import LANE, SUBLANE, _claim_fn, lane_padded
 
 # Dimension pools: mixes MXU-aligned sizes, Whisper's awkward 1504 =
-# 2^5 x 47 padding, and sub-tile smalls — all within QBLOCK rules on K.
+# 2^5 x 47 padding, the tied vocab readout's 51872 (not a lane multiple),
+# and sub-tile smalls — all within QBLOCK rules on K.
 MS = (8, 24, 94, 128, 752, 1504)
-NS = (128, 256, 384, 1152, 1536)
+NS = (128, 256, 384, 1152, 1536, 51872)
 KS = (64, 384, 1536, 3072)
 KERNS = ("q8_matmul", "q8_matvec", "bf16_matmul")
 SRC = ("analytic", "calibrated", "measured")
@@ -26,15 +27,18 @@ SRC = ("analytic", "calibrated", "measured")
        st.sampled_from(KS), st.integers(2**13, 2**22))
 @settings(max_examples=40, deadline=None)
 def test_every_candidate_admissible(kernel, m, n, k, budget):
-    """Every enumerated tiling divides its dims, honors the Q8_0 block
-    rule, and its recorded VMEM claim both fits the budget and equals
-    the kernel's own vmem_claim_bytes recomputation."""
+    """Every enumerated tiling divides its dims (N after lane padding),
+    is a block the TPU compiler accepts, honors the Q8_0 block rule, and
+    its recorded VMEM claim both fits the budget and equals the kernel's
+    own vmem_claim_bytes recomputation."""
     claim = _claim_fn(kernel)
     for c in enumerate_candidates(kernel, m, n, k,
                                   vmem_budget_bytes=budget):
         assert m % c.block_m == 0
-        assert n % c.block_n == 0
+        assert lane_padded(n) % c.block_n == 0 and c.block_n % LANE == 0
         assert k % c.block_k == 0
+        assert c.block_k % LANE == 0 or c.block_k == k
+        assert c.block_m % SUBLANE == 0 or c.block_m == m
         if kernel.startswith("q8"):
             assert c.block_k % QBLOCK == 0
         assert c.vmem_bytes <= budget
@@ -43,7 +47,7 @@ def test_every_candidate_admissible(kernel, m, n, k, budget):
         else:
             assert c.vmem_bytes == claim(block_m=c.block_m,
                                          block_n=c.block_n,
-                                         block_k=c.block_k)
+                                         block_k=c.block_k, k=k)
 
 
 @given(st.sampled_from(KERNS), st.sampled_from(MS), st.sampled_from(NS),
@@ -129,7 +133,7 @@ def test_admissibility_example():
                                   vmem_budget_bytes=2**20):
         assert c.vmem_bytes <= 2**20
         assert c.vmem_bytes == claim(block_m=c.block_m, block_n=c.block_n,
-                                     block_k=c.block_k)
+                                     block_k=c.block_k, k=1536)
 
 
 def test_pick_in_space_example():
