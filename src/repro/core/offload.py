@@ -124,8 +124,8 @@ class OffloadLedger:
         "main" everywhere else."""
         if plan is None or times <= 0:
             return
-        for entry in plan:
-            self.account(entry, times, role=role)
+        for entry, n in plan.counts().items():
+            self.account(entry, times * n, role=role)
         self.commits += 1
 
 
@@ -152,6 +152,8 @@ class OffloadEngine:
     # unsharded ones and the ledger can attribute work per device
     mesh_sig: Optional[tuple] = None
     _recording: Optional[DispatchPlan] = field(default=None, repr=False)
+    # executions per program run of each linear traced now (``repeat``)
+    _repeat: int = field(default=1, repr=False)
 
     @property
     def stats(self) -> OffloadStats:
@@ -185,6 +187,19 @@ class OffloadEngine:
         finally:
             self._recording = prev
 
+    @contextmanager
+    def repeat(self, n: int):
+        """While active, each ``linear`` traced runs ``n`` times per
+        execution of the program: the body of a ``lax.scan``, or a
+        ``vmap``, over ``n`` stacked layers is traced once but executes
+        per layer, so it records (or accounts) ``n`` entries, not one
+        (DESIGN.md §10.2). Nests multiplicatively."""
+        prev, self._repeat = self._repeat, self._repeat * n
+        try:
+            yield
+        finally:
+            self._repeat = prev
+
     # -- execution --------------------------------------------------------
     def linear(self, x: jax.Array, w, name: str = "linear") -> jax.Array:
         """y = x @ W^T, routed per the trace-time plan entry for this
@@ -198,17 +213,19 @@ class OffloadEngine:
         entry = self.plan_entry(m, k, n, quantized=isinstance(w, QTensor),
                                 name=name)
         y = self.execute(x, w, entry)
+        n = self._repeat
         if self._recording is not None:
-            self._recording.add(entry)
+            for _ in range(n):
+                self._recording.add(entry)
         elif not isinstance(x, jax.core.Tracer):
-            self.ledger.account(entry)
+            self.ledger.account(entry, times=n)
             # eager accounts land outside any ledger span; claiming them
             # on the active telemetry keeps the DESIGN.md §16.2 exact
             # span-FLOP == ledger-delta invariant under mixed usage
             from repro import obs
             tele = obs.active()
             if tele is not None and tele._ledger is self.ledger:
-                tele.claim_eager(entry)
+                tele.claim_eager(entry, times=n)
         return y
 
     def execute(self, x: jax.Array, w, entry: PlanEntry) -> jax.Array:

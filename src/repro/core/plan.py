@@ -150,9 +150,22 @@ class DispatchPlan:
     the ledger multiplies by the run count (DESIGN.md §10.2)."""
     key: Hashable = None
     entries: List[PlanEntry] = field(default_factory=list)
+    _counts: Optional[Dict[PlanEntry, int]] = field(
+        default=None, repr=False, compare=False)
 
     def add(self, entry: PlanEntry) -> None:
         self.entries.append(entry)
+        self._counts = None
+
+    def counts(self) -> Dict[PlanEntry, int]:
+        """Each distinct entry and how often it runs per execution: a
+        layer stack's entries repeat once per layer (DESIGN.md §10.2), and
+        the ledger commits each distinct entry once, times its count."""
+        if self._counts is None:
+            self._counts = {}
+            for e in self.entries:
+                self._counts[e] = self._counts.get(e, 0) + 1
+        return self._counts
 
     def __iter__(self):
         return iter(self.entries)

@@ -7,6 +7,7 @@ serving can route through core.offload.OffloadEngine with Q8_0 weights.
 """
 from __future__ import annotations
 
+from contextlib import nullcontext
 from typing import Optional
 
 import jax
@@ -31,6 +32,13 @@ def init_linear(key, d_in: int, d_out: int, *, bias: bool = False,
     if bias:
         p["b"] = jnp.zeros((d_out,), dtype)
     return p
+
+
+def per_layer(engine, n: int):
+    """Context for tracing the body of a ``lax.scan``/``vmap`` over ``n``
+    stacked layers: an offload engine then counts each linear inside once
+    per layer (``OffloadEngine.repeat``, DESIGN.md §10.2)."""
+    return engine.repeat(n) if engine is not None else nullcontext()
 
 
 def linear(p: dict, x: jax.Array, engine=None, name: str = "linear") -> jax.Array:

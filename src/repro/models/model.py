@@ -129,8 +129,7 @@ def forward(params: dict, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
 
 def whisper_readout(params: dict, cfg: ModelConfig, x: jax.Array,
                     engine=None) -> jax.Array:
-    x = layers.norm_apply(params["dec_norm"], x, cfg.norm)
-    return layers.unembed(params["embed"], x, engine)
+    return whisper.readout(params, cfg, x, engine)
 
 
 def _ce_of_logits(logits: jax.Array, labels: jax.Array,

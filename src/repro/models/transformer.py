@@ -155,8 +155,9 @@ def apply_decoder_stack(params: dict, cfg: ModelConfig, x: jax.Array, *,
             x, aux = carry
             x, a = repeat_fn(x, xs)
             return (x, aux + a), None
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   params["blocks"])
+        with layers.per_layer(engine, n_repeats(cfg)):
+            (x, aux), _ = jax.lax.scan(
+                body, (x, jnp.zeros((), jnp.float32)), params["blocks"])
     else:
         aux = jnp.zeros((), jnp.float32)
         for r in range(n_repeats(cfg)):
@@ -225,7 +226,8 @@ def decode_step_stack(params: dict, cfg: ModelConfig, x: jax.Array,
             block_params, states_r = xs
             x, new_states = repeat_fn(x, block_params, states_r)
             return x, new_states
-        x, new_states = jax.lax.scan(body, x, (params["blocks"], states))
+        with layers.per_layer(engine, n_repeats(cfg)):
+            x, new_states = jax.lax.scan(body, x, (params["blocks"], states))
     else:
         r = n_repeats(cfg)
         acc = []

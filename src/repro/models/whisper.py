@@ -125,27 +125,38 @@ def encode(params: dict, cfg: ModelConfig, mel: jax.Array, *,
 
     Trace-pure with an ``engine`` (DESIGN.md §10.1): serving jits the
     whole prefill (encode + cross-K/V projection) in one compiled call."""
-    x = layers.linear(params["frontend"], mel.astype(jnp.float32), engine,
-                      "enc.frontend")
-    x = jax.nn.gelu(x)
-    f = x.shape[1]
-    dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[cfg.dtype]
-    x = (x + params["enc_pos"]["table"][:f].astype(jnp.float32)).astype(dtype)
+    with jax.named_scope("encoder"):
+        return _encode(params, cfg, mel, engine, attn_chunk)
+
+
+def _encode(params: dict, cfg: ModelConfig, mel: jax.Array, engine,
+            attn_chunk: int) -> jax.Array:
+    with jax.named_scope("conv"):
+        x = layers.linear(params["frontend"], mel.astype(jnp.float32),
+                          engine, "enc.frontend")
+        x = jax.nn.gelu(x)
+        f = x.shape[1]
+        dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[cfg.dtype]
+        x = (x + params["enc_pos"]["table"][:f].astype(jnp.float32)
+             ).astype(dtype)
 
     def block(x, p):
         x = ctx.constrain(x, "batch", None, None)
-        h = layers.norm_apply(p["norm1"], x, cfg.norm)
-        x = x + attention(p["attn"], cfg, h, causal=False, chunk=attn_chunk,
-                          engine=engine).astype(x.dtype)
-        h = layers.norm_apply(p["norm2"], x, cfg.norm)
-        x = x + layers.mlp_apply(p["ffn"], h, cfg.act, engine=engine
-                                 ).astype(x.dtype)
+        with jax.named_scope("self_attn"):
+            h = layers.norm_apply(p["norm1"], x, cfg.norm)
+            x = x + attention(p["attn"], cfg, h, causal=False,
+                              chunk=attn_chunk, engine=engine).astype(x.dtype)
+        with jax.named_scope("ffn"):
+            h = layers.norm_apply(p["norm2"], x, cfg.norm)
+            x = x + layers.mlp_apply(p["ffn"], h, cfg.act, engine=engine
+                                     ).astype(x.dtype)
         return x
 
     block = _remat(block, cfg)
     if cfg.scan_layers:
-        x, _ = jax.lax.scan(lambda c, p: (block(c, p), None), x,
-                            params["enc_blocks"])
+        with layers.per_layer(engine, cfg.num_encoder_layers):
+            x, _ = jax.lax.scan(lambda c, p: (block(c, p), None), x,
+                                params["enc_blocks"])
     else:
         for i in range(cfg.num_encoder_layers):
             p = jax.tree_util.tree_map(lambda a: a[i], params["enc_blocks"])
@@ -168,29 +179,42 @@ def decode_train(params: dict, cfg: ModelConfig, tokens: jax.Array,
 
     def block(x, p):
         x = ctx.constrain(x, "batch", None, None)
-        h = layers.norm_apply(p["norm1"], x, cfg.norm)
-        x = x + attention(p["self_attn"], cfg, h, causal=True,
-                          chunk=attn_chunk, engine=engine).astype(x.dtype)
-        h = layers.norm_apply(p["norm_x"], x, cfg.norm)
-        x = x + attention(p["cross_attn"], cfg, h, memory=memory,
-                          chunk=attn_chunk, engine=engine).astype(x.dtype)
-        h = layers.norm_apply(p["norm2"], x, cfg.norm)
-        x = x + layers.mlp_apply(p["ffn"], h, cfg.act, engine=engine
-                                 ).astype(x.dtype)
+        with jax.named_scope("self_attn"):
+            h = layers.norm_apply(p["norm1"], x, cfg.norm)
+            x = x + attention(p["self_attn"], cfg, h, causal=True,
+                              chunk=attn_chunk, engine=engine).astype(x.dtype)
+        with jax.named_scope("cross_attn"):
+            h = layers.norm_apply(p["norm_x"], x, cfg.norm)
+            x = x + attention(p["cross_attn"], cfg, h, memory=memory,
+                              chunk=attn_chunk, engine=engine).astype(x.dtype)
+        with jax.named_scope("ffn"):
+            h = layers.norm_apply(p["norm2"], x, cfg.norm)
+            x = x + layers.mlp_apply(p["ffn"], h, cfg.act, engine=engine
+                                     ).astype(x.dtype)
         return x
 
     block = _remat(block, cfg)
-    if cfg.scan_layers:
-        x, _ = jax.lax.scan(lambda c, p: (block(c, p), None), x,
-                            params["dec_blocks"])
-    else:
-        for i in range(cfg.num_layers):
-            p = jax.tree_util.tree_map(lambda a: a[i], params["dec_blocks"])
-            x = block(x, p)
+    with jax.named_scope("decoder"):
+        if cfg.scan_layers:
+            with layers.per_layer(engine, cfg.num_layers):
+                x, _ = jax.lax.scan(lambda c, p: (block(c, p), None), x,
+                                    params["dec_blocks"])
+        else:
+            for i in range(cfg.num_layers):
+                p = jax.tree_util.tree_map(lambda a: a[i],
+                                           params["dec_blocks"])
+                x = block(x, p)
     if return_hidden:
         return x
-    x = layers.norm_apply(params["dec_norm"], x, cfg.norm)
-    return layers.unembed(params["embed"], x, engine)
+    return readout(params, cfg, x, engine)
+
+
+def readout(params: dict, cfg: ModelConfig, x: jax.Array,
+            engine=None) -> jax.Array:
+    """Final norm and the tied vocabulary readout."""
+    with jax.named_scope("readout"):
+        x = layers.norm_apply(params["dec_norm"], x, cfg.norm)
+        return layers.unembed(params["embed"], x, engine)
 
 
 # ---------------------------------------------------------------------------
@@ -210,7 +234,9 @@ def precompute_cross_kv(params: dict, cfg: ModelConfig, memory: jax.Array, *,
         return (k.reshape(b, f, hkv, hd).astype(dtype),
                 v.reshape(b, f, hkv, hd).astype(dtype))
 
-    return jax.vmap(per_layer)(params["dec_blocks"])
+    with jax.named_scope("cross_kv"), layers.per_layer(engine,
+                                                       cfg.num_layers):
+        return jax.vmap(per_layer)(params["dec_blocks"])
 
 
 def init_whisper_decode_state(params: dict, cfg: ModelConfig, memory: jax.Array,
@@ -244,34 +270,38 @@ def _paged_stack(params: dict, cfg: ModelConfig, x: jax.Array,
     def body(x, xs):
         p, sk, sv, length, ckp, cvp = xs
         cache = PagedKVCache(sk, sv, bt, length)
-        h = layers.norm_apply(p["norm1"], x, cfg.norm)
-        mixed, cache = decode_attention(p["self_attn"], cfg, h, cache,
-                                        engine=engine)
-        x = x + mixed.astype(x.dtype)
-        ck = ckp[ct].reshape(b, -1, hkv, hd)
-        cv = cvp[ct].reshape(b, -1, hkv, hd)
-        h = layers.norm_apply(p["norm_x"], x, cfg.norm)
-        mixed, _ = decode_attention(p["cross_attn"], cfg, h, cache,
-                                    memory_kv=(ck, cv), engine=engine)
-        x = x + mixed.astype(x.dtype)
-        h = layers.norm_apply(p["norm2"], x, cfg.norm)
-        x = x + layers.mlp_apply(p["ffn"], h, cfg.act, engine=engine
-                                 ).astype(x.dtype)
+        with jax.named_scope("self_attn"):
+            h = layers.norm_apply(p["norm1"], x, cfg.norm)
+            mixed, cache = decode_attention(p["self_attn"], cfg, h, cache,
+                                            engine=engine)
+            x = x + mixed.astype(x.dtype)
+        with jax.named_scope("cross_attn"):
+            ck = ckp[ct].reshape(b, -1, hkv, hd)
+            cv = cvp[ct].reshape(b, -1, hkv, hd)
+            h = layers.norm_apply(p["norm_x"], x, cfg.norm)
+            mixed, _ = decode_attention(p["cross_attn"], cfg, h, cache,
+                                        memory_kv=(ck, cv), engine=engine)
+            x = x + mixed.astype(x.dtype)
+        with jax.named_scope("ffn"):
+            h = layers.norm_apply(p["norm2"], x, cfg.norm)
+            x = x + layers.mlp_apply(p["ffn"], h, cfg.act, engine=engine
+                                     ).astype(x.dtype)
         return x, (cache.k_pages, cache.v_pages, cache.length)
 
     xs = (params["dec_blocks"], state.self_k, state.self_v, state.length,
           state.cross_k, state.cross_v)
-    if cfg.scan_layers:
-        x, (nk, nv, nl) = jax.lax.scan(body, x, xs)
-    else:
-        outs = []
-        for i in range(cfg.num_layers):
-            xi = jax.tree_util.tree_map(lambda a: a[i], xs)
-            x, o = body(x, xi)
-            outs.append(o)
-        nk, nv, nl = (jnp.stack([o[j] for o in outs]) for j in range(3))
-    x = layers.norm_apply(params["dec_norm"], x, cfg.norm)
-    logits = layers.unembed(params["embed"], x, engine)
+    with jax.named_scope("decoder"):
+        if cfg.scan_layers:
+            with layers.per_layer(engine, cfg.num_layers):
+                x, (nk, nv, nl) = jax.lax.scan(body, x, xs)
+        else:
+            outs = []
+            for i in range(cfg.num_layers):
+                xi = jax.tree_util.tree_map(lambda a: a[i], xs)
+                x, o = body(x, xi)
+                outs.append(o)
+            nk, nv, nl = (jnp.stack([o[j] for o in outs]) for j in range(3))
+    logits = readout(params, cfg, x, engine)
     return logits, WhisperPagedDecodeState(
         self_k=nk, self_v=nv, cross_k=state.cross_k, cross_v=state.cross_v,
         block_table=bt, cross_table=ct, length=nl)
@@ -315,32 +345,39 @@ def _decoder_stack(params: dict, cfg: ModelConfig, x: jax.Array,
     window causality, so W=1 reproduces the old step bit-for-bit."""
     def body(x, xs):
         p, kv, ck, cv = xs
-        h = layers.norm_apply(p["norm1"], x, cfg.norm)
-        mixed, kv = decode_attention(p["self_attn"], cfg, h, kv, engine=engine)
-        x = x + mixed.astype(x.dtype)
-        h = layers.norm_apply(p["norm_x"], x, cfg.norm)
-        mixed, _ = decode_attention(p["cross_attn"], cfg, h, kv,
-                                    memory_kv=(ck, cv), engine=engine)
-        x = x + mixed.astype(x.dtype)
-        h = layers.norm_apply(p["norm2"], x, cfg.norm)
-        x = x + layers.mlp_apply(p["ffn"], h, cfg.act, engine=engine
-                                 ).astype(x.dtype)
+        with jax.named_scope("self_attn"):
+            h = layers.norm_apply(p["norm1"], x, cfg.norm)
+            mixed, kv = decode_attention(p["self_attn"], cfg, h, kv,
+                                         engine=engine)
+            x = x + mixed.astype(x.dtype)
+        with jax.named_scope("cross_attn"):
+            h = layers.norm_apply(p["norm_x"], x, cfg.norm)
+            mixed, _ = decode_attention(p["cross_attn"], cfg, h, kv,
+                                        memory_kv=(ck, cv), engine=engine)
+            x = x + mixed.astype(x.dtype)
+        with jax.named_scope("ffn"):
+            h = layers.norm_apply(p["norm2"], x, cfg.norm)
+            x = x + layers.mlp_apply(p["ffn"], h, cfg.act, engine=engine
+                                     ).astype(x.dtype)
         return x, kv
 
     ck, cv = state.cross_kv
-    if cfg.scan_layers:
-        x, new_kv = jax.lax.scan(body, x, (params["dec_blocks"],
-                                           state.self_kv, ck, cv))
-    else:
-        caches = []
-        for i in range(cfg.num_layers):
-            xs = jax.tree_util.tree_map(
-                lambda a: a[i], (params["dec_blocks"], state.self_kv, ck, cv))
-            x, kv_i = body(x, xs)
-            caches.append(kv_i)
-        new_kv = jax.tree_util.tree_map(lambda *z: jnp.stack(z), *caches)
-    x = layers.norm_apply(params["dec_norm"], x, cfg.norm)
-    logits = layers.unembed(params["embed"], x, engine)
+    with jax.named_scope("decoder"):
+        if cfg.scan_layers:
+            with layers.per_layer(engine, cfg.num_layers):
+                x, new_kv = jax.lax.scan(body, x, (params["dec_blocks"],
+                                                   state.self_kv, ck, cv))
+        else:
+            caches = []
+            for i in range(cfg.num_layers):
+                xs = jax.tree_util.tree_map(
+                    lambda a: a[i],
+                    (params["dec_blocks"], state.self_kv, ck, cv))
+                x, kv_i = body(x, xs)
+                caches.append(kv_i)
+            new_kv = jax.tree_util.tree_map(lambda *z: jnp.stack(z),
+                                            *caches)
+    logits = readout(params, cfg, x, engine)
     return logits, WhisperDecodeState(self_kv=new_kv, cross_kv=state.cross_kv)
 
 

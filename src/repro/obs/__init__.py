@@ -4,11 +4,16 @@ tracing, step-clock metrics, Perfetto/Prometheus export.
 ``Telemetry`` is the one nullable handle the serving path threads through
 (DESIGN.md §16.2): ``ServeEngine(telemetry=Telemetry())`` instruments the
 engine, both schedulers, the paged pool, and the launcher; ``None`` (the
-default) keeps every instrumentation site a single ``is not None`` test —
-no spans are allocated, no metrics touched, and the jitted path is
-untouched either way because all recording happens between jitted steps
-or at trace time (the §10/§11/§13/§15 zero-retrace guarantees cannot be
-affected by a layer that never runs inside a traced function).
+default) keeps every instrumentation site a single ``is not None`` test
+or a profiler annotation — no spans are allocated, no metrics touched,
+and the jitted path is untouched either way because all recording
+happens between jitted steps or at trace time (the §10/§11/§13/§15
+zero-retrace guarantees cannot be affected by a layer that never runs
+inside a traced function).
+
+Every span also reaches ``jax.profiler`` as ``repro.<name>``, handle or
+no handle (obs/trace.py ``annotation``, DESIGN.md §16.1): under a
+profile the program's host phases share the device trace's clock.
 
 The handle bundles:
   ``tracer``   obs/trace.py — lifecycle + host spans, instant events
@@ -34,20 +39,19 @@ scope; ``ServeEngine`` activates its telemetry on construction
 from __future__ import annotations
 
 import time
-from contextlib import nullcontext
 from typing import Any, Callable, Dict, Optional
 
 from repro.obs import export as export  # noqa: F401  (re-export surface)
 from repro.obs.metrics import (LATENCY_BUCKETS_S, Counter, Gauge, Histogram,
                                MetricsRegistry, percentile, serving_registry)
-from repro.obs.trace import (ENGINE_TRACK, Span, Tracer, _SpanCtx,
-                             request_track)
+from repro.obs.trace import (ENGINE_TRACK, PREFIX, Phases, Span, Tracer,
+                             _SpanCtx, annotation, request_track)
 
 __all__ = [
     "Telemetry", "Tracer", "Span", "MetricsRegistry", "Histogram",
     "Counter", "Gauge", "percentile", "serving_registry",
-    "LATENCY_BUCKETS_S", "ENGINE_TRACK", "request_track",
-    "activate", "active", "export",
+    "LATENCY_BUCKETS_S", "ENGINE_TRACK", "PREFIX", "request_track",
+    "annotation", "phases", "Phases", "activate", "active", "export",
 ]
 
 _ACTIVE: Optional["Telemetry"] = None
@@ -145,35 +149,33 @@ class Telemetry:
                         args if args is not None else {})
 
     # -- hot-path ledger span (open/close pair) -------------------------
-    def ledger_open(self) -> tuple:
-        """Open half of a non-nesting ledger span, as a plain tuple
-        handle — the per-decode-step fast path. The with-form
-        (``span(..., ledger=True)``) costs ~5 Python frames per record;
-        this pair costs 2, and on a sub-millisecond serving step those
-        frames are the difference between fitting the ≤3% budget
-        (benchmarks/telemetry_overhead.py) and not. NOT exception-safe:
-        a raise between open and close leaves the nesting guard held —
-        use the with-form anywhere that isn't the measured hot loop."""
+    def ledger_open(self, name: str) -> tuple:
+        """Open half of a non-nesting ledger span ``name``, as a plain
+        tuple handle — the per-round fast path of the speculative
+        schedulers. The with-form (``span(..., ledger=True)``) costs ~5
+        Python frames per record; this pair costs 2. The span's profiler
+        annotation is entered here and left in ``ledger_close``. NOT
+        exception-safe: a raise between open and close leaves the nesting
+        guard held — use the with-form anywhere that isn't a measured
+        hot loop."""
         if self._ledger_depth:
             raise RuntimeError("nested ledger spans would double-claim "
                                "the §16.2 attribution invariant")
         self._ledger_depth = 1
-        led = self._ledger
-        if led is None:
-            return (0, 0, self.tracer.now_us())
-        s = led.totals
-        return (s.offloaded_flops + s.fallback_flops + s.residual_flops,
-                s.offloaded_calls + s.fallback_calls,
-                self.tracer.now_us())
+        ann = annotation(name)
+        ann.__enter__()
+        f0, c0 = self._ledger_now()
+        return (f0, c0, self.tracer.now_us(), name, ann)
 
-    def ledger_close(self, h: tuple, name: str, cat: str = "step",
+    def ledger_close(self, h: tuple, cat: str = "step",
                      track: int = ENGINE_TRACK, rid: Optional[int] = None,
                      args: Optional[Dict[str, Any]] = None) -> None:
         """Close half of ``ledger_open``: claims the exact FLOP/call
-        delta toward §16.2 and journals the span record (the journal
-        append is the tracer's own close-time representation)."""
+        delta toward §16.2, journals the span record (the journal append
+        is the tracer's own close-time representation) and leaves the
+        profiler annotation."""
         f1, c1 = self._ledger_now()
-        f0, c0, ts = h
+        f0, c0, ts, name, ann = h
         df, dc = f1 - f0, c1 - c0
         if args is None:
             args = {}
@@ -185,6 +187,7 @@ class Telemetry:
         self.claimed_flops += df
         self.claimed_calls += dc
         self._ledger_depth = 0
+        ann.__exit__(None, None, None)
 
     # -- lifecycle + instants (thin tracer passthrough) -----------------
     def begin(self, rid: int, name: str, **args: Any) -> None:
@@ -286,9 +289,20 @@ class _LedgerSpanCtx(_SpanCtx):
         super().__exit__(*exc)
 
 
-def maybe_span(tele: Optional[Telemetry], name: str, **kwargs):
-    """``tele.span(...)`` or a free ``nullcontext`` — the pattern every
-    instrumentation site uses so the disabled path allocates nothing."""
+def maybe_span(tele: Optional[Telemetry], name: str, cat: str = "host",
+               track: int = ENGINE_TRACK, rid: Optional[int] = None,
+               ledger: bool = False,
+               args: Optional[Dict[str, Any]] = None):
+    """``tele.span(...)``, or with no handle the span's profiler
+    annotation alone (``repro.<name>``, DESIGN.md §16.1) — the pattern
+    every instrumentation site uses, so the disabled path allocates no
+    span and still names its work on a profile."""
     if tele is None:
-        return nullcontext()
-    return tele.span(name, **kwargs)
+        return annotation(name, rid)
+    return tele.span(name, cat, track, rid, ledger, args)
+
+
+def phases(tele: Optional[Telemetry], cat: str = "host") -> Phases:
+    """Back-to-back child spans (``Phases``) journalled on ``tele``'s
+    tracer, or with no handle their profiler annotations alone."""
+    return Phases(tele.tracer if tele is not None else None, cat)

@@ -280,8 +280,6 @@ def serving_registry() -> MetricsRegistry:
                 help="submit -> first streamed token per request")
     r.histogram("repro_step_seconds",
                 help="one fixed-shape batch decode step (wall)")
-    r.histogram("repro_token_seconds",
-                help="per-token latency (step wall / active slots)")
     r.histogram("repro_prefill_seconds",
                 help="batch-1 admission prefill (wall)")
     r.histogram("repro_replay_seconds",

@@ -37,6 +37,14 @@ Tracks map to Perfetto threads in the export (obs/export.py): track 0 is
 the engine/scheduler host loop, track ``1 + rid`` is request ``rid``.
 ``check_nesting()`` verifies the containment discipline the validator
 (tools/check_trace.py) re-checks on the exported JSON.
+
+Profiler sink (DESIGN.md §16.1): every stack span is also entered as a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` (``annotation``),
+so under a profile it lands on the ``/host:CPU`` plane on the device
+trace's clock. With no profiler session an annotation is a sub-µs check;
+the disabled serving path (no ``Telemetry``) enters the annotation alone.
+Lifecycle phases span many host calls and do not nest, so they stay in
+the journal only.
 """
 from __future__ import annotations
 
@@ -44,12 +52,25 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
+#: prefix of every span's name on the profiler's host plane
+PREFIX = "repro."
+
 #: track id of the engine/scheduler host loop; requests live at 1 + rid
 ENGINE_TRACK = 0
 
 
 def request_track(rid: int) -> int:
     return 1 + rid
+
+
+def annotation(name: str, rid: Optional[int] = None) -> TraceAnnotation:
+    """The profiler half of span ``name``: ``repro.<name>``, carrying the
+    request id as the event's ``rid`` stat where there is one."""
+    if rid is None:
+        return TraceAnnotation(PREFIX + name)
+    return TraceAnnotation(PREFIX + name, rid=rid)
 
 
 @dataclass
@@ -228,9 +249,10 @@ def _end(sp: Span) -> float:
 class _SpanCtx:
     """The with-block behind ``Tracer.span`` — one clock read on enter,
     one clock read + one journal append on exit (recorded on exit, so a
-    span is never left open by an exception either)."""
+    span is never left open by an exception either), inside the span's
+    profiler annotation."""
     __slots__ = ("_tracer", "_name", "_cat", "_track", "_rid", "_args",
-                 "_ts")
+                 "_ts", "_ann")
 
     def __init__(self, tracer: Tracer, name: str, cat: str, track: int,
                  rid: Optional[int], args: Dict[str, Any]):
@@ -244,11 +266,58 @@ class _SpanCtx:
     def __enter__(self) -> "_SpanCtx":
         tr = self._tracer
         tr._depth += 1
-        self._ts = tr.now_us()
+        self._ann = annotation(self._name, self._rid)
+        self._ann.__enter__()
+        self._ts = tr._clock()
         return self
 
     def __exit__(self, *exc) -> None:
         tr = self._tracer
+        t1 = tr._clock()
         tr._depth -= 1
+        ts = (self._ts - tr._t0) * 1e6
         tr._j.append(("X", self._name, self._cat, self._track, self._rid,
-                      self._ts, tr.now_us() - self._ts, self._args))
+                      ts, (t1 - self._ts) * 1e6, self._args))
+        self._ann.__exit__(*exc)
+
+
+class Phases:
+    """Back-to-back child spans inside one with-block (DESIGN.md §16.1):
+    ``ph(name)`` ends the running child and starts ``name``; leaving the
+    block ends the last. Children are profiler annotations and, given a
+    tracer, journal records on the engine track; one clock read per child
+    start and end, so a hot loop's sub-phases cost little more than their
+    annotations."""
+    __slots__ = ("_tracer", "_cat", "_ann", "_name", "_ts")
+
+    def __init__(self, tracer: Optional[Tracer], cat: str = "host"):
+        self._tracer = tracer
+        self._cat = cat
+        self._ann = None
+
+    def __enter__(self) -> "Phases":
+        return self
+
+    def __call__(self, name: str) -> None:
+        self._end()
+        self._ann = a = annotation(name)
+        a.__enter__()
+        self._name = name
+        if self._tracer is not None:
+            self._ts = self._tracer._clock()
+
+    def _end(self) -> None:
+        a = self._ann
+        if a is None:
+            return
+        tr = self._tracer
+        if tr is not None:
+            t1 = tr._clock()
+            tr._j.append(("X", self._name, self._cat, ENGINE_TRACK, None,
+                          (self._ts - tr._t0) * 1e6, (t1 - self._ts) * 1e6,
+                          {}))
+        a.__exit__(None, None, None)
+        self._ann = None
+
+    def __exit__(self, *exc) -> None:
+        self._end()

@@ -628,101 +628,111 @@ class PagedScheduler(ContinuousBatchingScheduler):
         eng = self.engine
         pool = self.pool
         tele = self.telemetry
-        while self.queue and pool.n_free:
-            req = self.queue[0]
-            digest = _mel_digest(req.payload)
-            replay = isinstance(req, _PreemptedRequest)
-            ntok = len(req.tokens) if replay else 0
-            need_self = min(ntok // pool.page_size + 1, pool.max_pages)
-            shared = pool.has_shared(digest)
-            need_cross = 0 if shared else pool.n_cross_per_req
-            if not pool.can_alloc(need_self, need_cross):
-                if not self._active:
-                    raise RuntimeError(
-                        f"arena too small: request {req.rid} needs "
-                        f"{need_self} self + {need_cross} cross pages with "
-                        f"nothing left to preempt "
-                        f"(free: {pool.self_alloc.n_free}/"
-                        f"{pool.cross_alloc.n_free})")
-                break                                  # wait for evictions
-            self.queue.popleft()
-            # queue wait accumulates across preemption rounds: a replayed
-            # request's base is its requeue time, not the original submit
-            wait_base = req.requeue_t if replay else req.submit_t
-            queue_wait = (req.queue_wait_s if replay else 0.0) + (
-                time.perf_counter() - wait_base if wait_base else 0.0)
-            if tele is not None:
-                tele.end(req.rid, "queued", wait_s=queue_wait)
-                tele.observe("repro_queue_wait_seconds", queue_wait)
-            slot = pool.acquire()
-            if shared and not replay:
-                # prefix hit: no encoder, no prefill — attach the shared
-                # cross pages and zero the slot's counters. No ledger
-                # commit either: no GEMM ran, so attributing plan work
-                # here would break the PDP invariant. The ledger span's
-                # zero FLOP delta is the checkable form of that claim.
-                self.shared_hits += 1
+        with obs.maybe_span(tele, "admit", cat="sched"):
+            while self.queue and pool.n_free:
+                req = self.queue[0]
+                digest = _mel_digest(req.payload)
+                replay = isinstance(req, _PreemptedRequest)
+                ntok = len(req.tokens) if replay else 0
+                need_self = min(ntok // pool.page_size + 1, pool.max_pages)
+                shared = pool.has_shared(digest)
+                need_cross = 0 if shared else pool.n_cross_per_req
+                if not pool.can_alloc(need_self, need_cross):
+                    if not self._active:
+                        raise RuntimeError(
+                            f"arena too small: request {req.rid} needs "
+                            f"{need_self} self + {need_cross} cross pages "
+                            f"with nothing left to preempt "
+                            f"(free: {pool.self_alloc.n_free}/"
+                            f"{pool.cross_alloc.n_free})")
+                    break                                  # wait for evictions
+                self.queue.popleft()
+                # queue wait accumulates across preemption rounds: a replayed
+                # request's base is its requeue time, not the original submit
+                wait_base = req.requeue_t if replay else req.submit_t
+                queue_wait = (req.queue_wait_s if replay else 0.0) + (
+                    time.perf_counter() - wait_base if wait_base else 0.0)
                 if tele is not None:
-                    tele.instant("prefix_hit", rid=req.rid)
-                    tele.inc("repro_prefix_hits_total")
-                with obs.maybe_span(tele, "attach", cat="lifecycle",
-                                    track=obs.request_track(req.rid),
-                                    rid=req.rid, ledger=True):
-                    t0 = time.perf_counter()
-                    pool.attach_shared(slot, digest)
+                    tele.end(req.rid, "queued", wait_s=queue_wait)
+                    tele.observe("repro_queue_wait_seconds", queue_wait)
+                slot = pool.acquire()
+                if shared and not replay:
+                    # prefix hit: no encoder, no prefill — attach the shared
+                    # cross pages and zero the slot's counters. No ledger
+                    # commit either: no GEMM ran, so attributing plan work
+                    # here would break the PDP invariant. The ledger span's
+                    # zero FLOP delta is the checkable form of that claim.
+                    self.shared_hits += 1
+                    if tele is not None:
+                        tele.instant("prefix_hit", rid=req.rid)
+                        tele.inc("repro_prefix_hits_total")
+                    with obs.maybe_span(tele, "attach", cat="lifecycle",
+                                        track=obs.request_track(req.rid),
+                                        rid=req.rid, ledger=True):
+                        t0 = time.perf_counter()
+                        pool.attach_shared(slot, digest)
+                        for _ in range(need_self):
+                            pool.alloc_self_page(slot)
+                        pool.attach_reset(slot)
+                        prefill_s = time.perf_counter() - t0
+                        self._busy_s += prefill_s
+                    first = req.sot_id
+                    active = _ActiveSlot(rid=req.rid, max_new=req.max_new,
+                                         prefill_s=prefill_s,
+                                         submit_t=req.submit_t,
+                                         queue_wait_s=queue_wait)
+                else:
+                    with obs.maybe_span(tele, "upload", cat="lifecycle",
+                                        track=obs.request_track(req.rid),
+                                        rid=req.rid):
+                        payload = jnp.asarray(req.payload)
+                    with obs.maybe_span(tele, "prefill", cat="lifecycle",
+                                        track=obs.request_track(req.rid),
+                                        rid=req.rid, ledger=True):
+                        key = eng._key("prefill", 1, self.n_frames)
+                        plan = eng._plan(key, eng._prefill_fn,
+                                         eng._serve_params, payload)
+                        t0 = time.perf_counter()
+                        out, state = eng._prefill_jit(eng._serve_params,
+                                                      payload)
+                        jax.block_until_ready(out)
+                        prefill_s = time.perf_counter() - t0
+                        self._busy_s += prefill_s
+                        if eng.offload is not None:
+                            eng.offload.ledger.commit(plan, times=1)
+                    if tele is not None:
+                        tele.observe("repro_prefill_seconds", prefill_s)
+                    if shared:
+                        pool.attach_shared(slot, digest)
+                    else:
+                        pool.alloc_cross_pages(slot, digest)
                     for _ in range(need_self):
                         pool.alloc_self_page(slot)
-                    pool.attach_reset(slot)
-                    prefill_s = time.perf_counter() - t0
-                    self._busy_s += prefill_s
-                first = req.sot_id
-                active = _ActiveSlot(rid=req.rid, max_new=req.max_new,
-                                     prefill_s=prefill_s,
-                                     submit_t=req.submit_t,
-                                     queue_wait_s=queue_wait)
-            else:
-                payload = jnp.asarray(req.payload)
-                key = eng._key("prefill", 1, self.n_frames)
-                plan = eng._plan(key, eng._prefill_fn, eng._serve_params,
-                                 payload)
-                with obs.maybe_span(tele, "prefill", cat="lifecycle",
-                                    track=obs.request_track(req.rid),
-                                    rid=req.rid, ledger=True):
-                    t0 = time.perf_counter()
-                    out, state = eng._prefill_jit(eng._serve_params, payload)
-                    jax.block_until_ready(out)
-                    prefill_s = time.perf_counter() - t0
-                    self._busy_s += prefill_s
-                    if eng.offload is not None:
-                        eng.offload.ledger.commit(plan, times=1)
+                    decode_s = 0.0
+                    if replay and req.tokens:
+                        state, decode_s = self._replay(state, req)
+                    with obs.maybe_span(tele, "splice", cat="lifecycle",
+                                        track=obs.request_track(req.rid),
+                                        rid=req.rid):
+                        pool.insert(slot, state, write_cross=not shared)
+                    first = (req.tokens[-1] if replay and req.tokens
+                             else req.sot_id)
+                    active = _ActiveSlot(
+                        rid=req.rid, max_new=req.max_new,
+                        tokens=list(req.tokens) if replay else [],
+                        steps=ntok,
+                        prefill_s=prefill_s + (req.prefill_s if replay
+                                               else 0.0),
+                        decode_s=decode_s + (req.decode_s if replay
+                                             else 0.0),
+                        submit_t=req.submit_t,
+                        queue_wait_s=queue_wait,
+                        ttft_s=req.ttft_s if replay else 0.0)
                 if tele is not None:
-                    tele.observe("repro_prefill_seconds", prefill_s)
-                if shared:
-                    pool.attach_shared(slot, digest)
-                else:
-                    pool.alloc_cross_pages(slot, digest)
-                for _ in range(need_self):
-                    pool.alloc_self_page(slot)
-                decode_s = 0.0
-                if replay and req.tokens:
-                    state, decode_s = self._replay(state, req)
-                pool.insert(slot, state, write_cross=not shared)
-                first = (req.tokens[-1] if replay and req.tokens
-                         else req.sot_id)
-                active = _ActiveSlot(
-                    rid=req.rid, max_new=req.max_new,
-                    tokens=list(req.tokens) if replay else [],
-                    steps=ntok,
-                    prefill_s=prefill_s + (req.prefill_s if replay else 0.0),
-                    decode_s=decode_s + (req.decode_s if replay else 0.0),
-                    submit_t=req.submit_t,
-                    queue_wait_s=queue_wait,
-                    ttft_s=req.ttft_s if replay else 0.0)
-            if tele is not None:
-                tele.begin(req.rid, "decode")
-            self._tokens = self._tokens.at[slot, 0].set(int(first))
-            self._active[slot] = active
-            admitted.append(req.rid)
+                    tele.begin(req.rid, "decode")
+                self._tokens = self._tokens.at[slot, 0].set(int(first))
+                self._active[slot] = active
+                admitted.append(req.rid)
         if admitted:
             self._note_kv_usage()
         return admitted

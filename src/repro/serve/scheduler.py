@@ -111,8 +111,7 @@ class ContinuousBatchingScheduler:
             # pay a registry lookup per metric per step
             m = self.telemetry.metrics
             self._step_instruments = (m.counter("repro_tokens_total"),
-                                      m.histogram("repro_step_seconds"),
-                                      m.histogram("repro_token_seconds"))
+                                      m.histogram("repro_step_seconds"))
             self._step_gauges = (m.gauge("repro_queue_depth"),
                                  m.gauge("repro_slots_active"),
                                  m.gauge("repro_step_traces"),
@@ -123,7 +122,6 @@ class ContinuousBatchingScheduler:
             # attribution()/flush_telemetry) — registry calls are ~1-2 µs
             # each cold, and a decode step makes several (DESIGN.md §16.4)
             self._buf_steps: List[float] = []
-            self._buf_shares: List[float] = []
             self._buf_ttft: List[float] = []
             self._buf_tokens = 0
             self._buf_finished = 0
@@ -264,18 +262,33 @@ class ContinuousBatchingScheduler:
     def admit(self) -> List[int]:
         """Admit queued requests into free slots (one jitted batch-1
         prefill each, spliced in-place between decode steps). Returns the
-        admitted request ids."""
+        admitted request ids. Spans (DESIGN.md §16.1): ``admit`` around
+        the pass; per request ``upload`` (the padded payload to the
+        device), ``prefill`` (plan lookup, the program, its sync and the
+        ledger commit) and ``splice`` (slot, pool insert, token table)."""
         admitted = []
+        with obs.maybe_span(self.telemetry, "admit", cat="sched"):
+            while self.queue and self.pool.n_free:
+                admitted.append(self._admit_one(self.queue.popleft()))
+        return admitted
+
+    def _admit_one(self, req: _QueuedRequest) -> int:
         eng = self.engine
         tele = self.telemetry
-        while self.queue and self.pool.n_free:
-            req = self.queue.popleft()
-            queue_wait = (time.perf_counter() - req.submit_t
-                          if req.submit_t else 0.0)
-            if tele is not None:
-                tele.end(req.rid, "queued", wait_s=queue_wait)
-                tele.observe("repro_queue_wait_seconds", queue_wait)
+        rid = req.rid
+        queue_wait = (time.perf_counter() - req.submit_t
+                      if req.submit_t else 0.0)
+        if tele is not None:
+            tele.end(rid, "queued", wait_s=queue_wait)
+            tele.observe("repro_queue_wait_seconds", queue_wait)
+        track = obs.request_track(rid)
+        with obs.maybe_span(tele, "upload", cat="lifecycle", track=track,
+                            rid=rid):
             payload = jnp.asarray(req.payload)
+        # the ledger span tightly scopes this request's prefill exec +
+        # commit, so its FLOP delta IS the prefill's attribution
+        with obs.maybe_span(tele, "prefill", cat="lifecycle", track=track,
+                            rid=rid, ledger=True):
             if self._audio:
                 key = eng._key("prefill", 1, self.n_frames)
                 times = 1
@@ -283,34 +296,30 @@ class ContinuousBatchingScheduler:
                 key = eng._key("prefill", 1, payload.shape[1])
                 times = payload.shape[1]
             plan = eng._plan(key, eng._prefill_fn, eng._serve_params, payload)
-            # the ledger span tightly scopes this request's prefill exec +
-            # commit, so its FLOP delta IS the prefill's attribution
-            with obs.maybe_span(tele, "prefill", cat="lifecycle",
-                                track=obs.request_track(req.rid),
-                                rid=req.rid, ledger=True):
-                t0 = time.perf_counter()
-                out, state = eng._prefill_jit(eng._serve_params, payload)
-                jax.block_until_ready(out)
-                if self._audio:
-                    first = np.full((1,), req.sot_id, np.int32)
-                else:
-                    first = np.asarray(eng._argmax(out[:, -1]))
-                prefill_s = time.perf_counter() - t0
-                self._busy_s += prefill_s
-                if eng.offload is not None:
-                    eng.offload.ledger.commit(plan, times=times)
+            t0 = time.perf_counter()
+            out, state = eng._prefill_jit(eng._serve_params, payload)
+            jax.block_until_ready(out)
+            if self._audio:
+                first = np.full((1,), req.sot_id, np.int32)
+            else:
+                first = np.asarray(eng._argmax(out[:, -1]))
+            prefill_s = time.perf_counter() - t0
+            self._busy_s += prefill_s
+            if eng.offload is not None:
+                eng.offload.ledger.commit(plan, times=times)
+        with obs.maybe_span(tele, "splice", cat="lifecycle", track=track,
+                            rid=rid):
             slot = self.pool.acquire()
             self.pool.insert(slot, state)
             self._tokens = self._tokens.at[slot, 0].set(int(first[0]))
-            self._active[slot] = _ActiveSlot(rid=req.rid, max_new=req.max_new,
-                                             prefill_s=prefill_s,
-                                             submit_t=req.submit_t,
-                                             queue_wait_s=queue_wait)
-            if tele is not None:
-                tele.observe("repro_prefill_seconds", prefill_s)
-                tele.begin(req.rid, "decode")
-            admitted.append(req.rid)
-        return admitted
+        self._active[slot] = _ActiveSlot(rid=rid, max_new=req.max_new,
+                                         prefill_s=prefill_s,
+                                         submit_t=req.submit_t,
+                                         queue_wait_s=queue_wait)
+        if tele is not None:
+            tele.observe("repro_prefill_seconds", prefill_s)
+            tele.begin(rid, "decode")
+        return rid
 
     # -- decode ---------------------------------------------------------
     def _ensure_step_plan(self) -> None:
@@ -328,35 +337,58 @@ class ContinuousBatchingScheduler:
         """One fixed-shape batch decode step: every slot advances (free
         slots compute garbage that is never read), active slots emit their
         next token, finished requests are evicted. Returns the step's
-        ``TokenEvent`` stream in slot order."""
+        ``TokenEvent`` stream in slot order. Spans (DESIGN.md §16.1):
+        ``decode_step``, the step's ledger span, around ``step.kv_usage``,
+        ``step.dispatch`` (the program call returning), ``step.sync`` (the
+        host copy of the tokens), ``step.ledger`` and ``step.emit`` (the
+        per-slot tokens, results and releases)."""
         if not self._active:
             return []
-        self._ensure_step_plan()
-        self._note_kv_usage()
-        eng = self.engine
         tele = self.telemetry
+        eng = self.engine
         # the batch step's ledger span scopes exec + host sync + the one
-        # plan commit — its FLOP delta is the step's exact attribution.
-        # ledger_open/close, not the with-form: this step is what the
-        # ≤3% budget prices, and the pair is 3 Python frames lighter
+        # plan commit — its FLOP delta is the step's exact attribution
+        with obs.maybe_span(tele, "decode_step", cat="step", ledger=True,
+                            args={"active": len(self._active)}), \
+                obs.phases(tele, cat="step") as ph:
+            ph("step.kv_usage")
+            self._note_kv_usage()
+            ph("step.dispatch")
+            self._ensure_step_plan()
+            t0 = time.perf_counter()
+            nxt, _, state = eng._step_jit(eng._serve_params, self._tokens,
+                                          self._done0, self.pool.state)
+            self.pool.state = state
+            self._tokens = nxt
+            ph("step.sync")
+            nxt_np = np.asarray(nxt)                   # host sync: streaming
+            dt = time.perf_counter() - t0
+            self._busy_s += dt
+            ph("step.ledger")
+            if eng.offload is not None:
+                eng.offload.ledger.commit(self._step_plan, times=1)
+            ph("step.emit")
+            events = self._emit(tele, nxt_np, dt)
         if tele is not None:
-            h = tele.ledger_open()
-        t0 = time.perf_counter()
-        nxt, _, state = eng._step_jit(eng._serve_params, self._tokens,
-                                      self._done0, self.pool.state)
-        self.pool.state = state
-        self._tokens = nxt
-        nxt_np = np.asarray(nxt)                       # host sync: streaming
-        dt = time.perf_counter() - t0
-        self._busy_s += dt
-        if eng.offload is not None:
-            eng.offload.ledger.commit(self._step_plan, times=1)
-        if tele is not None:
-            tele.ledger_close(h, "decode_step", cat="step",
-                              args={"active": len(self._active)})
+            self._buf_tokens += len(events)
+            self._buf_steps.append(dt)
+            # change-gate on the plain-int peak, not the utilization
+            # property — the ratio's denominator walks the state pytree
+            g = (len(self.queue), len(self._active), eng._step_traces,
+                 self.kv_used_peak)
+            if g != self._gauge_state:      # gauges move rarely mid-drain
+                self._gauge_state = g
+                gq, gs, gt, gu = self._step_gauges
+                gq.set(g[0])
+                gs.set(g[1])
+                gt.set(g[2])
+                gu.set(self.kv_utilization_peak)
+        return events
+
+    def _emit(self, tele, nxt_np: np.ndarray, dt: float) -> List[TokenEvent]:
         share = dt / len(self._active)
         now = time.perf_counter()
-        eos = eng.eos_id
+        eos = self.engine.eos_id
         events = []
         for slot in sorted(self._active):
             a = self._active[slot]
@@ -386,21 +418,6 @@ class ContinuousBatchingScheduler:
                 # next admission and freed rows' garbage is never read —
                 # skipping the reset saves a pool-state copy per eviction
                 self.pool.release(slot, reset=False)
-        if tele is not None:
-            self._buf_tokens += len(events)
-            self._buf_steps.append(dt)
-            self._buf_shares.append(share)
-            # change-gate on the plain-int peak, not the utilization
-            # property — the ratio's denominator walks the state pytree
-            g = (len(self.queue), len(self._active), eng._step_traces,
-                 self.kv_used_peak)
-            if g != self._gauge_state:      # gauges move rarely mid-drain
-                self._gauge_state = g
-                gq, gs, gt, gu = self._step_gauges
-                gq.set(g[0])
-                gs.set(g[1])
-                gt.set(g[2])
-                gu.set(self.kv_utilization_peak)
         return events
 
     # -- telemetry flush -------------------------------------------------
@@ -414,16 +431,13 @@ class ContinuousBatchingScheduler:
         tele = self.telemetry
         if tele is None:
             return
-        ctok, hstep, htok = self._step_instruments
+        ctok, hstep = self._step_instruments
         if self._buf_tokens:
             ctok.inc(self._buf_tokens)
             self._buf_tokens = 0
         for v in self._buf_steps:
             hstep.observe(v)
         self._buf_steps.clear()
-        for v in self._buf_shares:
-            htok.observe(v)
-        self._buf_shares.clear()
         for v in self._buf_ttft:
             tele.observe("repro_ttft_seconds", v)
         self._buf_ttft.clear()

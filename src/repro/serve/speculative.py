@@ -205,7 +205,7 @@ class SpeculativeEngine:
 
         t0 = time.perf_counter()
         while not done.all():
-            h = tele.ledger_open() if tele is not None else None
+            h = tele.ledger_open("spec_round") if tele is not None else None
             active_mask = ~done
             active = int(active_mask.sum())
             # --- draft k tokens; the k+1-th feed writes d_k's KV entry
@@ -260,7 +260,7 @@ class SpeculativeEngine:
                 v.offload.ledger.commit(v_verify_plan, times=1,
                                         role="verify")
             if tele is not None:
-                tele.ledger_close(h, "spec_round", cat="step",
+                tele.ledger_close(h, cat="step",
                                   args={"round": self.rounds,
                                         "active": int(active)})
                 tele.inc("repro_spec_rounds_total")
@@ -540,7 +540,7 @@ class _SpecRoundsMixin:
         self._note_kv_usage()
         tele = self.telemetry
         if tele is not None:
-            h = tele.ledger_open()
+            h = tele.ledger_open("spec_round")
         t0 = time.perf_counter()
         dpool = self._draft_pool
         d_state = dpool.state
@@ -572,7 +572,7 @@ class _SpecRoundsMixin:
             v.offload.ledger.commit(self._verify_plan, times=1,
                                     role="verify")
         if tele is not None:
-            tele.ledger_close(h, "spec_round", cat="step",
+            tele.ledger_close(h, cat="step",
                               args={"active": len(self._active)})
         accept_len, committed, n_emit = accept_spec(win[:, 1:], vt)
         share = dt_s / len(self._active)
@@ -630,7 +630,6 @@ class _SpecRoundsMixin:
         if tele is not None:
             self._buf_tokens += len(events)
             self._buf_steps.append(dt_s)
-            self._buf_shares.append(share)
             tele.inc("repro_spec_rounds_total")
             tele.inc("repro_spec_drafted_total", drafted)
             tele.inc("repro_spec_accepted_total", accepted)

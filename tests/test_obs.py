@@ -230,10 +230,10 @@ def test_ledger_spans_do_not_nest():
             with tele.span("b", ledger=True):
                 pass
     tele2 = obs.Telemetry(clock=_VClock())
-    h = tele2.ledger_open()
+    h = tele2.ledger_open("a")
     with pytest.raises(RuntimeError):
-        tele2.ledger_open()
-    tele2.ledger_close(h, "a")
+        tele2.ledger_open("b")
+    tele2.ledger_close(h)
     with tele2.span("c", ledger=True):      # guard released after close
         pass
 
@@ -244,8 +244,8 @@ def test_ledger_open_close_matches_with_form():
     tele = obs.Telemetry(clock=_VClock())
     with tele.span("step", cat="step", ledger=True, args={"active": 2}):
         pass
-    h = tele.ledger_open()
-    tele.ledger_close(h, "step", cat="step", args={"active": 2})
+    h = tele.ledger_open("step")
+    tele.ledger_close(h, cat="step", args={"active": 2})
     a, b = tele.tracer.spans
     assert a.name == b.name == "step"
     assert a.args == b.args == {"active": 2, "flops": 0, "calls": 0}
