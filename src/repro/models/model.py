@@ -42,6 +42,38 @@ class ServeState(NamedTuple):
     step: jax.Array       # () or (B,) i32 — absolute position of next token
 
 
+def step_writes(state: ServeState) -> ServeState:
+    """The part of ``state`` a decode program writes and returns
+    (DESIGN.md §11.2). The per-utterance cross-KV (contiguous ``cross_kv``,
+    or the paged cross arenas and both page tables) is projected at
+    admission and only read while a request decodes; a program that
+    returned it would have XLA copy it whole into fresh output buffers on
+    every call, so those leaves come back as ``None``. LM states have no
+    read-only leaves and return whole."""
+    ls = state.layer_states
+    if isinstance(ls, whisper.WhisperDecodeState):
+        ls = ls._replace(cross_kv=None)
+    elif isinstance(ls, whisper.WhisperPagedDecodeState):
+        ls = ls._replace(cross_k=None, cross_v=None, block_table=None,
+                         cross_table=None)
+    return ServeState(layer_states=ls, step=state.step)
+
+
+def with_step_writes(state: ServeState, written: ServeState) -> ServeState:
+    """``state`` with the leaves a decode program wrote taken from
+    ``written``; the read-only leaves stay ``state``'s own arrays, by
+    reference. ``written`` may also be a full state: only its written
+    leaves are read."""
+    ls, w = state.layer_states, written.layer_states
+    if isinstance(ls, whisper.WhisperDecodeState):
+        ls = ls._replace(self_kv=w.self_kv)
+    elif isinstance(ls, whisper.WhisperPagedDecodeState):
+        ls = ls._replace(self_k=w.self_k, self_v=w.self_v, length=w.length)
+    else:
+        ls = w
+    return ServeState(layer_states=ls, step=written.step)
+
+
 def _dtype(cfg: ModelConfig):
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[cfg.dtype]
 

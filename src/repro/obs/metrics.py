@@ -293,6 +293,10 @@ def serving_registry() -> MetricsRegistry:
     r.gauge("repro_kv_pages_shared",
             help="pages with refcount > 1 (CoW/prefix sharing)")
     r.gauge("repro_kv_utilization", help="peak used/committed KV bytes")
+    r.gauge("repro_step_written_bytes",
+            help="bytes the decode step program returns per call")
+    r.gauge("repro_step_kept_bytes",
+            help="read-only decode state kept by reference, not returned")
     r.counter("repro_requests_submitted_total")
     r.counter("repro_requests_finished_total")
     r.counter("repro_tokens_total", help="tokens streamed")

@@ -181,17 +181,19 @@ class ServeEngine:
         def step_fn(params, token, done, state):
             """One greedy decode step with an on-device done-mask: emit
             the argmax token and fold its EOS test into ``done`` without
-            leaving the device. Shape-stable across both serving modes —
-            the continuous-batching scheduler drives the SAME compiled
-            step at its pool width (DESIGN.md §11.2). The trace counter
-            increments only when jax re-traces (host code runs at trace
-            time), which is how tests and the continuous_batching
+            leaving the device. Returns only the state it writes
+            (``model.step_writes``); callers put it back together with
+            ``model.with_step_writes``. Shape-stable across both serving
+            modes — the continuous-batching scheduler drives the SAME
+            compiled step at its pool width (DESIGN.md §11.2). The trace
+            counter increments only when jax re-traces (host code runs at
+            trace time), which is how tests and the continuous_batching
             benchmark assert zero retraces after warmup."""
             self._step_traces += 1
             logits, state = decode_fn(params, token, state)
             nxt = self._argmax(logits[:, -1])[:, None]
             done = done | (nxt[:, 0] == eos)
-            return nxt, done, state
+            return nxt, done, model_lib.step_writes(state)
 
         self._step_jit = jax.jit(step_fn)
 
@@ -208,9 +210,11 @@ class ServeEngine:
         def verify_fn(params, tokens, state):
             # counted exactly like _step_traces (host code runs at trace
             # time); plan recording uses the counter-free _verify_fn so
-            # an eval_shape never inflates the zero-retrace gate
+            # an eval_shape never inflates the zero-retrace gate. Like
+            # the step, it returns only the state it writes.
             self._verify_traces += 1
-            return verify_core(params, tokens, state)
+            logits, state = verify_core(params, tokens, state)
+            return logits, model_lib.step_writes(state)
 
         self._verify_fn = verify_core
         self._verify_jit = jax.jit(verify_fn)
@@ -298,8 +302,9 @@ class ServeEngine:
         t0 = time.perf_counter()
         steps = 0
         for _ in range(max_new):
-            token, done, state = self._step_jit(self._serve_params, token,
-                                                done, state)
+            token, done, written = self._step_jit(self._serve_params,
+                                                  token, done, state)
+            state = model_lib.with_step_writes(state, written)
             toks.append(token)
             steps += 1
             if bool(done.all()):

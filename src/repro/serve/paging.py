@@ -610,18 +610,6 @@ class PagedScheduler(ContinuousBatchingScheduler):
                            eng.max_len, n_frames=self.n_frames,
                            mesh=eng.mesh, **self._page_cfg)
 
-    # -- plan key (page geometry appended, DESIGN.md §15.5) -------------
-    def _ensure_step_plan(self) -> None:
-        if self._step_plan_ready:
-            return
-        eng = self.engine
-        key = eng._key("step", self.n_slots, self.n_frames,
-                       pages=self.pool.plan_geometry)
-        token = jnp.zeros((self.n_slots, 1), jnp.int32)
-        self._step_plan = eng._plan(key, eng._decode_fn, eng._serve_params,
-                                    token, self.pool.state)
-        self._step_plan_ready = True
-
     # -- admission ------------------------------------------------------
     def admit(self) -> List[int]:
         admitted = []

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.models import model as model_lib
 from repro.serve.engine import GenerationResult, ServeEngine
 from repro.serve.kvcache import SlotKVPool
 
@@ -323,15 +324,31 @@ class ContinuousBatchingScheduler:
 
     # -- decode ---------------------------------------------------------
     def _ensure_step_plan(self) -> None:
+        """Build the step's plan once per pool (keyed with the page
+        geometry for a paged pool, DESIGN.md §15.5) and gauge the bytes
+        the step returns against those it leaves with the pool."""
         if self._step_plan_ready:
             return
         eng = self.engine
         extra = (self.n_frames,) if self._audio else ()
-        key = eng._key("step", self.n_slots, *extra)
+        key = eng._key("step", self.n_slots, *extra,
+                       pages=getattr(self.pool, "plan_geometry", None))
         token = jnp.zeros((self.n_slots, 1), jnp.int32)
         self._step_plan = eng._plan(key, eng._decode_fn, eng._serve_params,
                                     token, self.pool.state)
         self._step_plan_ready = True
+        tele = self.telemetry
+        if tele is not None:
+            # the step returns what it writes (DESIGN.md §11.2); the rest
+            # of the pool state stays with the pool, by reference
+            state = self.pool.state
+            out = jax.eval_shape(eng._step_jit, eng._serve_params,
+                                 self._tokens, self._done0, state)
+            kept = (model_lib.state_kv_bytes(state)
+                    - model_lib.state_kv_bytes(model_lib.step_writes(state)))
+            tele.gauge("repro_step_written_bytes",
+                       model_lib.state_kv_bytes(out))
+            tele.gauge("repro_step_kept_bytes", kept)
 
     def decode_step(self) -> List[TokenEvent]:
         """One fixed-shape batch decode step: every slot advances (free
@@ -356,9 +373,10 @@ class ContinuousBatchingScheduler:
             ph("step.dispatch")
             self._ensure_step_plan()
             t0 = time.perf_counter()
-            nxt, _, state = eng._step_jit(eng._serve_params, self._tokens,
-                                          self._done0, self.pool.state)
-            self.pool.state = state
+            nxt, _, written = eng._step_jit(eng._serve_params, self._tokens,
+                                            self._done0, self.pool.state)
+            self.pool.state = model_lib.with_step_writes(self.pool.state,
+                                                         written)
             self._tokens = nxt
             ph("step.sync")
             nxt_np = np.asarray(nxt)                   # host sync: streaming

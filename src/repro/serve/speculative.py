@@ -213,15 +213,17 @@ class SpeculativeEngine:
             dtoks = []
             dt = cur
             for _ in range(k):
-                dt, _, d_state = d._step_jit(d._serve_params, dt, nodone,
-                                             d_state)
+                dt, _, d_w = d._step_jit(d._serve_params, dt, nodone,
+                                         d_state)
+                d_state = model_lib.with_step_writes(d_state, d_w)
                 dtoks.append(dt)
-            _, _, d_state = d._step_jit(d._serve_params, dtoks[-1], nodone,
-                                        d_state)
+            _, _, d_w = d._step_jit(d._serve_params, dtoks[-1], nodone,
+                                    d_state)
+            d_state = model_lib.with_step_writes(d_state, d_w)
             # --- verify the whole window in ONE forward
             window = jnp.concatenate([cur] + dtoks, axis=1)      # (B, k+1)
-            vlogits, v_state = v._verify_jit(v._serve_params, window,
-                                             v_state)
+            vlogits, v_w = v._verify_jit(v._serve_params, window, v_state)
+            v_state = model_lib.with_step_writes(v_state, v_w)
             vtoks = v._argmax(vlogits)                           # (B, k+1)
             # --- the round's single host sync
             vt, win = jax.device_get((vtoks, window))
@@ -549,18 +551,19 @@ class _SpecRoundsMixin:
         dt = self._tokens
         dtoks = []
         for _ in range(k):
-            dt, _, d_state = d._step_jit(d._serve_params, dt, self._done0,
-                                         d_state)
+            dt, _, d_w = d._step_jit(d._serve_params, dt, self._done0,
+                                     d_state)
+            d_state = model_lib.with_step_writes(d_state, d_w)
             dtoks.append(dt)
-        _, _, d_state = d._step_jit(d._serve_params, dtoks[-1], self._done0,
-                                    d_state)
-        dpool.state = d_state
+        _, _, d_w = d._step_jit(d._serve_params, dtoks[-1], self._done0,
+                                d_state)
+        dpool.state = model_lib.with_step_writes(d_state, d_w)
         # ONE verify forward over the whole window, then the round's
         # single host sync
         window = jnp.concatenate([self._tokens] + dtoks, axis=1)
-        vlogits, v_state = v._verify_jit(v._serve_params, window,
-                                         self.pool.state)
-        self.pool.state = v_state
+        vlogits, v_w = v._verify_jit(v._serve_params, window,
+                                     self.pool.state)
+        self.pool.state = model_lib.with_step_writes(self.pool.state, v_w)
         vtoks = v._argmax(vlogits)
         vt, win = jax.device_get((vtoks, window))
         dt_s = time.perf_counter() - t0

@@ -127,7 +127,9 @@ def test_tuner_candidates_compile(one_chip, no_persistent_cache, kernel):
 
 def test_whisper_tiny_served_programs_compile(one_chip, no_persistent_cache):
     """whisper-tiny's batch-1 prefill at 1500 frames and its 4-slot decode
-    step, traced as on the chip (main segments on native pallas_tpu)."""
+    step, traced as on the chip (main segments on native pallas_tpu). The
+    step returns only the state it writes (DESIGN.md §11.2): its outputs
+    are smaller than the cross-KV it reads, so XLA copies none of it."""
     from repro.backends import platform
     from repro.models import model as model_lib
     from repro.serve.engine import ServeEngine
@@ -146,6 +148,7 @@ def test_whisper_tiny_served_programs_compile(one_chip, no_persistent_cache):
     state = on_chip(jax.eval_shape(
         lambda pp, mm: eng._prefill_fn(pp, mm)[1], p,
         jax.ShapeDtypeStruct((4, *mel), jnp.float32)))
+    cross_kv_bytes = model_lib.state_kv_bytes(state.layer_states.cross_kv)
     platform._PROBE["platform"] = "tpu"      # route as the chip would
     try:
         for fn, args in (
@@ -156,7 +159,9 @@ def test_whisper_tiny_served_programs_compile(one_chip, no_persistent_cache):
                  (p, *on_chip((jax.ShapeDtypeStruct((4, 1), jnp.int32),
                                jax.ShapeDtypeStruct((4,), bool))), state))):
             compiled = fn.lower(*args).compile()
-            assert compiled.memory_analysis() is not None
+            mem = compiled.memory_analysis()
+            assert mem is not None
             assert "tpu_custom_call" in compiled.as_text()
+        assert mem.output_size_in_bytes < cross_kv_bytes
     finally:
         platform.reset_probe_cache()
