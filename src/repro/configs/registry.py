@@ -1,7 +1,9 @@
 """Architecture registry: ``--arch <id>`` -> (CONFIG, SMOKE).
 
-The 10 assigned archs form the 40-cell dry-run matrix; whisper-base/small are
-extra (the paper's own scaling study) and are exercised by benchmarks only.
+The 10 assigned archs form the 40-cell dry-run matrix. The extras are the
+Whisper ladder beyond tiny: base and small (the paper's own scaling study)
+and large-v3 (Whisper's flagship). All are served by the same engine and
+scheduler; small and large-v3 have on-chip benchmark cells (``chip_bench/``).
 """
 from __future__ import annotations
 
@@ -29,6 +31,7 @@ ASSIGNED: Dict[str, str] = {
 EXTRA: Dict[str, str] = {
     "whisper-base": "whisper_base",
     "whisper-small": "whisper_small",
+    "whisper-large-v3": "whisper_large_v3",
 }
 
 ALL_ARCHS: Dict[str, str] = {**ASSIGNED, **EXTRA}

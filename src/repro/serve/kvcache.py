@@ -221,6 +221,11 @@ class SlotKVPool:
         return int(total)
 
     # -- state ops ------------------------------------------------------
+    def splice_shape(self, req_state: ServeState) -> ServeState:
+        """The shapes ``insert`` of ``req_state`` returns: a whole pool
+        state, whatever one row it writes."""
+        return jax.eval_shape(self._insert_jit, self.state, 0, req_state)
+
     def insert(self, slot: int, req_state: ServeState) -> None:
         """Splice a batch-1 prefill state into ``slot`` (jitted; sharded
         pools keep their slot-axis sharding via pinned out_shardings)."""

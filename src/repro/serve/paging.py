@@ -527,6 +527,13 @@ class PagedKVPool:
             self.state, slot, jnp.asarray(self._bt[slot]),
             jnp.asarray(self._ct[slot]), req_state, write_cross=write_cross)
 
+    def splice_shape(self, req_state: ServeState) -> ServeState:
+        """The shapes ``insert`` of ``req_state`` returns: the whole
+        arenas and tables, whatever pages it writes."""
+        return jax.eval_shape(self._insert_jit, self.state, 0,
+                              self._bt[0], self._ct[0], req_state,
+                              write_cross=True)
+
     def attach_reset(self, slot: int) -> None:
         """Device-side half of a share-hit admission: zero the slot's
         counters (its tables were set on the host)."""

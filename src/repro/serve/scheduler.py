@@ -150,6 +150,11 @@ class ContinuousBatchingScheduler:
                 self._tokens, NamedSharding(mesh, P("data", None)))
             self._done0 = jax.device_put(
                 self._done0, NamedSharding(mesh, P("data")))
+        if self.telemetry is not None:
+            # each admission's splice returns a whole new pool state
+            # (DESIGN.md §11.1); gauged once, from the program's shapes
+            self.telemetry.gauge("repro_splice_written_bytes",
+                                 self._splice_written_bytes())
         self._next_rid = 0
         self._step_plan_ready = False
         self._step_plan = None
@@ -175,6 +180,20 @@ class ContinuousBatchingScheduler:
         return SlotKVPool(eng.cfg, eng._serve_params, self.n_slots,
                           eng.max_len, n_frames=self.n_frames,
                           mesh=eng.mesh)
+
+    def _splice_written_bytes(self) -> int:
+        """Bytes one admission's splice program returns, from the shapes
+        of a batch-1 prefill state (``init_serve_state`` at batch 1, as
+        the prefill builds it) spliced into this pool."""
+        eng, cfg = self.engine, self.engine.cfg
+        memory = (jax.ShapeDtypeStruct((1, self.n_frames, cfg.d_model),
+                                       model_lib._dtype(cfg))
+                  if self._audio else None)
+        req = jax.eval_shape(
+            lambda p, m: model_lib.init_serve_state(p, cfg, 1, eng.max_len,
+                                                    memory=m),
+            eng._serve_params, memory)
+        return model_lib.state_kv_bytes(self.pool.splice_shape(req))
 
     # -- KV accounting (DESIGN.md §15.4) --------------------------------
     @property

@@ -167,3 +167,27 @@ def test_step_bytes_gauges(kind, whisper_setup, lm_setup):
         assert 0 < written < kept
         assert kept == sum(int(l.size) * l.dtype.itemsize
                            for l in _read_only_leaves(sched.pool.state))
+
+
+@pytest.mark.parametrize("kind", ["contiguous", "paged"])
+@pytest.mark.parametrize("arch", ["whisper-tiny", "whisper-large-v3"])
+def test_splice_bytes_gauge(arch, kind):
+    """Set once with the pool, from the splice program's shapes: an
+    admission's splice returns the whole pool state today, far more than
+    a decode step returns."""
+    cfg = get_smoke_config(arch)
+    params = M.init_params(jax.random.PRNGKey(1), cfg, 64)
+    tele = obs.Telemetry()
+    eng = ServeEngine(cfg, params, max_len=MAX_LEN, quant="none", eos_id=-1,
+                      telemetry=tele)
+    sched = (eng.paged_scheduler(n_slots=2, n_frames=N_FRAMES, page_size=4)
+             if kind == "paged" else
+             eng.scheduler(n_slots=2, n_frames=N_FRAMES))
+    g = tele.metrics.snapshot()["gauges"]
+    splice = g["repro_splice_written_bytes"][""]
+    assert splice == M.state_kv_bytes(sched.pool.state)
+    _submit(sched, kind, 1, max_new=2)
+    sched.run()
+    g = tele.metrics.snapshot()["gauges"]
+    assert g["repro_splice_written_bytes"][""] == splice
+    assert splice > g["repro_step_written_bytes"][""]

@@ -2,18 +2,21 @@
 
 The TPU compiler is installed with jax; it compiles for a described
 ``v5e:2x2`` topology. These tests compile the main-path kernels at
-Whisper-tiny, -base and -small widths — every weight GEMM of the model,
-the tied vocab readout included — with the tilings the offload engine's
-plan and the untuned defaults resolve, and the first candidates the
-autotuner's space emits; and whisper-tiny's served prefill and slot step
-with the native kernels. Nothing runs: a pass says the chip's compiler
-accepts the program, not that its results are right (the interpret-mode
-parity tests say that).
+Whisper-tiny, -base, -small and -large-v3 widths — every weight GEMM of
+the model, the tied vocab readout included — with the tilings the offload
+engine's plan and the untuned defaults resolve, and the first candidates
+the autotuner's space emits; whisper-tiny's served prefill and slot step
+with the native kernels; and whisper-large-v3's served prefill, slot step
+and slot splice at its benchmark deployment, with what they hold in HBM.
+Nothing runs: a pass says the chip's compiler accepts the program, not
+that its results are right (the interpret-mode parity tests say that).
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and under pytest-xdist
 every worker imports this file."""
 import functools
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +32,8 @@ from repro.kernels.q8_matmul import q8_matmul
 from repro.kernels.q8_matvec import q8_matvec
 from repro.tuning import enumerate_candidates
 
-ARCHS = ("whisper-tiny", "whisper-base", "whisper-small")
+ARCHS = ("whisper-tiny", "whisper-base", "whisper-small",
+         "whisper-large-v3")
 # (kernel the plan must resolve, activation rows M, quantized weights):
 # decode batches pad to 8 and 16 rows; 1504 is the 1500-frame encoder
 VARIANTS = (("q8_matvec", 8, True), ("q8_matvec", 16, True),
@@ -165,3 +169,80 @@ def test_whisper_tiny_served_programs_compile(one_chip, no_persistent_cache):
         assert mem.output_size_in_bytes < cross_kv_bytes
     finally:
         platform.reset_probe_cache()
+
+
+def test_whisper_large_v3_served_programs_fit(one_chip, no_persistent_cache):
+    """whisper-large-v3 at published widths and the benchmark's slot
+    count: its batch-1 prefill (32-layer encoder scan, K = 5120 down
+    projection), its decode step over the pool, and the slot splice
+    compile as on the chip. Built from shapes alone (nothing is
+    allocated): the Q8_0 weights, the pool and the splice's output, which
+    is a whole second pool until the old one is freed, fit one chip's
+    16 GiB."""
+    from repro.backends import platform
+    from repro.core.qformats import quantize_tree
+    from repro.models import model as model_lib
+    from repro.serve import kvcache
+    from repro.serve.engine import ServeEngine, _keep_dense
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chip_bench", "configs",
+                           "whisper-large-v3-q8.json")) as f:
+        dep = json.load(f)["deployment"]
+    n, max_len = dep["n_slots"], dep["max_len"]
+    cfg = get_config("whisper-large-v3")
+    dense = jax.eval_shape(
+        lambda k: model_lib.init_params(k, cfg, 448), jax.random.PRNGKey(0))
+    served = jax.eval_shape(lambda p: quantize_tree(p, _keep_dense), dense)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    # the weights are already Q8_0 shapes: the engine serves them as given
+    eng = ServeEngine(cfg, served, max_len=max_len, quant="none",
+                      offload=OffloadEngine(), eos_id=None)
+    p = on_chip(served)
+    mel = (cfg.encoder_ctx, cfg.n_mels)
+    dtype = model_lib._dtype(cfg)
+    pool = on_chip(jax.eval_shape(
+        lambda pp: model_lib.slot_layout(model_lib.init_serve_state(
+            pp, cfg, n, max_len,
+            memory=jnp.zeros((n, cfg.encoder_ctx, cfg.d_model), dtype)), n),
+        served))
+    req = on_chip(jax.eval_shape(
+        lambda pp, mm: eng._prefill_fn(pp, mm)[1], p,
+        jax.ShapeDtypeStruct((1, *mel), jnp.float32)))
+    platform._PROBE["platform"] = "tpu"      # route as the chip would
+    try:
+        mems = {}
+        for name, fn, args in (
+                ("prefill", eng._prefill_jit,
+                 (p, jax.ShapeDtypeStruct((1, *mel), jnp.float32,
+                                          sharding=one_chip))),
+                ("step", eng._step_jit,
+                 (p, *on_chip((jax.ShapeDtypeStruct((n, 1), jnp.int32),
+                               jax.ShapeDtypeStruct((n,), bool))), pool)),
+                ("splice", kvcache.slot_insert,
+                 (pool, jax.ShapeDtypeStruct((), jnp.int32,
+                                             sharding=one_chip), req))):
+            compiled = jax.jit(fn).lower(*args).compile()
+            mems[name] = compiled.memory_analysis()
+            assert mems[name] is not None
+            if name != "splice":
+                assert "tpu_custom_call" in compiled.as_text()
+    finally:
+        platform.reset_probe_cache()
+    pool_bytes = model_lib.state_kv_bytes(pool)
+    step, splice = mems["step"], mems["splice"]
+    # the splice returns a whole pool (laid out in the chip's tiles); the
+    # step returns the self-KV, under a sixth of one (DESIGN.md §11.2)
+    assert pool_bytes <= splice.output_size_in_bytes < 1.1 * pool_bytes
+    assert step.output_size_in_bytes < pool_bytes // 6
+    # the weights as laid out on the chip: a program that takes them all
+    weights = jax.jit(lambda t: jnp.zeros(()), keep_unused=True).lower(
+        p).compile().memory_analysis().argument_size_in_bytes
+    assert weights >= model_lib.state_kv_bytes(served)
+    # held at once: the weights, the pool and the splice's new pool
+    held = weights + 2 * splice.output_size_in_bytes
+    assert held < 16 * 2**30
