@@ -298,7 +298,8 @@ def serving_registry() -> MetricsRegistry:
     r.gauge("repro_step_kept_bytes",
             help="read-only decode state kept by reference, not returned")
     r.gauge("repro_splice_written_bytes",
-            help="bytes the admission splice program returns per call")
+            help="bytes an admission's splice writes into the pool, in "
+                 "place")
     r.counter("repro_requests_submitted_total")
     r.counter("repro_requests_finished_total")
     r.counter("repro_tokens_total", help="tokens streamed")

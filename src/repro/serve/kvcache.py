@@ -9,7 +9,8 @@ zero retraces across any admission/eviction schedule (the property
 tests/test_scheduler.py regression-gates, in the style of
 tests/test_plan.py).
 
-Ops (all jit-compiled once per pool shape, shared module-level caches):
+Ops (pure functions; the pool jit-compiles each once per pool shape,
+in shared module-level caches):
 
   slot_insert(pool, slot, req)  splice a single-request prefill state
                                 (whisper encoder + cross-KV, or LM prompt
@@ -24,6 +25,12 @@ Free slots keep decoding garbage — that is the fixed-shape contract (the
 batch always computes all ``n_slots`` rows; the paper's CGLA keeps its
 lanes busy the same way) — and every insert overwrites the entire slot
 row, so stale state can never leak into a new request.
+
+The splices stay pure, but the pool's jits donate the pool (argument 0):
+XLA aliases the output to the input and writes the one row in place,
+instead of copying every leaf of the pool into a fresh output first. A
+pool state handed to ``insert`` or ``release`` is deleted by the call, so
+nothing may read it afterwards; the pool keeps the state it gets back.
 
 Sharded pools (DESIGN.md §13): with a serving mesh attached, the slot
 axis shards over the mesh's "data" axis (``model.slot_state_specs``) and
@@ -90,9 +97,9 @@ def slot_reset(pool: ServeState, slot: jax.Array) -> ServeState:
 
 # Module-level jits: shared across every pool instance, so repeatedly
 # constructing schedulers (tests, benchmarks) re-traces only on a genuinely
-# new pool shape.
-_INSERT_JIT = jax.jit(slot_insert)
-_RESET_JIT = jax.jit(slot_reset)
+# new pool shape. Both donate the pool, so the row is written in place.
+_INSERT_JIT = jax.jit(slot_insert, donate_argnums=0)
+_RESET_JIT = jax.jit(slot_reset, donate_argnums=0)
 
 
 class SlotKVPool:
@@ -139,8 +146,10 @@ class SlotKVPool:
             # per-pool jits with out_shardings pinned: the splice can
             # never silently un-shard the pool, whatever GSPMD would
             # propagate from the batch-1 request operand
-            self._insert_jit = jax.jit(slot_insert, out_shardings=shardings)
-            self._reset_jit = jax.jit(slot_reset, out_shardings=shardings)
+            self._insert_jit = jax.jit(slot_insert, out_shardings=shardings,
+                                       donate_argnums=0)
+            self._reset_jit = jax.jit(slot_reset, out_shardings=shardings,
+                                      donate_argnums=0)
             dsize = (mesh.shape["data"]
                      if "data" in mesh.axis_names else 1)
             if dsize > 1 and n_slots % dsize == 0:
@@ -188,7 +197,7 @@ class SlotKVPool:
         """Return ``slot`` to the free list. ``reset=False`` skips zeroing
         the row — safe because ``insert`` overwrites the entire slot before
         reuse and freed slots' garbage is never read (the scheduler's hot
-        path uses it; a reset is a full pool-state copy per eviction)."""
+        path uses it; the reset is one more dispatch per eviction)."""
         if reset:
             self.state = self._reset_jit(self.state, slot)
         bisect.insort(self._free_by_shard[self.slot_shard(slot)], slot)
@@ -221,12 +230,18 @@ class SlotKVPool:
         return int(total)
 
     # -- state ops ------------------------------------------------------
-    def splice_shape(self, req_state: ServeState) -> ServeState:
-        """The shapes ``insert`` of ``req_state`` returns: a whole pool
-        state, whatever one row it writes."""
-        return jax.eval_shape(self._insert_jit, self.state, 0, req_state)
+    def splice_writes(self, req_state: ServeState) -> ServeState:
+        """The shapes ``insert`` of ``req_state`` writes: the request's
+        state in slot layout and the pool's dtypes, one slot's row. The
+        rest of the pool stays where it is (the jit donates the pool)."""
+        row = jax.eval_shape(lambda r: model_lib.slot_layout(r, 1),
+                             req_state)
+        return jax.tree_util.tree_map(
+            lambda p, r: jax.ShapeDtypeStruct(r.shape, p.dtype),
+            self.state, row)
 
     def insert(self, slot: int, req_state: ServeState) -> None:
-        """Splice a batch-1 prefill state into ``slot`` (jitted; sharded
-        pools keep their slot-axis sharding via pinned out_shardings)."""
+        """Splice a batch-1 prefill state into ``slot`` in place (jitted,
+        the pool donated; sharded pools keep their slot-axis sharding via
+        pinned out_shardings)."""
         self.state = self._insert_jit(self.state, slot, req_state)

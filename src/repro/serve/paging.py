@@ -12,6 +12,10 @@ with vLLM-style paging restated under the repo's zero-retrace discipline
 table gathered inside the jitted step (``attention.PagedKVCache``), and
 every admission/eviction/preemption is a host-side table edit plus at most
 one pre-traced splice — the compiled decode step sees one shape forever.
+The splices (``paged_insert``, ``paged_attach``, ``paged_copy_page``) are
+pure functions; the pool's jits donate the state, so XLA writes a
+request's pages in place instead of copying both arenas first, and a
+state handed to a splice must not be read again.
 
 Pieces (DESIGN.md §15.1-§15.5):
 
@@ -223,9 +227,12 @@ def paged_copy_page(state: ServeState, src, dst) -> ServeState:
         self_v=ls.self_v.at[:, dst].set(ls.self_v[:, src])), state.step)
 
 
-_INSERT_JIT = jax.jit(paged_insert, static_argnames=("write_cross",))
-_ATTACH_JIT = jax.jit(paged_attach)
-_COPY_JIT = jax.jit(paged_copy_page)
+# every splice jit donates the state (argument 0): the arenas update in
+# place
+_INSERT_JIT = jax.jit(paged_insert, static_argnames=("write_cross",),
+                      donate_argnums=0)
+_ATTACH_JIT = jax.jit(paged_attach, donate_argnums=0)
+_COPY_JIT = jax.jit(paged_copy_page, donate_argnums=0)
 
 
 def _mel_digest(payload: np.ndarray) -> str:
@@ -326,9 +333,12 @@ class PagedKVPool:
             shardings = shard_rules.named(mesh, specs)
             self.state = jax.device_put(self.state, shardings)
             self._insert_jit = jax.jit(paged_insert, out_shardings=shardings,
-                                       static_argnames=("write_cross",))
-            self._attach_jit = jax.jit(paged_attach, out_shardings=shardings)
-            self._copy_jit = jax.jit(paged_copy_page, out_shardings=shardings)
+                                       static_argnames=("write_cross",),
+                                       donate_argnums=0)
+            self._attach_jit = jax.jit(paged_attach, out_shardings=shardings,
+                                       donate_argnums=0)
+            self._copy_jit = jax.jit(paged_copy_page, out_shardings=shardings,
+                                     donate_argnums=0)
             ls_sh = shardings.layer_states
             self._table_shardings = (ls_sh.block_table, ls_sh.cross_table)
             dsize = (mesh.shape["data"] if "data" in mesh.axis_names else 1)
@@ -521,18 +531,30 @@ class PagedKVPool:
     def insert(self, slot: int, req_state: ServeState,
                write_cross: bool = True) -> None:
         """Splice a batch-1 contiguous prefill/replay state into the
-        arenas at ``slot``'s allocated pages (jitted; sharded pools keep
-        their sharding via pinned out_shardings)."""
+        arenas at ``slot``'s allocated pages, in place (jitted, the state
+        donated; sharded pools keep their sharding via pinned
+        out_shardings)."""
         self.state = self._insert_jit(
             self.state, slot, jnp.asarray(self._bt[slot]),
             jnp.asarray(self._ct[slot]), req_state, write_cross=write_cross)
 
-    def splice_shape(self, req_state: ServeState) -> ServeState:
-        """The shapes ``insert`` of ``req_state`` returns: the whole
-        arenas and tables, whatever pages it writes."""
-        return jax.eval_shape(self._insert_jit, self.state, 0,
-                              self._bt[0], self._ct[0], req_state,
-                              write_cross=True)
+    def splice_writes(self, req_state: ServeState) -> tuple:
+        """The shapes ``insert`` of ``req_state`` writes on a prefix miss:
+        the request's self-KV pages, its cross-KV pages and its counters
+        (``paged_insert``'s updates). The rest of the arenas stays where
+        it is (the jit donates the state)."""
+        ls = self.state.layer_states
+        s_req = req_state.layer_states.self_kv.k.shape[2]
+        n_self = min(self.max_pages, -(-s_req // self.page_size))
+
+        def pages(arena, n):
+            return jax.ShapeDtypeStruct((arena.shape[0], n) + arena.shape[2:],
+                                        arena.dtype)
+        return (pages(ls.self_k, n_self), pages(ls.self_v, n_self),
+                pages(ls.cross_k, self.n_cross_per_req),
+                pages(ls.cross_v, self.n_cross_per_req),
+                jax.ShapeDtypeStruct(ls.length.shape[:1], ls.length.dtype),
+                jax.ShapeDtypeStruct((), self.state.step.dtype))
 
     def attach_reset(self, slot: int) -> None:
         """Device-side half of a share-hit admission: zero the slot's

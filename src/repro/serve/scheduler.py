@@ -151,8 +151,8 @@ class ContinuousBatchingScheduler:
             self._done0 = jax.device_put(
                 self._done0, NamedSharding(mesh, P("data")))
         if self.telemetry is not None:
-            # each admission's splice returns a whole new pool state
-            # (DESIGN.md §11.1); gauged once, from the program's shapes
+            # each admission's splice writes one request's row into the
+            # pool in place (DESIGN.md §11.1); gauged once, from shapes
             self.telemetry.gauge("repro_splice_written_bytes",
                                  self._splice_written_bytes())
         self._next_rid = 0
@@ -182,9 +182,9 @@ class ContinuousBatchingScheduler:
                           mesh=eng.mesh)
 
     def _splice_written_bytes(self) -> int:
-        """Bytes one admission's splice program returns, from the shapes
-        of a batch-1 prefill state (``init_serve_state`` at batch 1, as
-        the prefill builds it) spliced into this pool."""
+        """Bytes one admission's splice writes into the pool, from the
+        shapes of a batch-1 prefill state (``init_serve_state`` at batch
+        1, as the prefill builds it) spliced into this pool."""
         eng, cfg = self.engine, self.engine.cfg
         memory = (jax.ShapeDtypeStruct((1, self.n_frames, cfg.d_model),
                                        model_lib._dtype(cfg))
@@ -193,7 +193,7 @@ class ContinuousBatchingScheduler:
             lambda p, m: model_lib.init_serve_state(p, cfg, 1, eng.max_len,
                                                     memory=m),
             eng._serve_params, memory)
-        return model_lib.state_kv_bytes(self.pool.splice_shape(req))
+        return model_lib.state_kv_bytes(self.pool.splice_writes(req))
 
     # -- KV accounting (DESIGN.md §15.4) --------------------------------
     @property
@@ -453,7 +453,7 @@ class ContinuousBatchingScheduler:
                 del self._active[slot]
                 # reset=False: insert() fully overwrites the slot on the
                 # next admission and freed rows' garbage is never read —
-                # skipping the reset saves a pool-state copy per eviction
+                # skipping the reset saves a dispatch per eviction
                 self.pool.release(slot, reset=False)
         return events
 

@@ -32,6 +32,7 @@ draft/verifier FLOP gap.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
@@ -82,11 +83,14 @@ def accept_spec(drafts: np.ndarray, vtoks: np.ndarray
     return accept_len, committed, accept_len + 1
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0, keep_unused=True)
 def _rollback(state, new_len):
     """Jitted per-slot length splice (DESIGN.md §17.1): one compiled
     program per state structure (verifier + draft), zero retraces across
-    rounds — mixed accept lengths are data, not shapes."""
+    rounds — mixed accept lengths are data, not shapes. The state is
+    donated, so the lengths update in place and the caller's old state
+    must not be read again; ``keep_unused`` keeps the old counters, which
+    are overwritten whole, as arguments that can lend their buffers."""
     return model_lib.set_slot_lengths(state, new_len)
 
 

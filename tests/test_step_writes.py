@@ -172,9 +172,9 @@ def test_step_bytes_gauges(kind, whisper_setup, lm_setup):
 @pytest.mark.parametrize("kind", ["contiguous", "paged"])
 @pytest.mark.parametrize("arch", ["whisper-tiny", "whisper-large-v3"])
 def test_splice_bytes_gauge(arch, kind):
-    """Set once with the pool, from the splice program's shapes: an
-    admission's splice returns the whole pool state today, far more than
-    a decode step returns."""
+    """Set once with the pool, from the splice's shapes: an admission
+    writes one slot's row (contiguous pool) or the request's pages and
+    counters (paged pool) in place, not the whole pool."""
     cfg = get_smoke_config(arch)
     params = M.init_params(jax.random.PRNGKey(1), cfg, 64)
     tele = obs.Telemetry()
@@ -185,9 +185,17 @@ def test_splice_bytes_gauge(arch, kind):
              eng.scheduler(n_slots=2, n_frames=N_FRAMES))
     g = tele.metrics.snapshot()["gauges"]
     splice = g["repro_splice_written_bytes"][""]
-    assert splice == M.state_kv_bytes(sched.pool.state)
+    pool = sched.pool
+    if kind == "paged":
+        # every self page of a max_len request, its cross pages, and its
+        # (R,) lengths and step counter
+        assert splice == (pool.max_pages * pool.page_bytes
+                          + pool.n_cross_per_req * pool.cross_page_bytes
+                          + 4 * (cfg.num_layers + 1))
+    else:
+        assert splice == M.state_kv_bytes(pool.state) // sched.n_slots
+    assert splice < M.state_kv_bytes(pool.state)
     _submit(sched, kind, 1, max_new=2)
     sched.run()
     g = tele.metrics.snapshot()["gauges"]
     assert g["repro_splice_written_bytes"][""] == splice
-    assert splice > g["repro_step_written_bytes"][""]

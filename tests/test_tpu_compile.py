@@ -6,8 +6,10 @@ Whisper-tiny, -base, -small and -large-v3 widths — every weight GEMM of
 the model, the tied vocab readout included — with the tilings the offload
 engine's plan and the untuned defaults resolve, and the first candidates
 the autotuner's space emits; whisper-tiny's served prefill and slot step
-with the native kernels; and whisper-large-v3's served prefill, slot step
-and slot splice at its benchmark deployment, with what they hold in HBM.
+with the native kernels; whisper-large-v3's served prefill, slot step
+and slot splice at its benchmark deployment, with what they hold in HBM;
+and the slot splice at each benchmark deployment, updating the pool in
+place.
 Nothing runs: a pass says the chip's compiler accepts the program, not
 that its results are right (the interpret-mode parity tests say that).
 
@@ -17,6 +19,7 @@ every worker imports this file."""
 import functools
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +76,22 @@ def _compile(one_chip, fn, *shapes):
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.memory_analysis() is not None
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _deployment(name):
+    """(arch, n_slots, max_len) of a benchmark configuration."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chip_bench", "configs",
+                           f"{name}.json")) as f:
+        spec = json.load(f)
+    dep = spec["deployment"]
+    return spec["arch"], dep["n_slots"], dep["max_len"]
+
+
+def _laid_out_bytes(tree):
+    """Bytes ``tree`` takes on the chip, in its tiled layouts."""
+    return jax.jit(lambda t: jnp.zeros(()), keep_unused=True).lower(
+        tree).compile().memory_analysis().argument_size_in_bytes
 
 
 def _weight_shapes(cfg):
@@ -176,21 +195,16 @@ def test_whisper_large_v3_served_programs_fit(one_chip, no_persistent_cache):
     count: its batch-1 prefill (32-layer encoder scan, K = 5120 down
     projection), its decode step over the pool, and the slot splice
     compile as on the chip. Built from shapes alone (nothing is
-    allocated): the Q8_0 weights, the pool and the splice's output, which
-    is a whole second pool until the old one is freed, fit one chip's
-    16 GiB."""
+    allocated): the Q8_0 weights and the pool, which the splice updates
+    in place, fit one chip's 16 GiB."""
     from repro.backends import platform
     from repro.core.qformats import quantize_tree
     from repro.models import model as model_lib
     from repro.serve import kvcache
     from repro.serve.engine import ServeEngine, _keep_dense
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "chip_bench", "configs",
-                           "whisper-large-v3-q8.json")) as f:
-        dep = json.load(f)["deployment"]
-    n, max_len = dep["n_slots"], dep["max_len"]
-    cfg = get_config("whisper-large-v3")
+    arch, n, max_len = _deployment("whisper-large-v3-q8")
+    cfg = get_config(arch)
     dense = jax.eval_shape(
         lambda k: model_lib.init_params(k, cfg, 448), jax.random.PRNGKey(0))
     served = jax.eval_shape(lambda p: quantize_tree(p, _keep_dense), dense)
@@ -223,10 +237,10 @@ def test_whisper_large_v3_served_programs_fit(one_chip, no_persistent_cache):
                 ("step", eng._step_jit,
                  (p, *on_chip((jax.ShapeDtypeStruct((n, 1), jnp.int32),
                                jax.ShapeDtypeStruct((n,), bool))), pool)),
-                ("splice", kvcache.slot_insert,
+                ("splice", kvcache._INSERT_JIT,
                  (pool, jax.ShapeDtypeStruct((), jnp.int32,
                                              sharding=one_chip), req))):
-            compiled = jax.jit(fn).lower(*args).compile()
+            compiled = fn.lower(*args).compile()
             mems[name] = compiled.memory_analysis()
             assert mems[name] is not None
             if name != "splice":
@@ -235,14 +249,56 @@ def test_whisper_large_v3_served_programs_fit(one_chip, no_persistent_cache):
         platform.reset_probe_cache()
     pool_bytes = model_lib.state_kv_bytes(pool)
     step, splice = mems["step"], mems["splice"]
-    # the splice returns a whole pool (laid out in the chip's tiles); the
-    # step returns the self-KV, under a sixth of one (DESIGN.md §11.2)
-    assert pool_bytes <= splice.output_size_in_bytes < 1.1 * pool_bytes
+    # the splice's output is the pool it was given (laid out in the
+    # chip's tiles); the step returns the self-KV, under a sixth of one
+    # (DESIGN.md §11.2)
+    laid_out = _laid_out_bytes(pool)
+    assert pool_bytes <= laid_out < 1.1 * pool_bytes
+    assert splice.alias_size_in_bytes == laid_out
     assert step.output_size_in_bytes < pool_bytes // 6
-    # the weights as laid out on the chip: a program that takes them all
-    weights = jax.jit(lambda t: jnp.zeros(()), keep_unused=True).lower(
-        p).compile().memory_analysis().argument_size_in_bytes
+    # the weights as laid out on the chip
+    weights = _laid_out_bytes(p)
     assert weights >= model_lib.state_kv_bytes(served)
-    # held at once: the weights, the pool and the splice's new pool
-    held = weights + 2 * splice.output_size_in_bytes
+    # held at once: the weights, the one pool and the splice's scratch
+    held = weights + laid_out + splice.temp_size_in_bytes
     assert held < 16 * 2**30
+
+
+@pytest.mark.parametrize("name", ["whisper-tiny-q8", "whisper-small-q8",
+                                  "whisper-large-v3-q8"])
+def test_splice_updates_the_pool_in_place(one_chip, no_persistent_cache,
+                                          name):
+    """The pool's splice at each benchmark deployment, compiled for the
+    chip: its output aliases the whole pool as laid out, it needs no
+    scratch, and no leaf of the pool is copied (DESIGN.md §11.1)."""
+    from repro.models import model as model_lib
+    from repro.serve import kvcache
+
+    arch, n, max_len = _deployment(name)
+    cfg = get_config(arch)
+    dtype = model_lib._dtype(cfg)
+    params = jax.eval_shape(
+        lambda k: model_lib.init_params(k, cfg, 448), jax.random.PRNGKey(0))
+
+    def state(b):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(lambda pp: model_lib.slot_layout(
+                model_lib.init_serve_state(
+                    pp, cfg, b, max_len, memory=jnp.zeros(
+                        (b, cfg.encoder_ctx, cfg.d_model), dtype)), b),
+                params))
+
+    pool = state(n)
+    compiled = kvcache._INSERT_JIT.lower(
+        pool, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        state(1)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _laid_out_bytes(pool)
+    assert mem.temp_size_in_bytes == 0
+    leaves = {f"[{','.join(map(str, a.shape))}]"
+              for a in jax.tree_util.tree_leaves(pool)}
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r"\bcopy(-start)?\(", line)]
+    assert not [c for c in copies if any(leaf in c for leaf in leaves)]
