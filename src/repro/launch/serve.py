@@ -103,20 +103,12 @@ def main(argv=None):
     rng = np.random.default_rng(args.seed)
     # --full serves the published 30 s encoder window
     n_frames = cfg.encoder_ctx if args.full else 64
-    if cfg.family == "audio":
-        mel = rng.standard_normal(
-            (args.requests, n_frames, cfg.n_mels)).astype(np.float32)
-        payloads = [mel[i:i + 1] for i in range(args.requests)]
-    else:
-        prompts = rng.integers(
-            0, cfg.vocab_size, (args.requests, 8)).astype(np.int32)
-        payloads = [prompts[i:i + 1] for i in range(args.requests)]
+    batch = engine.family.random_batch(cfg, rng, args.requests, n_frames)
+    payloads = [batch[i:i + 1] for i in range(args.requests)]
 
     attribution = None
     if args.continuous:
-        sched = engine.scheduler(n_slots=args.slots,
-                                 n_frames=n_frames if cfg.family == "audio"
-                                 else None)
+        sched = engine.scheduler(n_slots=args.slots, n_frames=n_frames)
         rids = [sched.submit(p, max_new=args.max_new) for p in payloads]
         streamed = {r: 0 for r in rids}
 
@@ -136,7 +128,7 @@ def main(argv=None):
               f"{sum(streamed.values())} tokens streamed, "
               f"{sched.step_traces} step trace(s)")
     elif args.speculative:
-        if cfg.family != "audio":
+        if "verify" not in engine.family.serves:
             ap.error("--speculative serves the Whisper ladder "
                      "(audio archs, DESIGN.md §17)")
         dcfg = (get_config(args.draft) if args.full
@@ -144,15 +136,13 @@ def main(argv=None):
         dparams = model_lib.init_params(jax.random.PRNGKey(args.seed + 1),
                                         dcfg, max_positions=512)
         spec = engine.speculative(dcfg, dparams, k=args.k)
-        results = spec.transcribe(mel, max_new=args.max_new)
+        results = spec.transcribe(batch, max_new=args.max_new)
         print(f"speculative: draft={args.draft} k={args.k} "
               f"acceptance={spec.acceptance_rate():.2f} "
               f"rounds={spec.rounds} "
               f"verify_traces={spec.stats()['verify_traces']}")
-    elif cfg.family == "audio":
-        results = engine.transcribe(mel, max_new=args.max_new)
     else:
-        results = engine.generate(prompts, max_new=args.max_new)
+        results = engine.generate(batch, max_new=args.max_new)
 
     for i, r in enumerate(results):
         print(f"req{i}: {r.steps} tokens in {r.total_s:.3f}s "

@@ -6,6 +6,12 @@
   init_serve_state(params, cfg, batch, max_len) -> decode state pytree
   serve_step(params, cfg, token, state)       -> (logits, state')  one token
 
+What differs between families — the parameters, the backbone, the decode
+state, the request payload and how it prefills — lives in one
+``ServingFamily`` per family, chosen from ``cfg.family`` by ``family_of``
+(DESIGN.md §11.4). The functions below and ``serve/`` ask that object;
+nothing else tells Whisper from the token LMs.
+
 Batch dict conventions (mirrored by launch/input_specs.py):
   LM families : {"tokens": (B,S) i32, "labels": (B,S) i32}
   vlm         : + {"patches": (B,P,E_vis) f32}  — precomputed anyres tiles,
@@ -18,6 +24,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import layers, transformer, whisper
@@ -48,14 +55,13 @@ def step_writes(state: ServeState) -> ServeState:
     or the paged cross arenas and both page tables) is projected at
     admission and only read while a request decodes; a program that
     returned it would have XLA copy it whole into fresh output buffers on
-    every call, so those leaves come back as ``None``. LM states have no
-    read-only leaves and return whole."""
+    every call, so those leaves — the fields a state type declares in
+    ``READ_ONLY`` — come back as ``None``. LM states have no read-only
+    leaves and return whole."""
     ls = state.layer_states
-    if isinstance(ls, whisper.WhisperDecodeState):
-        ls = ls._replace(cross_kv=None)
-    elif isinstance(ls, whisper.WhisperPagedDecodeState):
-        ls = ls._replace(cross_k=None, cross_v=None, block_table=None,
-                         cross_table=None)
+    read_only = getattr(ls, "READ_ONLY", ())
+    if read_only:
+        ls = ls._replace(**dict.fromkeys(read_only))
     return ServeState(layer_states=ls, step=state.step)
 
 
@@ -65,13 +71,11 @@ def with_step_writes(state: ServeState, written: ServeState) -> ServeState:
     reference. ``written`` may also be a full state: only its written
     leaves are read."""
     ls, w = state.layer_states, written.layer_states
-    if isinstance(ls, whisper.WhisperDecodeState):
-        ls = ls._replace(self_kv=w.self_kv)
-    elif isinstance(ls, whisper.WhisperPagedDecodeState):
-        ls = ls._replace(self_k=w.self_k, self_v=w.self_v, length=w.length)
-    else:
-        ls = w
-    return ServeState(layer_states=ls, step=written.step)
+    read_only = getattr(ls, "READ_ONLY", ())
+    if read_only:
+        w = ls._replace(**{f: getattr(w, f) for f in ls._fields
+                           if f not in read_only})
+    return ServeState(layer_states=w, step=written.step)
 
 
 def _dtype(cfg: ModelConfig):
@@ -82,23 +86,7 @@ def _dtype(cfg: ModelConfig):
 # Init
 # ---------------------------------------------------------------------------
 def init_params(key, cfg: ModelConfig, max_positions: int = 0) -> dict:
-    if cfg.family == "audio":
-        return whisper.init_whisper(key, cfg, max_positions)
-    pdtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[cfg.param_dtype]
-    ks = jax.random.split(key, 4)
-    params = {
-        "embed": layers.init_embedding(ks[0], cfg.padded_vocab, cfg.d_model,
-                                       pdtype),
-        "stack": transformer.init_decoder_stack(ks[1], cfg),
-        "final_norm": layers.init_norm(cfg.d_model, cfg.norm, pdtype),
-    }
-    if not cfg.tie_embeddings:
-        params["lm_head"] = layers.init_linear(ks[2], cfg.d_model,
-                                               cfg.padded_vocab, dtype=pdtype)
-    if cfg.family == "vlm":
-        params["projector"] = layers.init_linear(
-            ks[3], cfg.vision_embed_dim, cfg.d_model, bias=True, dtype=pdtype)
-    return params
+    return family_of(cfg).init_params(key, cfg, max_positions)
 
 
 # ---------------------------------------------------------------------------
@@ -127,41 +115,13 @@ def _readout(params: dict, cfg: ModelConfig, x: jax.Array,
 # ---------------------------------------------------------------------------
 # Forward (train / prefill)
 # ---------------------------------------------------------------------------
-def hidden_forward(params: dict, cfg: ModelConfig,
-                   batch: Dict[str, jax.Array], *,
-                   engine=None, attn_chunk: int = 2048
-                   ) -> Tuple[jax.Array, jax.Array]:
-    """Backbone only: (final hidden states pre-readout, moe_aux_loss)."""
-    if cfg.family == "audio":
-        memory = whisper.encode(params, cfg, batch["mel"], engine=engine,
-                                attn_chunk=attn_chunk)
-        h = whisper.decode_train(params, cfg, batch["tokens"], memory,
-                                 engine=engine, attn_chunk=attn_chunk,
-                                 return_hidden=True)
-        return h, jnp.zeros((), jnp.float32)
-    x = _embed_inputs(params, cfg, batch, engine)
-    positions = jnp.arange(x.shape[1])[None, :]
-    x, aux = transformer.apply_decoder_stack(params["stack"], cfg, x,
-                                             positions=positions,
-                                             engine=engine,
-                                             attn_chunk=attn_chunk)
-    return x, aux
-
-
 def forward(params: dict, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
             engine=None, attn_chunk: int = 2048
             ) -> Tuple[jax.Array, jax.Array]:
     """Returns (logits, moe_aux_loss)."""
-    h, aux = hidden_forward(params, cfg, batch, engine=engine,
-                            attn_chunk=attn_chunk)
-    if cfg.family == "audio":
-        return whisper_readout(params, cfg, h, engine), aux
-    return _readout(params, cfg, h, engine), aux
-
-
-def whisper_readout(params: dict, cfg: ModelConfig, x: jax.Array,
-                    engine=None) -> jax.Array:
-    return whisper.readout(params, cfg, x, engine)
+    fam = family_of(cfg)
+    h, aux = fam.hidden(params, cfg, batch, engine, attn_chunk)
+    return fam.readout(params, cfg, h, engine), aux
 
 
 def _ce_of_logits(logits: jax.Array, labels: jax.Array,
@@ -192,10 +152,10 @@ def loss_fn(params: dict, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
     backward pass per chunk (remat) and bounds the logits temp at
     (B, ce_chunk, V).
     """
-    h, aux = hidden_forward(params, cfg, batch, engine=engine,
-                            attn_chunk=attn_chunk)
+    fam = family_of(cfg)
+    h, aux = fam.hidden(params, cfg, batch, engine, attn_chunk)
     labels = batch["labels"]
-    readout = (whisper_readout if cfg.family == "audio" else _readout)
+    readout = fam.readout
 
     b, s, d = h.shape
     n_chunks = s // ce_chunk if (s % ce_chunk == 0 and s > ce_chunk) else 1
@@ -297,12 +257,8 @@ def state_kv_bytes(state: Any) -> int:
 def init_serve_state(params: dict, cfg: ModelConfig, batch: int, max_len: int,
                      *, memory: Optional[jax.Array] = None, engine=None,
                      prefill_len: int = 0) -> ServeState:
-    if cfg.family == "audio":
-        assert memory is not None, "whisper decode needs encoder memory"
-        st = whisper.init_whisper_decode_state(params, cfg, memory, max_len,
-                                               engine=engine, dtype=_dtype(cfg))
-    else:
-        st = transformer.init_decode_state(cfg, batch, max_len, _dtype(cfg))
+    st = family_of(cfg).init_layer_states(params, cfg, batch, max_len,
+                                          memory, engine)
     return ServeState(layer_states=st, step=jnp.asarray(prefill_len, jnp.int32))
 
 
@@ -315,14 +271,8 @@ def serve_step(params: dict, cfg: ModelConfig, token: jax.Array,
     routing resolves from static shapes at trace time and nothing mutates
     host state, so serve/engine.py jits this step unconditionally
     (regression-tested by tests/test_plan.py)."""
-    if cfg.family == "audio":
-        logits, st = whisper.decode_step(params, cfg, token,
-                                         state.layer_states, engine=engine)
-        return logits, ServeState(st, state.step + 1)
-    x = layers.embed(params["embed"], token).astype(_dtype(cfg))
-    x, st = transformer.decode_step_stack(params["stack"], cfg, x,
-                                          state.layer_states, engine=engine)
-    logits = _readout(params, cfg, x, engine)
+    logits, st = family_of(cfg).decode(params, cfg, token,
+                                       state.layer_states, engine)
     return logits, ServeState(st, state.step + 1)
 
 
@@ -336,12 +286,9 @@ def verify_step(params: dict, cfg: ModelConfig, tokens: jax.Array,
     time — the token-exactness contract speculative acceptance relies
     on. Audio (whisper) only for now: the draft/verify ladder is the
     Whisper scaling study's regime (tiny drafts, base/small verifies)."""
-    if cfg.family != "audio":
-        raise NotImplementedError(
-            "speculative verify windows are wired for the audio family "
-            "(the Whisper ladder); LM families still serve_step one token")
-    logits, st = whisper.verify_step(params, cfg, tokens,
-                                     state.layer_states, engine=engine)
+    fam = family_of(cfg)
+    fam.require("verify")
+    logits, st = fam.verify(params, cfg, tokens, state.layer_states, engine)
     return logits, ServeState(st, state.step + tokens.shape[1])
 
 
@@ -353,25 +300,16 @@ def set_slot_lengths(state: ServeState, new_len: jax.Array) -> ServeState:
     over-written KV entries beyond ``new_len`` stay in place (masked by
     the validity test, then overwritten by the next window).
 
-    Structural rule, the inverse discipline of ``slot_layout``: in the
-    slot layout the counters are exactly the ``ndim <= 2`` leaves —
-    ``step`` (B,) and layer-stacked lengths (R, B) — and every data leaf
-    is ``ndim >= 3``, so counters broadcast-assign from ``new_len`` and
-    data passes through untouched.
-
-    The paged layout (DESIGN.md §15.2) breaks that structural rule: its
-    block/cross tables are ndim-2 *data* leaves (B, max_pages) int32, so
-    it splices by field name instead — only ``length`` (R, B) and
-    ``step`` rewind; the tables and page arenas pass through untouched
-    (rejected-suffix *pages* are released host-side by the paged
-    scheduler's post-round trim, DESIGN.md §17.4)."""
+    Structural rule, the inverse discipline of ``slot_layout``: among the
+    leaves a decode step writes (``step_writes``), the counters are
+    exactly the ``ndim <= 2`` ones — ``step`` (B,) and layer-stacked
+    lengths (R, B) — and every data leaf is ``ndim >= 3``, so counters
+    broadcast-assign from ``new_len`` and data passes through untouched.
+    The read-only leaves pass through too: the paged layout's block and
+    cross tables (DESIGN.md §15.2) are ndim-2 *data* leaves, and its
+    rejected-suffix *pages* are released host-side by the paged
+    scheduler's post-round trim (DESIGN.md §17.4)."""
     new_len = jnp.asarray(new_len, jnp.int32)
-    ls = state.layer_states
-    if isinstance(ls, whisper.WhisperPagedDecodeState):
-        ls = ls._replace(
-            length=jnp.broadcast_to(new_len[None, :], ls.length.shape))
-        return ServeState(layer_states=ls,
-                          step=jnp.broadcast_to(new_len, state.step.shape))
 
     def conv(a):
         if a.ndim == 1:                       # (B,) unstacked counter
@@ -380,28 +318,228 @@ def set_slot_lengths(state: ServeState, new_len: jax.Array) -> ServeState:
             return jnp.broadcast_to(new_len[None, :], a.shape)
         return a
 
-    return ServeState(
-        layer_states=jax.tree_util.tree_map(conv, state.layer_states),
-        step=jnp.broadcast_to(new_len, state.step.shape))
+    written = jax.tree_util.tree_map(conv, step_writes(state).layer_states)
+    return with_step_writes(state, ServeState(
+        layer_states=written,
+        step=jnp.broadcast_to(new_len, state.step.shape)))
 
 
-def prefill(params: dict, cfg: ModelConfig, batch: Dict[str, jax.Array],
-            state: ServeState, *, engine=None, attn_chunk: int = 2048
-            ) -> Tuple[jax.Array, ServeState]:
-    """Sequence prefill that fills the decode caches, returning last-token
-    logits. Implemented as a scan of serve_step for state-carrying families
-    (correct, if not flash-fast; the prefill_32k dry-run cells lower
-    ``forward`` instead, which is the throughput path). This is the
-    serving engine's LM prefill: one jitted call replaces the former
-    per-token Python loop, and its dispatch plan records one scan-body
-    execution — the ledger commits it ``seq_len`` times (DESIGN.md §10.2)."""
-    tokens = batch["tokens"]
-    s = tokens.shape[1]
+# ---------------------------------------------------------------------------
+# Serving families (DESIGN.md §11.4)
+# ---------------------------------------------------------------------------
+class ServingFamily:
+    """What the model functions above and ``serve/`` need to know about a
+    model family: its parameters and backbone, the decode state it carries,
+    the request payload and how it prefills, the first decode input, the
+    family's part of the plan keys, and what it can serve. The base class
+    holds what both families share; ``family_of`` picks the instance."""
+    payload_rank = 1         # one request's payload: an (S,) prompt
+    payload_dims = "S"
+    payload_axis = "seq"     # name of a batch payload's axis 1 (span args)
+    serves: frozenset = frozenset()     # "verify" windows, the "paged" pool
 
-    def body(st, t):
-        tok = jax.lax.dynamic_slice_in_dim(tokens, t, 1, axis=1)
-        logits, st = serve_step(params, cfg, tok, st, engine=engine)
-        return st, logits
+    def require(self, what: str) -> None:
+        """Raise unless this family serves ``what`` (``"verify"`` windows
+        for speculative decoding, the ``"paged"`` pool)."""
+        if what not in self.serves:
+            raise NotImplementedError(
+                f"{what!r} serving is wired for the audio family only (the "
+                f"Whisper ladder, DESIGN.md §15, §17); token LMs decode one "
+                f"token a step over the contiguous slot pool")
 
-    state, logits = jax.lax.scan(body, state, jnp.arange(s))
-    return logits[-1], state
+    def request(self, payload: Any, n_frames: Optional[int]) -> np.ndarray:
+        """One request's payload as a batch-1 array, fitted to the pool's
+        geometry. One request per submit: a stacked batch would splice
+        several rows into one slot and corrupt its neighbours' state."""
+        arr = np.asarray(payload)
+        if arr.ndim == self.payload_rank:
+            arr = arr[None]
+        if arr.ndim != self.payload_rank + 1 or arr.shape[0] != 1:
+            raise ValueError(
+                f"submit() takes ONE request — expected shape "
+                f"({self.payload_dims},) or batch-1, got {arr.shape}; "
+                f"submit rows separately")
+        return arr
+
+    def empty_state(self, params: dict, cfg: ModelConfig, batch: int,
+                    max_len: int, n_frames: Optional[int]) -> ServeState:
+        """A ``batch``-row decode state holding no request — the slot
+        pool's initial state, in the standard layout."""
+        return init_serve_state(params, cfg, batch, max_len)
+
+    def warm_tuning(self, cfg: ModelConfig, offload, *,
+                    quant: Optional[str], **shape) -> int:
+        """Pre-tune the GEMM shapes of one inference (DESIGN.md §9.4);
+        returns the number of shapes tuned, 0 where the family has no
+        enumerated workload."""
+        return 0
+
+
+class _TokenLM(ServingFamily):
+    """Every decoder-only family (dense, MoE, SSM, hybrid, VLM): a request
+    is a prompt, prefilled by a scan of the one-token step."""
+
+    def init_params(self, key, cfg, max_positions):
+        pdtype = {"bfloat16": jnp.bfloat16,
+                  "float32": jnp.float32}[cfg.param_dtype]
+        ks = jax.random.split(key, 4)
+        params = {
+            "embed": layers.init_embedding(ks[0], cfg.padded_vocab,
+                                           cfg.d_model, pdtype),
+            "stack": transformer.init_decoder_stack(ks[1], cfg),
+            "final_norm": layers.init_norm(cfg.d_model, cfg.norm, pdtype),
+        }
+        if not cfg.tie_embeddings:
+            params["lm_head"] = layers.init_linear(
+                ks[2], cfg.d_model, cfg.padded_vocab, dtype=pdtype)
+        if cfg.family == "vlm":
+            params["projector"] = layers.init_linear(
+                ks[3], cfg.vision_embed_dim, cfg.d_model, bias=True,
+                dtype=pdtype)
+        return params
+
+    def hidden(self, params, cfg, batch, engine, attn_chunk):
+        """The backbone: (hidden states before the readout, MoE aux loss)."""
+        x = _embed_inputs(params, cfg, batch, engine)
+        positions = jnp.arange(x.shape[1])[None, :]
+        return transformer.apply_decoder_stack(params["stack"], cfg, x,
+                                               positions=positions,
+                                               engine=engine,
+                                               attn_chunk=attn_chunk)
+
+    def readout(self, params, cfg, x, engine=None):
+        return _readout(params, cfg, x, engine)
+
+    def init_layer_states(self, params, cfg, batch, max_len, memory, engine):
+        return transformer.init_decode_state(cfg, batch, max_len, _dtype(cfg))
+
+    def decode(self, params, cfg, token, layer_states, engine):
+        x = layers.embed(params["embed"], token).astype(_dtype(cfg))
+        x, st = transformer.decode_step_stack(params["stack"], cfg, x,
+                                              layer_states, engine=engine)
+        return _readout(params, cfg, x, engine), st
+
+    def prefill(self, params, cfg, tokens, max_len, engine):
+        """A scan of ``serve_step`` over the prompt that fills the decode
+        caches and returns the last logits (correct, if not flash-fast;
+        the prefill_32k dry-run cells lower ``forward`` instead, which is
+        the throughput path). Its plan records one scan body, so the
+        ledger commits it once per prompt token (DESIGN.md §10.2)."""
+        state = init_serve_state(params, cfg, tokens.shape[0], max_len)
+
+        def body(st, t):
+            tok = jax.lax.dynamic_slice_in_dim(tokens, t, 1, axis=1)
+            logits, st = serve_step(params, cfg, tok, st, engine=engine)
+            return st, logits
+
+        state, logits = jax.lax.scan(body, state, jnp.arange(tokens.shape[1]))
+        return logits[-1], state
+
+    def prefill_times(self, payload) -> int:
+        return payload.shape[1]
+
+    def first_token(self, out, sot_id, argmax):
+        """The greedy pick after the prompt: (B, 1), on the device."""
+        return argmax(out[:, -1])[:, None]
+
+    def step_extra(self, n_frames) -> tuple:
+        return ()
+
+    def random_batch(self, cfg, rng, batch: int, n_frames: int) -> np.ndarray:
+        """``batch`` random 8-token prompts; an LM request has no frames."""
+        return rng.integers(0, cfg.vocab_size, (batch, 8)).astype(np.int32)
+
+
+class _Audio(ServingFamily):
+    """Whisper: a request is a mel spectrogram padded to the pool's frame
+    capacity, prefilled by the encoder and the per-layer cross-KV
+    projection (paper Fig 1); decoding starts from the SOT token."""
+    payload_rank = 2
+    payload_dims = "F, n_mels"
+    payload_axis = "frames"
+    serves = frozenset({"verify", "paged"})
+
+    def init_params(self, key, cfg, max_positions):
+        return whisper.init_whisper(key, cfg, max_positions)
+
+    def hidden(self, params, cfg, batch, engine, attn_chunk):
+        memory = whisper.encode(params, cfg, batch["mel"], engine=engine,
+                                attn_chunk=attn_chunk)
+        h = whisper.decode_train(params, cfg, batch["tokens"], memory,
+                                 engine=engine, attn_chunk=attn_chunk,
+                                 return_hidden=True)
+        return h, jnp.zeros((), jnp.float32)
+
+    def readout(self, params, cfg, x, engine=None):
+        return whisper.readout(params, cfg, x, engine)
+
+    def init_layer_states(self, params, cfg, batch, max_len, memory, engine):
+        assert memory is not None, "whisper decode needs encoder memory"
+        return whisper.init_whisper_decode_state(params, cfg, memory, max_len,
+                                                 engine=engine,
+                                                 dtype=_dtype(cfg))
+
+    def decode(self, params, cfg, token, layer_states, engine):
+        return whisper.decode_step(params, cfg, token, layer_states,
+                                   engine=engine)
+
+    def verify(self, params, cfg, tokens, layer_states, engine):
+        return whisper.verify_step(params, cfg, tokens, layer_states,
+                                   engine=engine)
+
+    def prefill(self, params, cfg, mel, max_len, engine):
+        """The encoder once per utterance batch, then the decode state
+        with its cross-K/V projected from the encoder memory."""
+        memory = whisper.encode(params, cfg, mel, engine=engine)
+        state = init_serve_state(params, cfg, mel.shape[0], max_len,
+                                 memory=memory, engine=engine)
+        return memory, state
+
+    def prefill_times(self, payload) -> int:
+        return 1
+
+    def first_token(self, out, sot_id, argmax):
+        """The SOT token seeds every row: (B, 1), on the host."""
+        return np.full((out.shape[0], 1), sot_id, np.int32)
+
+    def step_extra(self, n_frames) -> tuple:
+        return (n_frames,)
+
+    def request(self, payload, n_frames):
+        arr = super().request(payload, n_frames)
+        f = arr.shape[1]
+        if f > n_frames:
+            raise ValueError(f"utterance has {f} frames > pool "
+                             f"capacity {n_frames}")
+        if f < n_frames:
+            arr = np.pad(arr, ((0, 0), (0, n_frames - f), (0, 0)))
+        return arr
+
+    def empty_state(self, params, cfg, batch, max_len, n_frames):
+        if n_frames is None:
+            raise ValueError("audio serving needs n_frames, the pool's fixed "
+                             "mel-frame capacity (utterances are padded to "
+                             "it)")
+        # zeros memory only shapes the cross-KV rows; a splice overwrites
+        # them with the request's own. engine=None: building the pool must
+        # not touch the offload ledger.
+        memory = jnp.zeros((batch, n_frames, cfg.d_model), _dtype(cfg))
+        return init_serve_state(params, cfg, batch, max_len, memory=memory)
+
+    def warm_tuning(self, cfg, offload, *, quant, **shape):
+        return whisper.warm_tuning(cfg, offload, quant=quant, **shape)
+
+    def random_batch(self, cfg, rng, batch, n_frames):
+        """``batch`` random mel spectrograms of ``n_frames`` frames."""
+        return rng.standard_normal(
+            (batch, n_frames, cfg.n_mels)).astype(np.float32)
+
+
+TOKEN_LM = _TokenLM()
+AUDIO = _Audio()
+
+
+def family_of(cfg: ModelConfig) -> ServingFamily:
+    """The serving family of ``cfg`` — the one place that tells Whisper
+    from the token LMs by ``cfg.family``."""
+    return AUDIO if cfg.family == "audio" else TOKEN_LM

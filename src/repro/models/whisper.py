@@ -30,6 +30,9 @@ class WhisperDecodeState(NamedTuple):
     self_kv: List[KVCache]          # stacked (R, ...) decoder self-attn cache
     cross_kv: Tuple[jax.Array, jax.Array]  # (R, B, F, Hkv, hd) x2, fixed
 
+    # fields a decode step reads and never writes (model.step_writes)
+    READ_ONLY = ("cross_kv",)
+
 
 class WhisperPagedDecodeState(NamedTuple):
     """Paged slot-pool decode state (DESIGN.md §15.2): self-attn KV and
@@ -46,6 +49,9 @@ class WhisperPagedDecodeState(NamedTuple):
     block_table: jax.Array   # (B, max_pages) i32 — self logical -> physical
     cross_table: jax.Array   # (B, n_cross_pages) i32 — frames -> physical
     length: jax.Array        # (R, B) i32 — tokens valid per layer/slot
+
+    # fields a decode step reads and never writes (model.step_writes)
+    READ_ONLY = ("cross_k", "cross_v", "block_table", "cross_table")
 
 
 def warm_tuning(cfg: ModelConfig, engine, *, n_frames: int = 1500,

@@ -58,7 +58,6 @@ from repro.core.offload import OffloadEngine
 from repro.core.plan import DispatchPlan, PlanCache, plan_key, record_plan
 from repro.core.qformats import quantize_tree
 from repro.models import model as model_lib
-from repro.models import whisper as whisper_lib
 from repro.sharding import ctx as shard_ctx
 from repro.sharding import rules as shard_rules
 
@@ -136,6 +135,9 @@ class ServeEngine:
         else:
             self._serve_params = self.params
         cfg = self.cfg
+        # what this engine's family serves and how it prefills
+        # (DESIGN.md §11.4)
+        self.family = model_lib.family_of(cfg)
         # Pre-tune the canonical single-utterance workload (full 30s
         # window) so the common case never pays a first-invocation sweep
         # (DESIGN.md §9.4); transcribe() re-warms for the actual batch and
@@ -143,8 +145,7 @@ class ServeEngine:
         # *resolved* quantization q, which may override cfg.quant.
         self._serve_quant = q
         if (self.offload is not None and self.offload.tuner is not None
-                and cfg.family == "audio"):
-            whisper_lib.warm_tuning(cfg, self.offload, quant=q)
+                and self.family.warm_tuning(cfg, self.offload, quant=q)):
             self.offload.tuner.save()
 
         if self.mesh is not None:
@@ -219,27 +220,13 @@ class ServeEngine:
         self._verify_fn = verify_core
         self._verify_jit = jax.jit(verify_fn)
 
-        if cfg.family == "audio":
-            def prefill_fn(params, mel):
-                """Whisper prefill: encoder once per utterance batch +
-                per-layer cross-K/V projection (paper Fig 1)."""
-                with shard_ctx.activation_sharding(mesh):
-                    memory = whisper_lib.encode(params, cfg, mel,
-                                                engine=engine)
-                    state = model_lib.init_serve_state(
-                        params, cfg, mel.shape[0], self.max_len,
-                        memory=memory, engine=engine)
-                    return memory, state
-        else:
-            def prefill_fn(params, tokens):
-                """LM prefill: one traced scan of serve_step over the
-                prompt (fills the decode caches, returns last logits)."""
-                with shard_ctx.activation_sharding(mesh):
-                    state = model_lib.init_serve_state(
-                        params, cfg, tokens.shape[0], self.max_len)
-                    return model_lib.prefill(params, cfg,
-                                             {"tokens": tokens},
-                                             state, engine=engine)
+        family, max_len = self.family, self.max_len
+
+        def prefill_fn(params, payload):
+            """The family's batch prefill (DESIGN.md §11.4): Whisper's
+            encoder and cross-KV projection, or an LM's prompt scan."""
+            with shard_ctx.activation_sharding(mesh):
+                return family.prefill(params, cfg, payload, max_len, engine)
 
         self._prefill_fn = prefill_fn
         self._prefill_jit = jax.jit(prefill_fn)
@@ -293,6 +280,68 @@ class ServeEngine:
         return self._plans.get_or_build(
             key, lambda: record_plan(self.offload, fn, *args, key=key))
 
+    def _commit(self, plan: Optional[DispatchPlan], times: int,
+                role: str) -> None:
+        if self.offload is not None:
+            self.offload.ledger.commit(plan, times=times, role=role)
+
+    # ------------------------------------------------------------------
+    # Admission API (DESIGN.md §11.4): the one place that keys, plans,
+    # runs and commits the engine's programs for the schedulers and the
+    # speculative engine. None of these waits on the device — each caller
+    # keeps its own spans, timers and sync.
+    # ------------------------------------------------------------------
+    def prefill(self, payload: jax.Array, *, role: str = "main"):
+        """Dispatch the batch prefill of ``payload`` — (B, F, n_mels) mels
+        or (B, S) prompts — and commit its plan to the ledger under
+        ``role`` (``"main"``, or ``"draft"``/``"verify"`` in a speculative
+        engine). Returns the program's ``(out, state)``: the encoder
+        memory or the last logits, and the standard-layout decode state."""
+        key = self._key("prefill", payload.shape[0], payload.shape[1])
+        plan = self._plan(key, self._prefill_fn, self._serve_params, payload)
+        out, state = self._prefill_jit(self._serve_params, payload)
+        self._commit(plan, self.family.prefill_times(payload), role)
+        return out, state
+
+    def first_token(self, out: jax.Array, sot_id: int):
+        """The first decode input after a prefill whose output is ``out``:
+        (B, 1) int32 — the SOT token (on the host) for Whisper, the greedy
+        pick after the prompt (on the device) for an LM."""
+        return self.family.first_token(out, sot_id, self._argmax)
+
+    def replay(self, state, tokens: List[int],
+               n_frames: Optional[int] = None, *, role: str = "main"):
+        """Feed ``tokens`` one at a time through the batch-1 decode from
+        ``state`` — the preempt-and-recompute replay (DESIGN.md §15.5,
+        §17.4) — and commit that many executions of the step's plan under
+        ``role``. Returns the advanced state."""
+        plan = self.program_plan(1, state, n_frames, role=role)
+        for t in tokens:
+            _, state = self._decode_jit(self._serve_params,
+                                        jnp.full((1, 1), t, jnp.int32), state)
+        self._commit(plan, len(tokens), role)
+        return state
+
+    def program_plan(self, batch: int, state, n_frames: Optional[int] = None,
+                     *, k: Optional[int] = None, pages: Optional[Any] = None,
+                     role: str = "main") -> Optional[DispatchPlan]:
+        """The plan of the decode step over ``state`` at ``batch`` rows or,
+        given ``k``, of the verify program over a (batch, k+1) window
+        (DESIGN.md §17.1), keyed with the pool's page geometry ``pages``
+        (DESIGN.md §15.5) and a speculative ``role`` (DESIGN.md §17.2)."""
+        extra = self.family.step_extra(n_frames)
+        key_role = None if role == "main" else role
+        if k is None:
+            key = self._key("step", batch, *extra, pages=pages, role=key_role)
+            fn, width = self._decode_fn, 1
+        else:
+            key = self._key("verify", batch, *extra, pages=pages,
+                            role=key_role, k=k)
+            fn, width = self._verify_fn, k + 1
+        return self._plan(key, fn, self._serve_params,
+                          jax.ShapeDtypeStruct((batch, width), jnp.int32),
+                          state)
+
     def _greedy_loop(self, state, first_token: jax.Array,
                      max_new: int) -> Dict[str, Any]:
         b = first_token.shape[0]
@@ -338,34 +387,12 @@ class ServeEngine:
     # ------------------------------------------------------------------
     def generate(self, prompts: np.ndarray, max_new: int = 32
                  ) -> List[GenerationResult]:
-        """LM families. prompts: (B, S_prompt) int32 (already padded).
-        Returns one result per request; ``tokens`` are the generated
-        tokens only (see the module-level token contract)."""
-        b, s = prompts.shape
-        tokens = jnp.asarray(prompts)
-        prefill_plan = self._plan(self._key("prefill", b, s),
-                                  self._prefill_fn, self._serve_params,
-                                  tokens)
-        t0 = time.perf_counter()
-        with obs.maybe_span(self.telemetry, "prefill", cat="engine",
-                            ledger=True, args={"batch": b, "seq": s}):
-            logits, state = self._prefill_jit(self._serve_params, tokens)
-            jax.block_until_ready(logits)
-            first = self._argmax(logits[:, -1])[:, None]
-            prefill_s = time.perf_counter() - t0
-            if self.offload is not None:
-                # the prefill plan records ONE scan-body execution; the
-                # scan runs once per prompt token; committing inside the
-                # ledger span attributes these FLOPs to prefill
-                self.offload.ledger.commit(prefill_plan, times=s)
-        step_plan = self._plan(self._key("step", b), self._decode_fn,
-                               self._serve_params, first, state)
-        with obs.maybe_span(self.telemetry, "decode", cat="engine",
-                            ledger=True, args={"batch": b}):
-            r = self._greedy_loop(state, first, max_new)
-            if self.offload is not None:
-                self.offload.ledger.commit(step_plan, times=r["steps"])
-        return self._finalize(r, prefill_s)
+        """One static batch, prefilled together and decoded to completion:
+        (B, S_prompt) int32 prompts (already padded) for LM families, or
+        (B, F, n_mels) mels seeded with SOT 1 for Whisper. Returns one
+        result per request; ``tokens`` are the generated tokens only (see
+        the module-level token contract)."""
+        return self._one_shot(prompts, max_new, sot_id=1)
 
     def transcribe(self, mel: np.ndarray, sot_id: int = 1,
                    max_new: int = 32) -> List[GenerationResult]:
@@ -373,9 +400,16 @@ class ServeEngine:
         autoregressive decode (paper Fig 1). ``tokens`` are the generated
         tokens only (the SOT seed token is not echoed back) — identical
         contract to ``generate()``."""
-        assert self.cfg.family == "audio"
-        b, f = mel.shape[0], mel.shape[1]
-        q = self._serve_quant
+        return self._one_shot(mel, max_new, sot_id)
+
+    def _one_shot(self, payload: np.ndarray, max_new: int, sot_id: int
+                  ) -> List[GenerationResult]:
+        """Prefill one static batch and decode it to completion."""
+        fam = self.family
+        if np.ndim(payload) != fam.payload_rank + 1:
+            raise ValueError(f"expected a batch of ({fam.payload_dims}) "
+                             f"payloads, got shape {np.shape(payload)}")
+        b, n = payload.shape[0], payload.shape[1]
         if self.offload is not None and self.offload.tuner is not None:
             # warm the *actual* batch/frame-count keys (the construction-
             # time warm covers only the canonical 1x1500 shapes) so tuning
@@ -383,31 +417,27 @@ class ServeEngine:
             # are pure cache hits. Persist only when new winners appeared.
             tuner = self.offload.tuner
             n0 = tuner.searches
-            whisper_lib.warm_tuning(self.cfg, self.offload,
-                                    n_frames=f, batch=b, n_tokens=max_new,
-                                    quant=q)
+            fam.warm_tuning(self.cfg, self.offload, n_frames=n, batch=b,
+                            n_tokens=max_new, quant=self._serve_quant)
             if tuner.searches > n0:
                 tuner.save()
-        mel_j = jnp.asarray(mel)
-        prefill_plan = self._plan(self._key("prefill", b, f),
-                                  self._prefill_fn, self._serve_params,
-                                  mel_j)
+        payload = jnp.asarray(payload)
         t0 = time.perf_counter()
         with obs.maybe_span(self.telemetry, "prefill", cat="engine",
-                            ledger=True, args={"batch": b, "frames": f}):
-            memory, state = self._prefill_jit(self._serve_params, mel_j)
-            jax.block_until_ready(memory)
+                            ledger=True,
+                            args={"batch": b, fam.payload_axis: n}):
+            # committing inside the ledger span attributes these FLOPs to
+            # the prefill
+            out, state = self.prefill(payload)
+            jax.block_until_ready(out)
+            first = self.first_token(out, sot_id)
             prefill_s = time.perf_counter() - t0
-            if self.offload is not None:
-                self.offload.ledger.commit(prefill_plan, times=1)
-        first = jnp.full((b, 1), sot_id, jnp.int32)
-        step_plan = self._plan(self._key("step", b, f), self._decode_fn,
-                               self._serve_params, first, state)
+        first = jnp.asarray(first)
+        step_plan = self.program_plan(b, state, n)
         with obs.maybe_span(self.telemetry, "decode", cat="engine",
                             ledger=True, args={"batch": b}):
             r = self._greedy_loop(state, first, max_new)
-            if self.offload is not None:
-                self.offload.ledger.commit(step_plan, times=r["steps"])
+            self._commit(step_plan, r["steps"], "main")
         return self._finalize(r, prefill_s)
 
     # ------------------------------------------------------------------

@@ -115,25 +115,15 @@ class SlotKVPool:
     ranges of ``shard_size`` and ``acquire`` balances admission across
     them.
     """
+    plan_geometry = None    # contiguous: the step's plan key has no pages
 
     def __init__(self, cfg, params, n_slots: int, max_len: int,
                  n_frames: Optional[int] = None, mesh=None):
         self.n_slots = n_slots
         self.max_len = max_len
         self.n_frames = n_frames
-        dtype = model_lib._dtype(cfg)
-        if cfg.family == "audio":
-            if n_frames is None:
-                raise ValueError("audio slot pool needs a fixed n_frames "
-                                 "capacity (utterances are padded to it)")
-            # zeros memory only shapes the cross-KV rows; insert()
-            # overwrites them with the request's real prefill state.
-            # engine=None: pool init must not touch the offload ledger.
-            memory = jnp.zeros((n_slots, n_frames, cfg.d_model), dtype)
-            st = model_lib.init_serve_state(params, cfg, n_slots, max_len,
-                                            memory=memory, engine=None)
-        else:
-            st = model_lib.init_serve_state(params, cfg, n_slots, max_len)
+        st = model_lib.family_of(cfg).empty_state(params, cfg, n_slots,
+                                                  max_len, n_frames)
         self.state: ServeState = model_lib.slot_layout(st, n_slots)
         self.mesh = mesh
         self.n_shards = 1

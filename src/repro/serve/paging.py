@@ -270,11 +270,7 @@ class PagedKVPool:
                  n_pages: Optional[int] = None,
                  cross_page_size: Optional[int] = None,
                  n_cross_pages: Optional[int] = None, mesh=None):
-        if cfg.family != "audio":
-            raise NotImplementedError(
-                "PagedKVPool currently serves the audio family only "
-                "(DESIGN.md §15); LM families use the contiguous "
-                "SlotKVPool")
+        model_lib.family_of(cfg).require("paged")
         if n_frames is None:
             raise ValueError("audio paged pool needs a fixed n_frames "
                              "capacity (utterances are padded to it)")
@@ -642,7 +638,6 @@ class PagedScheduler(ContinuousBatchingScheduler):
     # -- admission ------------------------------------------------------
     def admit(self) -> List[int]:
         admitted = []
-        eng = self.engine
         pool = self.pool
         tele = self.telemetry
         with obs.maybe_span(tele, "admit", cat="sched"):
@@ -673,6 +668,8 @@ class PagedScheduler(ContinuousBatchingScheduler):
                     tele.end(req.rid, "queued", wait_s=queue_wait)
                     tele.observe("repro_queue_wait_seconds", queue_wait)
                 slot = pool.acquire()
+                tokens = list(req.tokens) if replay else []
+                decode_s = 0.0
                 if shared and not replay:
                     # prefix hit: no encoder, no prefill — attach the shared
                     # cross pages and zero the slot's counters. No ledger
@@ -694,57 +691,28 @@ class PagedScheduler(ContinuousBatchingScheduler):
                         prefill_s = time.perf_counter() - t0
                         self._busy_s += prefill_s
                     first = req.sot_id
-                    active = _ActiveSlot(rid=req.rid, max_new=req.max_new,
-                                         prefill_s=prefill_s,
-                                         submit_t=req.submit_t,
-                                         queue_wait_s=queue_wait)
                 else:
-                    with obs.maybe_span(tele, "upload", cat="lifecycle",
-                                        track=obs.request_track(req.rid),
-                                        rid=req.rid):
-                        payload = jnp.asarray(req.payload)
-                    with obs.maybe_span(tele, "prefill", cat="lifecycle",
-                                        track=obs.request_track(req.rid),
-                                        rid=req.rid, ledger=True):
-                        key = eng._key("prefill", 1, self.n_frames)
-                        plan = eng._plan(key, eng._prefill_fn,
-                                         eng._serve_params, payload)
-                        t0 = time.perf_counter()
-                        out, state = eng._prefill_jit(eng._serve_params,
-                                                      payload)
-                        jax.block_until_ready(out)
-                        prefill_s = time.perf_counter() - t0
-                        self._busy_s += prefill_s
-                        if eng.offload is not None:
-                            eng.offload.ledger.commit(plan, times=1)
-                    if tele is not None:
-                        tele.observe("repro_prefill_seconds", prefill_s)
+                    state, first, prefill_s = self._prefill(req)
                     if shared:
                         pool.attach_shared(slot, digest)
                     else:
                         pool.alloc_cross_pages(slot, digest)
                     for _ in range(need_self):
                         pool.alloc_self_page(slot)
-                    decode_s = 0.0
-                    if replay and req.tokens:
+                    if tokens:
                         state, decode_s = self._replay(state, req)
+                        first = tokens[-1]
                     with obs.maybe_span(tele, "splice", cat="lifecycle",
                                         track=obs.request_track(req.rid),
                                         rid=req.rid):
                         pool.insert(slot, state, write_cross=not shared)
-                    first = (req.tokens[-1] if replay and req.tokens
-                             else req.sot_id)
-                    active = _ActiveSlot(
-                        rid=req.rid, max_new=req.max_new,
-                        tokens=list(req.tokens) if replay else [],
-                        steps=ntok,
-                        prefill_s=prefill_s + (req.prefill_s if replay
-                                               else 0.0),
-                        decode_s=decode_s + (req.decode_s if replay
-                                             else 0.0),
-                        submit_t=req.submit_t,
-                        queue_wait_s=queue_wait,
-                        ttft_s=req.ttft_s if replay else 0.0)
+                active = _ActiveSlot(
+                    rid=req.rid, max_new=req.max_new, tokens=tokens,
+                    steps=ntok,
+                    prefill_s=prefill_s + (req.prefill_s if replay else 0.0),
+                    decode_s=decode_s + (req.decode_s if replay else 0.0),
+                    submit_t=req.submit_t, queue_wait_s=queue_wait,
+                    ttft_s=req.ttft_s if replay else 0.0)
                 if tele is not None:
                     tele.begin(req.rid, "decode")
                 self._tokens = self._tokens.at[slot, 0].set(int(first))
@@ -761,25 +729,16 @@ class PagedScheduler(ContinuousBatchingScheduler):
         deterministic, so the rebuilt state continues token-exactly; the
         replay's wall time and its per-step plan commits land on THIS
         request, keeping PDP attribution exact-by-steps-lived."""
-        eng = self.engine
         tele = self.telemetry
         inputs = [req.sot_id] + req.tokens[:-1]
-        tok0 = jnp.full((1, 1), inputs[0], jnp.int32)
-        plan = eng._plan(eng._key("step", 1, self.n_frames),
-                         eng._decode_fn, eng._serve_params, tok0, state)
         with obs.maybe_span(tele, "replay", cat="lifecycle",
                             track=obs.request_track(req.rid), rid=req.rid,
                             ledger=True, args={"tokens": len(inputs)}):
             t0 = time.perf_counter()
-            for t in inputs:
-                _, state = eng._decode_jit(eng._serve_params,
-                                           jnp.full((1, 1), t, jnp.int32),
-                                           state)
-            state = jax.block_until_ready(state)
+            state = jax.block_until_ready(
+                self.engine.replay(state, inputs, self.n_frames))
             replay_s = time.perf_counter() - t0
             self._busy_s += replay_s
-            if eng.offload is not None:
-                eng.offload.ledger.commit(plan, times=len(inputs))
         if tele is not None:
             tele.instant("replay", rid=req.rid, tokens=len(inputs))
             tele.inc("repro_replays_total")

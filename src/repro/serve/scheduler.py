@@ -29,9 +29,12 @@ Mechanics per step (DESIGN.md §11.2):
             the next admission; ``kvcache.slot_reset`` exists for callers
             that want freed rows zeroed eagerly).
 
-Plan keys are shared with the one-shot paths via ``ServeEngine._key``
-(DESIGN.md §11.3): the slot-batched step at ``(n_slots, n_frames)`` IS
-the static decode step at that shape, so no plan is ever re-recorded.
+Plans come from the engine's admission API (DESIGN.md §11.4) and are
+shared with the one-shot paths (DESIGN.md §11.3): the slot-batched step at
+``(n_slots, n_frames)`` IS the static decode step at that shape, so no
+plan is ever re-recorded. What differs between Whisper and the token LMs
+— the payload, its padding, the first token — the engine's serving
+family answers.
 With a serving mesh attached (DESIGN.md §13) the pool's slot axis shards
 over the mesh's "data" axis, admission targets device-local slot ranges
 (``SlotKVPool.acquire`` balances across shards), and every plan key
@@ -127,11 +130,6 @@ class ContinuousBatchingScheduler:
             self._buf_tokens = 0
             self._buf_finished = 0
         self.n_slots = n_slots
-        cfg = engine.cfg
-        self._audio = cfg.family == "audio"
-        if self._audio and n_frames is None:
-            raise ValueError("audio scheduler needs n_frames (the pool's "
-                             "fixed mel-frame capacity)")
         self.n_frames = n_frames
         self.pool = self._make_pool()
         self.queue: Deque[_QueuedRequest] = deque()
@@ -183,16 +181,14 @@ class ContinuousBatchingScheduler:
 
     def _splice_written_bytes(self) -> int:
         """Bytes one admission's splice writes into the pool, from the
-        shapes of a batch-1 prefill state (``init_serve_state`` at batch
-        1, as the prefill builds it) spliced into this pool."""
-        eng, cfg = self.engine, self.engine.cfg
-        memory = (jax.ShapeDtypeStruct((1, self.n_frames, cfg.d_model),
-                                       model_lib._dtype(cfg))
-                  if self._audio else None)
+        shapes of a batch-1 decode state (the family's empty state at
+        batch 1, shaped as the prefill builds it) spliced into this
+        pool."""
+        eng = self.engine
         req = jax.eval_shape(
-            lambda p, m: model_lib.init_serve_state(p, cfg, 1, eng.max_len,
-                                                    memory=m),
-            eng._serve_params, memory)
+            lambda p: eng.family.empty_state(p, eng.cfg, 1, eng.max_len,
+                                             self.n_frames),
+            eng._serve_params)
         return model_lib.state_kv_bytes(self.pool.splice_writes(req))
 
     # -- KV accounting (DESIGN.md §15.4) --------------------------------
@@ -241,24 +237,7 @@ class ContinuousBatchingScheduler:
         """Queue one request; returns its request id. ``payload`` is a
         mel (F, n_mels) / (1, F, n_mels) for audio engines (padded to the
         pool's ``n_frames``) or an int prompt (S,) / (1, S) for LMs."""
-        arr = np.asarray(payload)
-        want_ndim = 2 if self._audio else 1
-        if arr.ndim == want_ndim:
-            arr = arr[None]
-        if arr.ndim != want_ndim + 1 or arr.shape[0] != 1:
-            # one request per submit: a stacked batch would slot_insert
-            # multiple rows at one slot and corrupt its neighbors' KV state
-            raise ValueError(
-                f"submit() takes ONE request — expected shape "
-                f"({'F, n_mels' if self._audio else 'S'},) or batch-1, "
-                f"got {arr.shape}; submit rows separately")
-        if self._audio:
-            f = arr.shape[1]
-            if f > self.n_frames:
-                raise ValueError(f"utterance has {f} frames > pool "
-                                 f"capacity {self.n_frames}")
-            if f < self.n_frames:
-                arr = np.pad(arr, ((0, 0), (0, self.n_frames - f), (0, 0)))
+        arr = self.engine.family.request(payload, self.n_frames)
         rid = self._next_rid
         self._next_rid += 1
         if max_new <= 0:
@@ -293,7 +272,6 @@ class ContinuousBatchingScheduler:
         return admitted
 
     def _admit_one(self, req: _QueuedRequest) -> int:
-        eng = self.engine
         tele = self.telemetry
         rid = req.rid
         queue_wait = (time.perf_counter() - req.submit_t
@@ -301,45 +279,42 @@ class ContinuousBatchingScheduler:
         if tele is not None:
             tele.end(rid, "queued", wait_s=queue_wait)
             tele.observe("repro_queue_wait_seconds", queue_wait)
-        track = obs.request_track(rid)
-        with obs.maybe_span(tele, "upload", cat="lifecycle", track=track,
-                            rid=rid):
-            payload = jnp.asarray(req.payload)
-        # the ledger span tightly scopes this request's prefill exec +
-        # commit, so its FLOP delta IS the prefill's attribution
-        with obs.maybe_span(tele, "prefill", cat="lifecycle", track=track,
-                            rid=rid, ledger=True):
-            if self._audio:
-                key = eng._key("prefill", 1, self.n_frames)
-                times = 1
-            else:
-                key = eng._key("prefill", 1, payload.shape[1])
-                times = payload.shape[1]
-            plan = eng._plan(key, eng._prefill_fn, eng._serve_params, payload)
-            t0 = time.perf_counter()
-            out, state = eng._prefill_jit(eng._serve_params, payload)
-            jax.block_until_ready(out)
-            if self._audio:
-                first = np.full((1,), req.sot_id, np.int32)
-            else:
-                first = np.asarray(eng._argmax(out[:, -1]))
-            prefill_s = time.perf_counter() - t0
-            self._busy_s += prefill_s
-            if eng.offload is not None:
-                eng.offload.ledger.commit(plan, times=times)
-        with obs.maybe_span(tele, "splice", cat="lifecycle", track=track,
-                            rid=rid):
+        state, first, prefill_s = self._prefill(req)
+        with obs.maybe_span(tele, "splice", cat="lifecycle",
+                            track=obs.request_track(rid), rid=rid):
             slot = self.pool.acquire()
             self.pool.insert(slot, state)
-            self._tokens = self._tokens.at[slot, 0].set(int(first[0]))
+            self._tokens = self._tokens.at[slot, 0].set(first)
         self._active[slot] = _ActiveSlot(rid=rid, max_new=req.max_new,
                                          prefill_s=prefill_s,
                                          submit_t=req.submit_t,
                                          queue_wait_s=queue_wait)
         if tele is not None:
-            tele.observe("repro_prefill_seconds", prefill_s)
             tele.begin(rid, "decode")
         return rid
+
+    def _prefill(self, req: _QueuedRequest):
+        """One request's ``upload`` and ``prefill`` spans: its batch-1
+        prefill state, its first token and the prefill's wall time (the
+        program, its sync and the first token), which counts as busy."""
+        tele = self.telemetry
+        track = obs.request_track(req.rid)
+        with obs.maybe_span(tele, "upload", cat="lifecycle", track=track,
+                            rid=req.rid):
+            payload = jnp.asarray(req.payload)
+        # the ledger span tightly scopes this request's prefill exec +
+        # commit, so its FLOP delta IS the prefill's attribution
+        with obs.maybe_span(tele, "prefill", cat="lifecycle", track=track,
+                            rid=req.rid, ledger=True):
+            t0 = time.perf_counter()
+            out, state = self.engine.prefill(payload)
+            jax.block_until_ready(out)
+            first = np.asarray(self.engine.first_token(out, req.sot_id))
+            prefill_s = time.perf_counter() - t0
+            self._busy_s += prefill_s
+        if tele is not None:
+            tele.observe("repro_prefill_seconds", prefill_s)
+        return state, int(first[0, 0]), prefill_s
 
     # -- decode ---------------------------------------------------------
     def _ensure_step_plan(self) -> None:
@@ -349,12 +324,9 @@ class ContinuousBatchingScheduler:
         if self._step_plan_ready:
             return
         eng = self.engine
-        extra = (self.n_frames,) if self._audio else ()
-        key = eng._key("step", self.n_slots, *extra,
-                       pages=getattr(self.pool, "plan_geometry", None))
-        token = jnp.zeros((self.n_slots, 1), jnp.int32)
-        self._step_plan = eng._plan(key, eng._decode_fn, eng._serve_params,
-                                    token, self.pool.state)
+        self._step_plan = eng.program_plan(self.n_slots, self.pool.state,
+                                           self.n_frames,
+                                           pages=self.pool.plan_geometry)
         self._step_plan_ready = True
         tele = self.telemetry
         if tele is not None:

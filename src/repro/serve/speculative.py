@@ -130,10 +130,10 @@ class SpeculativeEngine:
             raise ValueError(
                 f"draft and verifier must share a vocabulary to compare "
                 f"tokens: {dc.vocab_size} != {vc.vocab_size}")
-        if vc.family != "audio" or dc.family != "audio":
-            raise NotImplementedError(
-                "speculative serving is wired for the audio family "
-                "(the Whisper ladder, DESIGN.md §17)")
+        # both models prefill the same payload, and the verifier scores
+        # windows
+        self.verifier.family.require("verify")
+        self.draft.family.require("verify")
 
     # ------------------------------------------------------------------
     def transcribe(self, mel: np.ndarray, sot_id: int = 1,
@@ -167,26 +167,16 @@ class SpeculativeEngine:
 
         # plans: prefills are the SAME traced programs as the plain path
         # (plain keys -> shared PlanCache entries); the draft step and the
-        # verify window are role-keyed (DESIGN.md §17.2)
-        v_prefill_plan = v._plan(v._key("prefill", b, f), v._prefill_fn,
-                                 v._serve_params, mel_j)
-        d_prefill_plan = d._plan(d._key("prefill", b, f), d._prefill_fn,
-                                 d._serve_params, mel_j)
-
+        # verify window are role-keyed (DESIGN.md §17.2). Both prefills
+        # dispatch before either is waited on.
         t0 = time.perf_counter()
         with obs.maybe_span(tele, "spec_prefill", cat="engine", ledger=True,
                             args={"batch": b, "frames": f}):
-            v_mem, v_state = v._prefill_jit(v._serve_params, mel_j)
-            d_mem, d_state = d._prefill_jit(d._serve_params, mel_j)
+            v_mem, v_state = v.prefill(mel_j, role="verify")
+            d_mem, d_state = d.prefill(mel_j, role="draft")
             jax.block_until_ready(v_mem)
             jax.block_until_ready(d_mem)
             prefill_s = time.perf_counter() - t0
-            if v.offload is not None:
-                v.offload.ledger.commit(v_prefill_plan, times=1,
-                                        role="verify")
-            if d.offload is not None:
-                d.offload.ledger.commit(d_prefill_plan, times=1,
-                                        role="draft")
 
         # per-row accept lengths need per-slot positions: the slot layout
         # (DESIGN.md §11.1) inside a run-to-completion static batch
@@ -195,11 +185,8 @@ class SpeculativeEngine:
 
         cur = jnp.full((b, 1), sot_id, jnp.int32)
         nodone = jnp.zeros((b,), bool)
-        d_step_plan = d._plan(d._key("step", b, f, role="draft"),
-                              d._decode_fn, d._serve_params, cur, d_state)
-        v_verify_plan = v._plan(
-            v._key("verify", b, f, role="verify", k=k), v._verify_fn,
-            v._serve_params, jnp.zeros((b, w), jnp.int32), v_state)
+        d_step_plan = d.program_plan(b, d_state, f, role="draft")
+        v_verify_plan = v.program_plan(b, v_state, f, k=k, role="verify")
 
         toks: List[List[int]] = [[] for _ in range(b)]
         done = np.zeros(b, bool)
@@ -463,8 +450,6 @@ class _SpecRoundsMixin:
         a = self._active[slot]
         tokens = list(a.tokens)          # non-empty only after preemption
         payload = jnp.asarray(req.payload)
-        plan = d._plan(d._key("prefill", 1, self.n_frames), d._prefill_fn,
-                       d._serve_params, payload)
         # the ledger span tightly scopes the draft-side prefill + replay
         # exec and commits, preserving §16.2 span exactness (the draft
         # shares the verifier's ledger, so unclaimed commits here would
@@ -473,22 +458,10 @@ class _SpecRoundsMixin:
                             track=obs.request_track(a.rid), rid=a.rid,
                             ledger=True):
             t0 = time.perf_counter()
-            _, state = d._prefill_jit(d._serve_params, payload)
-            if d.offload is not None:
-                d.offload.ledger.commit(plan, times=1, role="draft")
+            _, state = d.prefill(payload, role="draft")
             if tokens:
-                inputs = [req.sot_id] + tokens[:-1]
-                tok0 = jnp.full((1, 1), inputs[0], jnp.int32)
-                rplan = d._plan(d._key("step", 1, self.n_frames,
-                                       role="draft"),
-                                d._decode_fn, d._serve_params, tok0, state)
-                for t in inputs:
-                    _, state = d._decode_jit(d._serve_params,
-                                             jnp.full((1, 1), t, jnp.int32),
-                                             state)
-                if d.offload is not None:
-                    d.offload.ledger.commit(rplan, times=len(inputs),
-                                            role="draft")
+                state = d.replay(state, [req.sot_id] + tokens[:-1],
+                                 self.n_frames, role="draft")
             state = jax.block_until_ready(state)
             wall = time.perf_counter() - t0
         self._busy_s += wall
@@ -516,17 +489,12 @@ class _SpecRoundsMixin:
         if self._step_plan_ready:
             return
         spec = self.spec
-        v, d, k = spec.verifier, spec.draft, spec.k
-        token = jnp.zeros((self.n_slots, 1), jnp.int32)
-        self._draft_step_plan = d._plan(
-            d._key("step", self.n_slots, self.n_frames, role="draft"),
-            d._decode_fn, d._serve_params, token, self._draft_pool.state)
-        window = jnp.zeros((self.n_slots, k + 1), jnp.int32)
-        self._verify_plan = v._plan(
-            v._key("verify", self.n_slots, self.n_frames,
-                   pages=getattr(self.pool, "plan_geometry", None),
-                   role="verify", k=k),
-            v._verify_fn, v._serve_params, window, self.pool.state)
+        self._draft_step_plan = spec.draft.program_plan(
+            self.n_slots, self._draft_pool.state, self.n_frames,
+            role="draft")
+        self._verify_plan = spec.verifier.program_plan(
+            self.n_slots, self.pool.state, self.n_frames, k=spec.k,
+            pages=self.pool.plan_geometry, role="verify")
         self._step_plan_ready = True
 
     def decode_step(self) -> List[TokenEvent]:
