@@ -65,6 +65,18 @@ def _tiles(kernel: str, mp: int, n: int, k: int, dtype: str, *, block_k: int,
     return (bm if mp % bm == 0 else dflt[0]), bn, bk
 
 
+def m_tiles(entry) -> int:
+    """How many M tiles the kernel sweeps for a plan entry's main segment,
+    tiled as ``OffloadEngine.execute`` runs it: the entry's tiling, else
+    the defaults. A ``q8_matmul`` dequantizes every weight tile once per
+    M tile."""
+    mp = space.round_up(entry.m, space.SUBLANE)
+    bm = _tiles(entry.kernel, mp, entry.n, entry.k_main, entry.dtype,
+                block_k=KernelRequest.block_k, tuner=None,
+                tiling=entry.tiling)[0]
+    return mp // bm
+
+
 def _padded(x2d: jax.Array, w_rows, bn: int):
     """Pad activations to the sublane multiple and weight rows to a lane
     multiple of ``bn``, so the kernel only sees whole, chip-legal tiles

@@ -30,6 +30,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Hashable, List, Optional, Tuple
 
 from repro.backends import MAIN, KernelRequest, REGISTRY
+from repro.backends.pallas_tpu import m_tiles
 from repro.core.coverage import MulMat, fits
 from repro.core.mixed_exec import select_burst, split_aligned
 from repro.sharding.rules import mesh_signature
@@ -177,6 +178,14 @@ class DispatchPlan:
         """Hashable identity — equal signatures mean identical routing
         (the determinism contract of tests/test_plan.py)."""
         return tuple(self.entries)
+
+    def q8_weight_passes(self) -> int:
+        """The most M tiles that any offloaded ``q8_matmul`` entry sweeps:
+        each M tile dequantizes every weight tile of the call once more
+        (0 when the plan has no such entry)."""
+        return max((m_tiles(e) for e in self.entries
+                    if e.kernel == "q8_matmul" and e.offload and e.k_main),
+                   default=0)
 
     def summary(self) -> Dict[str, Any]:
         off = [e for e in self.entries if e.offload]

@@ -143,11 +143,16 @@ def vmem_claim_bytes(block_m: int = DEFAULT_BLOCK_M,
     """The VMEM working set this tiling claims (the LMM-sizing analog):
     double-buffered x/q tiles and scales band (``k`` is the full
     contraction; 0 means a single K block, ``k = block_k``) + the f32
-    dequantized tile + f32 accumulator + out tile."""
+    copy the kernel makes of a narrower x tile + the f32 dequantized tile
+    + f32 accumulator + out tile. A bf16 x tile and its f32 copy claim
+    what a double-buffered f32 x tile does, so the claim ranks tilings
+    the same whichever the activations are."""
     db = 2  # pallas pipeline double-buffers inputs
+    x_f32_copy = block_m * block_k * 4 if x_bytes < 4 else 0
     return (db * (block_m * block_k * x_bytes            # x tile
                   + block_n * block_k                    # int8 payload
                   + scales_band_bytes(block_n, k or block_k))
+            + x_f32_copy                                 # x.astype(f32)
             + block_n * block_k * 4                      # dequantized tile
             + block_m * block_n * 4                      # accumulator
             + block_m * block_n * 4)                     # out tile

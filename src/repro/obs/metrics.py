@@ -300,6 +300,9 @@ def serving_registry() -> MetricsRegistry:
     r.gauge("repro_splice_written_bytes",
             help="bytes an admission's splice writes into the pool, in "
                  "place")
+    r.gauge("repro_q8_weight_passes",
+            help="most M tiles any q8_matmul of a prefill plan sweeps: "
+                 "each dequantizes every weight tile once more")
     r.counter("repro_requests_submitted_total")
     r.counter("repro_requests_finished_total")
     r.counter("repro_tokens_total", help="tokens streamed")

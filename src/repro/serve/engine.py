@@ -298,7 +298,13 @@ class ServeEngine:
         engine). Returns the program's ``(out, state)``: the encoder
         memory or the last logits, and the standard-layout decode state."""
         key = self._key("prefill", payload.shape[0], payload.shape[1])
+        fresh = key not in self._plans.plans
         plan = self._plan(key, self._prefill_fn, self._serve_params, payload)
+        if fresh and plan is not None and self.telemetry is not None:
+            # gauged once per prefill plan, from its shapes: the most
+            # times any prefill kernel dequantizes its weights
+            g = self.telemetry.metrics.gauge("repro_q8_weight_passes")
+            g.set(max(g.value(), plan.q8_weight_passes()))
         out, state = self._prefill_jit(self._serve_params, payload)
         self._commit(plan, self.family.prefill_times(payload), role)
         return out, state

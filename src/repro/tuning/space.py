@@ -136,12 +136,23 @@ def default_tiles(kernel: str, m: int, n: int, k: int,
     """The (block_m, block_n, block_k) dispatch uses with no tuned tiling:
     the largest legal tiles under fixed caps (block_m 128, block_n 512 on
     the matvec and 256 elsewhere, block_k ``block_k``; the matvec takes K
-    whole). ``m`` is the sublane-padded row count."""
+    whole). ``m`` is the sublane-padded row count.
+
+    ``q8_matmul`` dequantizes every weight tile once per M tile, so where
+    M needs more than one 128-row tile it takes the largest legal M tile
+    whose VMEM claim fits the tuner's budget instead: the 1504-row Whisper
+    encoder is one M tile, where the cap left 47 tiles of 32 rows."""
     if kernel == "q8_matvec":
         return m, legal_tiles(lane_padded(n), 512, LANE)[0], k
-    return (legal_tiles(m, 128, SUBLANE)[0],
-            legal_tiles(lane_padded(n), 256, LANE)[0],
-            legal_tiles(k, block_k, LANE)[0])
+    bm = legal_tiles(m, 128, SUBLANE)[0]
+    bn = legal_tiles(lane_padded(n), 256, LANE)[0]
+    bk = legal_tiles(k, block_k, LANE)[0]
+    if kernel == "q8_matmul" and bm < m:
+        claim = _claim_fn(kernel)
+        bm = next((b for b in legal_tiles(m, m, SUBLANE)
+                   if claim(block_m=b, block_n=bn, block_k=bk, k=k)
+                   <= VMEM_FULL_BYTES // 2), bm)
+    return bm, bn, bk
 
 
 def default_candidate(kernel: str, m: int, n: int, k: int, *,

@@ -26,8 +26,14 @@ def _w(key, n, k, scale=0.05):
     (32, 256, 128, 16, 128, 128),
     (128, 256, 512, 64, 128, 256),     # default-ish MXU tiling
     (8, 512, 96, 8, 256, 32),          # skinny K with whole blocks
+    (376, 384, 384, 376, 128, 128),    # one M tile of 8 x 47 rows
+    (752, 384, 384, 752, 384, 128),    # one M tile, whole N
+    (752, 384, 384, 376, 128, 384),    # two M tiles, whole K
 ])
 def test_q8_matmul_vs_ref(m, n, k, bm, bn, bk):
+    """Against the oracle, and row for row against the same call in
+    8-row M tiles: the M tiling changes which rows share a grid step,
+    never what a row computes."""
     kx, kw = jax.random.split(jax.random.PRNGKey(m * n + k))
     x = jax.random.normal(kx, (m, k), jnp.float32)
     wq = quantize_q8_0(_w(kw, n, k))
@@ -35,6 +41,9 @@ def test_q8_matmul_vs_ref(m, n, k, bm, bn, bk):
                     block_k=bk, interpret=True)
     want = ref.q8_matmul_ref(x, wq)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    rows8 = q8_matmul(x, wq.flat_qs(), wq.scales, block_m=8, block_n=bn,
+                      block_k=bk, interpret=True)
+    np.testing.assert_array_equal(got, rows8)
 
 
 @pytest.mark.parametrize("xdtype", [jnp.float32, jnp.bfloat16])
@@ -70,9 +79,12 @@ def test_vmem_claim_model():
     db_x = 2 * 128 * 256 * 2
     db_q = 2 * 256 * 256
     db_s = 2 * 256 * 128 * 4          # (256, 8) f32 band, lanes pad to 128
+    x_f32 = 128 * 256 * 4             # the kernel's f32 copy of x
     deq = 256 * 256 * 4               # f32 dequantized tile
     acc = 128 * 256 * 4 * 2
-    assert base == db_x + db_q + db_s + deq + acc
+    assert base == db_x + db_q + db_s + x_f32 + deq + acc
+    # an f32 x tile needs no copy: it claims what bf16 and its copy do
+    assert vmem_claim_bytes(128, 256, 256, x_bytes=4) == base
     # the resident scales band grows with the full contraction, one
     # 128-lane tile per 128 Q8_0 blocks
     assert vmem_claim_bytes(128, 256, 256, k=4096) == base

@@ -199,3 +199,37 @@ def test_splice_bytes_gauge(arch, kind):
     sched.run()
     g = tele.metrics.snapshot()["gauges"]
     assert g["repro_splice_written_bytes"][""] == splice
+
+
+@pytest.mark.parametrize("kind", ["contiguous", "paged"])
+def test_q8_weight_passes_gauge(kind):
+    """Set once with the prefill plan, from its shapes: a 376-frame
+    encoder (8 x 47 rows, which the 128-row cap split into 47 tiles of
+    8) is one M tile of every Q8_0 matmul, so each weight tile is
+    dequantized once per prefill."""
+    from repro.core.offload import OffloadEngine
+    from repro.tuning import padded_m
+    from repro.tuning.space import default_tiles
+
+    frames = 376
+    cfg = get_smoke_config("whisper-tiny")
+    params = M.init_params(jax.random.PRNGKey(2), cfg, frames)
+    tele = obs.Telemetry()
+    eng = ServeEngine(cfg, params, max_len=MAX_LEN, quant="q8_0",
+                      offload=OffloadEngine(burst=64), eos_id=-1,
+                      telemetry=tele)
+    sched = (eng.paged_scheduler(n_slots=2, n_frames=frames, page_size=4)
+             if kind == "paged" else
+             eng.scheduler(n_slots=2, n_frames=frames))
+    rng = np.random.default_rng(0)
+    sched.submit(rng.standard_normal((1, frames, cfg.n_mels)
+                                     ).astype(np.float32), max_new=2)
+    sched.run()
+    passes = tele.metrics.snapshot()["gauges"]["repro_q8_weight_passes"][""]
+    (plan,) = [p for k, p in eng._plans.plans.items() if k[0] == "prefill"]
+    q8 = [e for e in plan if e.kernel == "q8_matmul" and e.offload]
+    assert max(e.m for e in q8) == frames
+    want = max(-(-padded_m(e.m)
+                 // default_tiles(e.kernel, padded_m(e.m), e.n, e.k_main)[0])
+               for e in q8)
+    assert passes == want == 1

@@ -4,7 +4,8 @@ The TPU compiler is installed with jax; it compiles for a described
 ``v5e:2x2`` topology. These tests compile the main-path kernels at
 Whisper-tiny, -base, -small and -large-v3 widths — every weight GEMM of
 the model, the tied vocab readout included — with the tilings the offload
-engine's plan and the untuned defaults resolve, and the first candidates
+engine's plan and the untuned defaults resolve (the 1504-row encoder as
+one M tile of the Q8_0 matmul), and the first candidates
 the autotuner's space emits; whisper-tiny's served prefill and slot step
 with the native kernels; whisper-large-v3's served prefill, slot step
 and slot splice at its benchmark deployment, with what they hold in HBM;
@@ -26,7 +27,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.backends.pallas_tpu import bf16_main, q8_main
+from repro.backends.pallas_tpu import bf16_main, m_tiles, q8_main
 from repro.configs.registry import get_config
 from repro.core.offload import OffloadEngine
 from repro.core.qformats import QBLOCK, QTensor
@@ -117,6 +118,10 @@ def test_main_segments_compile(one_chip, no_persistent_cache, arch, kernel,
     for n, k in _weight_shapes(cfg):
         e = eng.plan_entry(m, k, n, quantized=quantized, name=f"{n}x{k}")
         assert e.kernel == kernel and e.k_main
+        if kernel == "q8_matmul":
+            # the untuned tiles take the whole 1504-row window as one M
+            # tile, as the served prefill does
+            assert e.tiling is None and m_tiles(e) == 1
         kw = dict(interpret=False, block_k=256, tiling=e.tiling)
         if quantized:
             _compile(one_chip,

@@ -277,3 +277,75 @@ def test_space_emits_only_chip_legal_tiles(arch):
                 assert c.block_k % LANE == 0 or c.block_k == k
                 assert k % c.block_k == 0
                 assert c.block_m % SUBLANE == 0 and m % c.block_m == 0
+
+
+# (arch, N, K) -> the untuned q8_matmul tiles of the 1504-row encoder:
+# the (d, d) projections, the fused qkv and cross-K/V widths, FFN up and
+# down (tiny's 384-wide K also as the 256-column main segment that the
+# default burst leaves the kernel). Each is one M tile: its weights are
+# dequantized once per call.
+_ENCODER_TILES = {
+    "whisper-tiny": {(384, 256): (1504, 128, 256),
+                     (1536, 256): (1504, 256, 256),
+                     (1152, 384): (1504, 128, 128),
+                     (384, 384): (1504, 128, 128),
+                     (768, 384): (1504, 256, 128),
+                     (1536, 384): (1504, 256, 128),
+                     (384, 1536): (1504, 128, 256)},
+    "whisper-small": {(2304, 768): (1504, 256, 256),
+                      (768, 768): (1504, 256, 256),
+                      (1536, 768): (1504, 256, 256),
+                      (3072, 768): (1504, 256, 256),
+                      (768, 3072): (1504, 256, 256)},
+    "whisper-large-v3": {(3840, 1280): (1504, 256, 256),
+                         (1280, 1280): (1504, 256, 256),
+                         (2560, 1280): (1504, 256, 256),
+                         (5120, 1280): (1504, 256, 256),
+                         (1280, 5120): (1504, 256, 256)},
+}
+
+
+@pytest.mark.parametrize("arch", sorted(_ENCODER_TILES))
+def test_default_q8_tiles_take_one_m_tile_of_the_encoder(arch):
+    """The untuned q8_matmul tile of the 1500-frame encoder (1504 rows
+    after sublane padding) is the largest legal M tile whose VMEM claim
+    fits the tuner's budget — the whole window — not the 32 rows the
+    128-row cap leaves of 1504 = 32 x 47."""
+    from repro.configs.registry import get_config
+    from repro.tuning.space import _claim_fn, default_tiles
+    cfg = get_config(arch)
+    claim = _claim_fn("q8_matmul")
+    for (n, k), want in _ENCODER_TILES[arch].items():
+        assert {n, k} <= {256, cfg.d_model, 2 * cfg.d_model,
+                          3 * cfg.d_model, cfg.d_ff}
+        bm, bn, bk = default_tiles("q8_matmul", 1504, n, k)
+        assert (bm, bn, bk) == want
+        assert claim(block_m=bm, block_n=bn, block_k=bk,
+                     k=k) <= VMEM_FULL_BYTES // 2
+
+
+@pytest.mark.parametrize("m", [8, 16, 32, 64, 128])
+def test_default_tiles_of_decode_rows_are_unchanged(m):
+    """M of at most 128 rows is one tile already: decode batches keep
+    the capped tiles, whatever the claim would allow."""
+    from repro.tuning.space import default_tiles
+    assert default_tiles("q8_matmul", m, 1536, 384) == (m, 256, 128)
+    assert default_tiles("q8_matmul", m, 5120, 1280) == (m, 256, 256)
+    assert default_tiles("q8_matvec", m, 1536, 384) == (m, 512, 384)
+
+
+def test_default_tiles_shrink_the_m_tile_to_the_claim():
+    """Where the whole of M overflows the budget the rule takes the
+    largest legal divisor that fits; bf16_matmul, which has no weight
+    dequantization to spread, keeps the 128-row cap."""
+    from repro.tuning.space import _claim_fn, default_tiles
+    claim = _claim_fn("q8_matmul")
+    bm, bn, bk = default_tiles("q8_matmul", 6016, 5120, 1280)
+    assert (bm, bn, bk) == (1504, 256, 256)
+    for over in (6016, 3008):
+        assert claim(block_m=over, block_n=bn, block_k=bk,
+                     k=1280) > VMEM_FULL_BYTES // 2
+    assert claim(block_m=bm, block_n=bn, block_k=bk,
+                 k=1280) <= VMEM_FULL_BYTES // 2
+    for n, k in ((1536, 384), (3072, 768), (5120, 1280), (1280, 5120)):
+        assert default_tiles("bf16_matmul", 1504, n, k)[0] == 32
