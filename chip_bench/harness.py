@@ -11,6 +11,7 @@ loop), with the host clock around calls that end in a host sync.
 from __future__ import annotations
 
 import contextlib
+import functools
 import gc
 import os
 import shutil
@@ -18,7 +19,7 @@ import sys
 import tempfile
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -26,7 +27,6 @@ import numpy as np
 from chip_bench import reference, spec, stats, trace_reduce, traffic, weights
 from chip_bench.peaks import peaks
 
-SOT = 1
 DRAIN_S = 60.0          # how long past the window a due request may take
 
 
@@ -35,13 +35,17 @@ class Served:
     """The harness's record of one request."""
     index: int
     max_new: int
-    mel: np.ndarray
+    payload: np.ndarray
     submit: float
     due: Optional[float] = None         # open loop: when it was due
     token_t: List[float] = field(default_factory=list)
     tokens: List[int] = field(default_factory=list)
     prefill_start: Optional[float] = None
     prefill_s: Optional[float] = None
+    size: int = 0                       # the traffic's input size
+    admitted: Optional[float] = None    # end of its admission pass
+    first: Any = None                   # the first decode input after
+                                        # its prefill (``FirstTokens``)
 
 
 @dataclass
@@ -49,8 +53,8 @@ class Run:
     """What the per-layer readers see of a run."""
     cell: dict
     cfg: object
+    family: Any                               # the family module
     n_slots: int
-    frames: int
     t0: float
     t1: float
     requests: Dict[int, Served]
@@ -73,26 +77,51 @@ def now() -> float:
 
 
 def build(cell: dict, seed: int):
-    """The model configuration and the engine serving it, weights from
-    ``seed``."""
+    """The model family, the model configuration and the engine serving
+    it, weights from ``seed``."""
     from repro.core.offload import OffloadEngine
     from repro.serve.engine import ServeEngine
     conf = cell["config"]
-    cfg = spec.model_config(conf)
-    params = weights.make_params(cfg, seed)
-    weights.check_layout(cfg, params)
+    fam = spec.load_family(cell["family"], cell["bench_dir"])
+    cfg = spec.model_config(conf, cell["bench_dir"])
+    params = fam.make_params(cfg, seed)
+    weights.check_layout(cfg, params, fam.POSITIONS)
     # eos off: the weights are random, so the drawn length is the length
     engine = ServeEngine(cfg, params, max_len=conf["deployment"]["max_len"],
                          quant=conf["quant"], offload=OffloadEngine(),
                          eos_id=None)
-    return cfg, engine
+    return fam, cfg, engine
+
+
+class FirstTokens:
+    """Records, in admission order, the first decode input the engine
+    picks after each prefill (``ServeEngine.first_token``): the start
+    token the request names, or an LM's greedy pick after its prompt,
+    which the program's results leave out."""
+
+    def __init__(self, engine):
+        self.pick = engine.first_token
+        self.seen: list = []
+        engine.first_token = self
+
+    def __call__(self, *args):
+        first = self.pick(*args)
+        self.seen.append(first)
+        return first
+
+    def take(self) -> list:
+        seen, self.seen = self.seen, []
+        return seen
 
 
 class Client:
     """Drives the scheduler and records what happened, in time order."""
 
-    def __init__(self, sched, traced: bool):
+    def __init__(self, sched, firsts: FirstTokens, submit_kwargs: dict,
+                 traced: bool):
         self.sched = sched
+        self.firsts = firsts
+        self.submit_kwargs = submit_kwargs
         self.records: Dict[int, Served] = {}
         self.steps: List[Tuple[float, float, int]] = []
         self.admits: List[Tuple[float, float, int]] = []
@@ -101,15 +130,21 @@ class Client:
 
     def submit(self, req: traffic.Request, due: Optional[float]) -> None:
         t = now()
-        rid = self.sched.submit(req.mel, max_new=req.max_new, sot_id=SOT)
-        self.records[rid] = Served(req.index, req.max_new, req.mel, t, due)
+        rid = self.sched.submit(req.payload, max_new=req.max_new,
+                                **self.submit_kwargs)
+        self.records[rid] = Served(req.index, req.max_new, req.payload, t,
+                                   due, size=req.size)
 
     def cycle(self) -> None:
         """One admission pass, then one decode step over the slot pool."""
         with self.ann("bench.admit"):
             a = now()
-            n = len(self.sched.admit())
-            self.admits.append((a, now(), n))
+            rids = self.sched.admit()
+            b = now()
+            self.admits.append((a, b, len(rids)))
+            for rid, first in zip(rids, self.firsts.take()):
+                self.records[rid].admitted = b
+                self.records[rid].first = first
         if not self.sched.n_active:
             return
         active = self.sched.n_active
@@ -130,6 +165,34 @@ class Client:
                 r.tokens = list(res.tokens)
                 r.prefill_s = res.prefill_s
                 r.prefill_start = r.submit + res.queue_wait_s
+        for r in self.records.values():
+            if r.first is not None:
+                r.first = int(np.asarray(r.first).reshape(-1)[0])
+
+
+def start(cell: dict, seed: int, seconds: float,
+          engine_hook: Optional[Callable] = None):
+    """Build the served path for ``cell`` and warm it up: one request of
+    each program shape the traffic has through admission, the slot splice
+    and the step. Returns the family, the configuration, the engine, the
+    scheduler, the run's traffic and the ``FirstTokens`` record."""
+    conf = cell["config"]
+    n_slots = conf["deployment"]["n_slots"]
+    fam, cfg, engine = build(cell, seed)
+    if engine_hook is not None:
+        engine_hook(engine)
+    firsts = FirstTokens(engine)
+    sched = engine.scheduler(n_slots=n_slots, **fam.scheduler_kwargs(cfg))
+    tr = traffic.Traffic(cell["traffic"], seed, seconds, n_slots,
+                         functools.partial(fam.payload, cfg=cfg))
+    warm = Client(sched, firsts, fam.submit_kwargs(cfg), traced=False)
+    shapes = {fam.program_args(cfg, s).shape: s for s in tr.size_values()}
+    for size in shapes.values():
+        warm.submit(tr.warm_request(size), None)
+    while sched.n_queued or sched.n_active:
+        warm.cycle()
+    sched.finished.clear()
+    return fam, cfg, engine, sched, tr, firsts
 
 
 class CompileCount:
@@ -296,27 +359,21 @@ def sample(run: Run, seed: int, want_tokens: int, most: int) -> List[Served]:
     return out
 
 
-def reference_gaps(cfg, seed: int, picked: List[Served], frames: int,
-                   pad_to: int, control_bits: Optional[int] = None):
+def reference_gaps(fam, cfg, seed: int, picked: List[Served], pad_to: int,
+                   control_bits: Optional[int] = None):
     """Per sampled request, the gaps of its served tokens below the float32
     reference's best; with ``control_bits``, also the gaps of the tokens
     the reference with weights rounded to that many bits puts first."""
-    p = weights.make_params(cfg, seed)
+    p = fam.make_params(cfg, seed)
     pc = reference.quantized(p, control_bits) if control_bits else None
     served, control = [], []
     for r in picked:
-        mel = np.zeros((frames, cfg.n_mels), np.float32)
-        mel[:r.mel.shape[0]] = r.mel
-        toks = np.asarray(r.tokens, np.int32)
-        inp = np.zeros((pad_to,), np.int32)
-        inp[0] = SOT
-        inp[1:len(toks)] = toks[:-1]
-        n = len(toks)
-        ref = np.asarray(reference.logits(p, mel, inp, cfg.num_heads))[:n]
+        ref, toks = fam.reference_logits(p, cfg, r.payload, r.first,
+                                         r.tokens, pad_to)
         served.append(reference.served_gaps(ref, toks, cfg.vocab_size))
         if pc is not None:
-            ctl = np.asarray(reference.logits(pc, mel, inp,
-                                              cfg.num_heads))[:n]
+            ctl, _ = fam.reference_logits(pc, cfg, r.payload, r.first,
+                                          r.tokens, pad_to)
             first = ctl[:, :cfg.vocab_size].argmax(-1)
             control.append(reference.served_gaps(ref, first, cfg.vocab_size))
     return served, control
@@ -331,22 +388,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     a test break the served path underneath."""
     dev = jax.devices()[0]
     pk = peaks(dev.device_kind) if dev.platform == "tpu" else {}
-    conf = cell["config"]
-    n_slots = conf["deployment"]["n_slots"]
-    cfg, engine = build(cell, seed)
-    if engine_hook is not None:
-        engine_hook(engine)
-    frames = cfg.encoder_ctx
-    sched = engine.scheduler(n_slots=n_slots, n_frames=frames)
-    tr = traffic.Traffic(cell["traffic"], seed, seconds, n_slots, cfg.n_mels)
+    n_slots = cell["config"]["deployment"]["n_slots"]
+    fam, cfg, engine, sched, tr, firsts = start(cell, seed, seconds,
+                                                engine_hook)
     lines = []
-
-    # warm-up: one request through admission, the slot splice and the step
-    warm = Client(sched, traced=False)
-    warm.submit(tr.warm_request(), None)
-    while sched.n_queued or sched.n_active:
-        warm.cycle()
-    sched.finished.clear()
     if tr.kind == "backlog":
         tr.prepare(traffic.expected_backlog_requests(
             cell["traffic"]["expected_tokens_per_s"], seconds,
@@ -356,13 +401,13 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     jax.block_until_ready(sched.pool.state)
     setup_s = now() - t_start
     if report_programs:
-        lines += program_memory(engine, sched, frames)
+        lines += program_memory(engine, sched, fam, max(tr.size_values()))
     if trace and tr.kind == "poisson":
         more = traffic.Traffic(cell["traffic"], seed + 2**40,
-                               cell["trace"]["seconds"], n_slots, cfg.n_mels)
+                               cell["trace"]["seconds"], n_slots, tr.payload)
         more.prepare()
 
-    d = Client(sched, traced=trace)
+    d = Client(sched, firsts, fam.submit_kwargs(cfg), traced=trace)
     with CompileCount() as compiles, GcPauses() as collections:
         if tr.kind == "backlog":
             t0, t1, nxt = closed_loop(d, tr, seconds)
@@ -380,7 +425,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     d.finish()
     mem = max((x.memory_stats() or {}).get("peak_bytes_in_use", 0)
               for x in jax.devices())
-    run = Run(cell, cfg, n_slots, frames, t0, t1, d.records, d.steps,
+    run = Run(cell, cfg, fam, n_slots, t0, t1, d.records, d.steps,
               d.admits, pk, bounds, reduced)
     lines.append(f"programs compiled inside the window: {compiles.n}")
     lines.append(host_line(run, collections.pauses))
@@ -389,7 +434,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
                      f"{1e3 * stats.percentile(late, 50):.3f} ms, max "
                      f"{1e3 * max(late):.3f} ms")
     else:
-        lines.append(f"generator: closed loop, {tr.late} requests' audio "
+        lines.append(f"generator: closed loop, {tr.late} requests' inputs "
                      f"made inside the window")
     unserved = sum(1 for r in d.records.values()
                    if r.due is not None and run.in_window(r.due)
@@ -426,9 +471,9 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     limits = cell["limits"]
     picked = sample(run, seed, limits["sample_tokens"],
                     limits["sample_requests"])
-    del engine, sched, d, warm
+    del engine, sched, d, firsts
     gc.collect()
-    served, control = reference_gaps(cfg, seed, picked, frames,
+    served, control = reference_gaps(fam, cfg, seed, picked,
                                       limits["pad_tokens"], control_bits)
     short = sum(1 for r in picked if len(r.tokens) != r.max_new)
     checks, correct = judge(served, unserved, short, limits)
@@ -469,12 +514,13 @@ def judge(gaps: List[np.ndarray], unserved: int, short: int,
     return checks, correct
 
 
-def program_memory(engine, sched, frames: int) -> List[str]:
-    """The compiled prefill's and decode step's ``memory_analysis()``."""
+def program_memory(engine, sched, fam, size: int) -> List[str]:
+    """The compiled prefill's (of a request of ``size``) and decode
+    step's ``memory_analysis()``."""
     import jax.numpy as jnp
     n = sched.n_slots
-    mel = jnp.zeros((1, frames, engine.cfg.n_mels), jnp.float32)
-    progs = {"prefill": (engine._prefill_jit, (engine._serve_params, mel)),
+    progs = {"prefill": (engine._prefill_jit, (
+                 engine._serve_params, fam.program_args(engine.cfg, size))),
              "step": (engine._step_jit, (
                  engine._serve_params, jnp.zeros((n, 1), jnp.int32),
                  jnp.zeros((n,), bool), sched.pool.state))}

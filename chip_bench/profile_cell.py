@@ -109,17 +109,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     cell = spec.resolve(spec.load_spec(), args.workload)
     harness.enable_compile_cache()
-    conf = cell["config"]
-    n_slots = conf["deployment"]["n_slots"]
-    cfg, engine = harness.build(cell, args.seed)
-    sched = engine.scheduler(n_slots=n_slots, n_frames=cfg.encoder_ctx)
-    tr = traffic.Traffic(cell["traffic"], args.seed, args.window, n_slots,
-                         cfg.n_mels)
-    warm = harness.Client(sched, traced=False)
-    warm.submit(tr.warm_request(), None)
-    while sched.n_queued or sched.n_active:
-        warm.cycle()
-    sched.finished.clear()
+    n_slots = cell["config"]["deployment"]["n_slots"]
+    fam, cfg, engine, sched, tr, firsts = harness.start(
+        cell, args.seed, args.window)
     total = args.window + args.stretch
     if tr.kind == "backlog":
         tr.prepare(traffic.expected_backlog_requests(
@@ -128,12 +120,12 @@ def main(argv=None) -> int:
     else:
         tr.prepare()
         more = traffic.Traffic(cell["traffic"], args.seed + 2**40,
-                               args.stretch, n_slots, cfg.n_mels)
+                               args.stretch, n_slots, tr.payload)
         more.prepare()
     jax.block_until_ready(sched.pool.state)
     setup_s = time.perf_counter() - t_start
 
-    d = harness.Client(sched, traced=True)
+    d = harness.Client(sched, firsts, fam.submit_kwargs(cfg), traced=True)
     with harness.GcPauses() as gcs:
         if tr.kind == "backlog":
             t0, t1, nxt = harness.closed_loop(d, tr, args.window)
@@ -184,7 +176,8 @@ def main(argv=None) -> int:
         "program": prog,
         "idle_split": (program_spans.idle_split(prog, per)
                        if prog else None),
-        "scopes_s": program_spans.scopes_s(path, lo_ns, hi_ns),
+        "scopes_s": program_spans.scopes_s(path, lo_ns, hi_ns,
+                                           fam.SCOPES),
         "long_calls": _under_long_calls(pd, raw, args.long_ms * 1e6,
                                         lo_ns, hi_ns),
     }

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import functools
 from collections import defaultdict
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from chip_bench import trace_reduce
 
@@ -164,12 +164,6 @@ def idle_split(reduced: dict, per: Dict[str, str]) -> Dict[str, dict]:
 # class declared here from the field numbers of the profiler's
 # ``xplane.proto`` (only the fields read below).
 
-#: the scopes ``models/whisper.py`` gives its modules
-SCOPES = ("encoder/conv", "encoder/self_attn", "encoder/ffn", "cross_kv",
-          "decoder/self_attn", "decoder/cross_attn", "decoder/ffn",
-          "readout")
-
-
 @functools.lru_cache(maxsize=None)
 def _xspace_class():
     from google.protobuf import descriptor_pb2, descriptor_pool
@@ -222,11 +216,12 @@ def _xspace_class():
         pool.FindMessageTypeByName("chip_bench_xplane.XSpace"))
 
 
-def scope_of(path: str) -> Optional[str]:
-    """The model scope an op_name path lies in: the first of ``SCOPES``
-    whose parts appear in order among the path's parts."""
+def scope_of(path: str, scopes: Sequence[str]) -> Optional[str]:
+    """The model scope an op_name path lies in: the first of ``scopes``
+    (a family module's ``SCOPES``) whose parts appear in order among the
+    path's parts."""
     parts = path.split("/")
-    for scope in SCOPES:
+    for scope in scopes:
         want = scope.split("/")
         i = 0
         for p in parts:
@@ -237,9 +232,11 @@ def scope_of(path: str) -> Optional[str]:
     return None
 
 
-def scopes_s(path: str, lo: float, hi: float) -> Dict[str, float]:
-    """Device seconds per model scope, over the first device's leaf
-    ``XLA Ops`` inside [lo, hi] (ns); ops in no scope go under ``other``.
+def scopes_s(path: str, lo: float, hi: float,
+             scopes: Sequence[str]) -> Dict[str, float]:
+    """Device seconds per model scope of ``scopes``, over the first
+    device's leaf ``XLA Ops`` inside [lo, hi] (ns); ops in no scope go
+    under ``other``.
     Empty where the trace's ops carry no ``tf_op`` path."""
     space = _xspace_class()()
     with open(path, "rb") as f:
@@ -256,7 +253,8 @@ def scopes_s(path: str, lo: float, hi: float) -> Dict[str, float]:
             if stat_names.get(s.metadata_id) == "tf_op":
                 tf_op = s.str_value or stat_names.get(s.ref_value)
         op_scope[e.key] = (e.value.name,
-                           scope_of(tf_op) if tf_op else None, tf_op)
+                           scope_of(tf_op, scopes) if tf_op else None,
+                           tf_op)
     if not any(v[2] for v in op_scope.values()):
         return {}
     out: Dict[str, float] = defaultdict(float)

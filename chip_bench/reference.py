@@ -1,24 +1,9 @@
-"""Plain float32 reference of the Whisper encoder-decoder as the repo
-defines it (``repro.models.whisper``), written from that definition and
-importing nothing of the program.
+"""What every family's float32 reference shares: the layer helpers, the
+control's rounding, and the gap a served token is judged by.
 
-What it computes, in float32 at the ``highest`` matmul precision:
-
-* conv frontend stub: one linear (with bias) on the mel frames, tanh GELU,
-  plus the fixed sinusoidal table;
-* encoder: pre-LayerNorm blocks of non-causal attention (q/k/v with bias,
-  output projection without) and a bias-free GELU MLP, then a final
-  LayerNorm;
-* cross-KV: each decoder layer's k/v projection of the encoder output;
-* decoder, teacher-forced: token embedding plus the learned positional
-  row, pre-LayerNorm blocks of causal self-attention, cross-attention and
-  the MLP, a final LayerNorm, and the tied vocabulary readout.
-
-Departures from published Whisper, shared with the program: the conv stub,
-the k-projection bias (zero in the checkpoints, random here: it shifts
-every key's score by the same amount, so softmax ignores it), bias-free
-MLPs and output projections, and the tanh form of GELU.
-
+Each family module (``families/<name>.py``) holds the plain float32
+forward of its model, written from the program's definition and importing
+nothing of the program; it runs at the ``highest`` matmul precision.
 ``quantize_blocks`` makes the control: the same forward on weights rounded
 to a lower precision in blocks of 32 along K, as Q8_0 rounds them.
 """
@@ -30,86 +15,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-EPS = 1e-5
 BLOCK = 32
 
 
-def _ln(p, x):
-    mu = jnp.mean(x, -1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + EPS) * p["scale"] + p["bias"]
-
-
-def _lin(p, x):
+def linear(p, x):
+    """``x @ w.T``, plus the bias where the layer has one."""
     y = x @ p["w"].T
     return y + p["b"] if "b" in p else y
 
 
-def _attend(q, k, v, heads, mask=None):
-    """q (S, H*D), k/v (T, H*D) -> (S, H*D)."""
-    s, t = q.shape[0], k.shape[0]
-    q = q.reshape(s, heads, -1)
-    k = k.reshape(t, heads, -1)
-    v = v.reshape(t, heads, -1)
-    logits = jnp.einsum("shd,thd->hst", q, k) * q.shape[-1] ** -0.5
-    if mask is not None:
-        logits = jnp.where(mask[None], logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("hst,thd->shd", probs, v).reshape(s, -1)
-
-
-def _mlp(p, x):
-    return jax.nn.gelu(x @ p["up"]["w"].T) @ p["down"]["w"].T
-
-
-def _layer(tree, i):
+def layer(tree, i):
+    """Layer ``i`` of a stack whose leaves carry the layers on axis 0."""
     return jax.tree_util.tree_map(lambda a: a[i], tree)
-
-
-def encode(p, mel, heads: int):
-    """mel (F, n_mels) -> encoder output (F, d)."""
-    x = jax.nn.gelu(_lin(p["frontend"], mel))
-    x = x + p["enc_pos"]["table"][:mel.shape[0]]
-    n = p["enc_blocks"]["norm1"]["scale"].shape[0]
-    for i in range(n):
-        b = _layer(p["enc_blocks"], i)
-        h = _ln(b["norm1"], x)
-        a = b["attn"]
-        x = x + _lin(a["o"], _attend(_lin(a["q"], h), _lin(a["k"], h),
-                                     _lin(a["v"], h), heads))
-        x = x + _mlp(b["ffn"], _ln(b["norm2"], x))
-    return _ln(p["enc_norm"], x)
-
-
-def decode(p, memory, tokens, heads: int):
-    """Teacher-forced decoder: tokens (L,) i32 -> logits (L, padded vocab);
-    row j is the next-token distribution after tokens[:j+1]."""
-    n_tok = tokens.shape[0]
-    x = p["embed"]["table"][tokens] + p["dec_pos"]["table"][:n_tok]
-    causal = jnp.tril(jnp.ones((n_tok, n_tok), bool))
-    n = p["dec_blocks"]["norm1"]["scale"].shape[0]
-    for i in range(n):
-        b = _layer(p["dec_blocks"], i)
-        h = _ln(b["norm1"], x)
-        a = b["self_attn"]
-        x = x + _lin(a["o"], _attend(_lin(a["q"], h), _lin(a["k"], h),
-                                     _lin(a["v"], h), heads, causal))
-        h = _ln(b["norm_x"], x)
-        c = b["cross_attn"]
-        x = x + _lin(c["o"], _attend(_lin(c["q"], h), _lin(c["k"], memory),
-                                     _lin(c["v"], memory), heads))
-        x = x + _mlp(b["ffn"], _ln(b["norm2"], x))
-    return _ln(p["dec_norm"], x) @ p["embed"]["table"].T
-
-
-@functools.partial(jax.jit, static_argnames=("heads",))
-def logits(p, mel, tokens, heads: int):
-    """Reference logits of one request: its padded mel (F, n_mels) and its
-    decoder input (SOT then the served tokens, padded to a fixed length)."""
-    with jax.default_matmul_precision("highest"):
-        p = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), p)
-        return decode(p, encode(p, mel.astype(jnp.float32), heads),
-                      tokens, heads)
 
 
 def is_matrix(path) -> bool:

@@ -27,8 +27,8 @@ def main() -> int:
     out = sys.argv[1]
     harness.enable_compile_cache()
     cell = spec.resolve(spec.load_spec(), "whisper-tiny-q8.longform")
-    cfg, engine = harness.build(cell, 5)
-    sched = engine.scheduler(n_slots=2, n_frames=cfg.encoder_ctx)
+    fam, cfg, engine = harness.build(cell, 5)
+    sched = engine.scheduler(n_slots=2, **fam.scheduler_kwargs(cfg))
     mel = np.random.default_rng(5).standard_normal(
         (cfg.encoder_ctx, cfg.n_mels)).astype(np.float32)
     for _ in range(2):                      # warm: compile everything

@@ -9,6 +9,7 @@ import os
 
 from chip_bench import spec
 
+WHISPER = spec.load_family("whisper")
 SMOKE = {"dtype": "bfloat16", "param_dtype": "bfloat16",
          "scan_layers": True, "encoder_ctx": 64, "vocab_size": 500,
          "vocab_pad": 12}

@@ -12,7 +12,7 @@ import os
 
 import pytest
 
-from chip_bench import program_spans, trace_reduce
+from chip_bench import program_spans, spec, trace_reduce
 
 DATA = os.path.join(os.path.dirname(os.path.dirname(__file__)), "testdata")
 CHILDREN = program_spans.CHILDREN
@@ -94,7 +94,8 @@ def test_scopes_split_the_device_time(old, new):
         raw = program_spans.load(path)
         lo, hi = [(a, b) for a, b, n in raw["host"]
                   if n == trace_reduce.STRETCH][0]
-        scopes = program_spans.scopes_s(path, lo, hi)
+        scopes = program_spans.scopes_s(path, lo, hi,
+                                        spec.load_family("whisper").SCOPES)
         busy = trace_reduce.reduce(raw)["busy_s"]
         # leaf ops sum to the busy time, less the odd overlap
         assert sum(scopes.values()) == pytest.approx(busy, rel=0.02)
@@ -143,4 +144,5 @@ def test_overlap_of_sorted_interval_lists(a, b, want):
     ("jit(step_fn)/while/body/closed_call/reshape", None),
 ])
 def test_scope_of_an_op_name_path(path, want):
-    assert program_spans.scope_of(path) == want
+    assert program_spans.scope_of(
+        path, spec.load_family("whisper").SCOPES) == want

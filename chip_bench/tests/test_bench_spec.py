@@ -3,6 +3,7 @@ names, units, metric wiring, and that new cells, mixes and metrics are
 found by name from new files alone."""
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -14,7 +15,9 @@ import sys
 import numpy as np
 import pytest
 
+from bench_cells import WHISPER
 from chip_bench import spec, traffic
+from repro.configs.registry import get_config
 
 ROOT = spec.ROOT
 BENCH = spec.BENCH_DIR
@@ -22,6 +25,8 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SPEC = spec.load_spec()
 CELLS = [w["name"] for w in SPEC["workloads"]]
+#: 80-channel mel requests, as whisper-tiny and whisper-small take them
+MEL80 = functools.partial(WHISPER.payload, cfg=get_config("whisper-tiny"))
 
 
 def test_top_level_keys_and_limits():
@@ -149,25 +154,29 @@ def test_new_cell_mix_and_metric_are_found_from_new_files(tmp_path):
 def test_same_seed_same_traffic_other_seed_same_work():
     mix = json.load(open(os.path.join(BENCH, "traffic", "commands.json")))
     mix["rate_per_s"] = 40.0
-    a = traffic.Traffic(dict(mix), 2**31 + 9, 5.0, 8, 80)
-    b = traffic.Traffic(dict(mix), 2**31 + 9, 5.0, 8, 80)
-    c = traffic.Traffic(dict(mix), 17, 5.0, 8, 80)
-    key = lambda t: [(r.due, r.frames, r.max_new) for r in t.requests]
+    a = traffic.Traffic(dict(mix), 2**31 + 9, 5.0, 8, MEL80)
+    b = traffic.Traffic(dict(mix), 2**31 + 9, 5.0, 8, MEL80)
+    c = traffic.Traffic(dict(mix), 17, 5.0, 8, MEL80)
+    key = lambda t: [(r.due, r.size, r.max_new) for r in t.requests]
     assert key(a) == key(b) and key(a) != key(c)
     assert sorted(r.max_new for r in a.requests) == \
         sorted(r.max_new for r in c.requests)
-    assert sorted(r.frames for r in a.requests) == \
-        sorted(r.frames for r in c.requests)
+    assert sorted(r.size for r in a.requests) == \
+        sorted(r.size for r in c.requests)
     assert len(a.requests) == 200
     a.prepare()
     b.prepare()
-    assert all((x.mel == y.mel).all() for x, y in zip(a.requests, b.requests))
-    assert not (a.requests[0].mel[:10] == a.requests[1].mel[:10]).all()
-    assert all(1 <= r.frames / 50 <= 5 for r in a.requests)
+    assert all((x.payload == y.payload).all()
+               for x, y in zip(a.requests, b.requests))
+    assert not (a.requests[0].payload[:10] ==
+                a.requests[1].payload[:10]).all()
+    assert all(1 <= r.size / 50 <= 5 for r in a.requests)
+    assert all(r.payload.shape == (r.size, 80) for r in a.requests)
+    assert a.size_values() == sorted({r.size for r in a.requests})
 
     lf = json.load(open(os.path.join(BENCH, "traffic", "longform.json")))
-    d = traffic.Traffic(lf, 5, 5.0, 4, 80)
-    e = traffic.Traffic(lf, 5, 5.0, 4, 80)
+    d = traffic.Traffic(lf, 5, 5.0, 4, MEL80)
+    e = traffic.Traffic(lf, 5, 5.0, 4, MEL80)
     d.prepare(300)
     e.prepare(300)
     assert [r.max_new for r in d.requests] == [r.max_new for r in e.requests]
@@ -194,7 +203,7 @@ def test_open_loop_arrivals_are_poisson():
     1), and a block of 8 gaps varies as 8 independent gaps do."""
     mix = json.load(open(os.path.join(BENCH, "traffic", "commands.json")))
     mix["rate_per_s"] = 16.0
-    t = traffic.Traffic(mix, 2**31 + 3, 200.0, 8, 80)
+    t = traffic.Traffic(mix, 2**31 + 3, 200.0, 8, MEL80)
     due = np.array([r.due for r in t.requests])
     assert len(due) == 3200 and (np.diff(due) >= 0).all()
     assert 0.0 <= due[0] and due[-1] < 200.0
@@ -204,3 +213,32 @@ def test_open_loop_arrivals_are_poisson():
     assert 0.9 < gaps.std() / gaps.mean() < 1.1
     blocks = gaps[:len(gaps) // 8 * 8].reshape(-1, 8).sum(1)
     assert 0.75 < blocks.var() / (8 * gaps.var()) < 1.25
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_resolve_finds_the_family(cell):
+    cell = spec.resolve(SPEC, cell)
+    assert cell["family"] == "whisper"
+    fam = spec.load_family(cell["family"])
+    assert fam is spec.load_family("whisper")       # loaded once
+    assert callable(fam.reference_logits)
+
+
+def test_unknown_family_raises(tmp_path):
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "chip_bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "testdata",
+                                                  "tests"))
+    conf = json.loads((root / "chip_bench/configs/whisper-tiny-q8.json")
+                      .read_text())
+    (root / "chip_bench/configs/other.json").write_text(
+        json.dumps(dict(conf, name="other", family="wav2vec")))
+    shutil.copy(root / "chip_bench/workloads/whisper-tiny-q8.longform.json",
+                root / "chip_bench/workloads/other.longform.json")
+    s = json.loads(json.dumps(SPEC))
+    s["configs"].append(dict(s["configs"][0], name="other",
+                             file="chip_bench/configs/other.json"))
+    s["workloads"].append(dict(s["workloads"][0], name="other.longform",
+                               config="other"))
+    with pytest.raises(KeyError, match=r"wav2vec.*\['decoder_lm', 'whisper'\]"):
+        spec.resolve(s, "other.longform", bench_dir=str(root / "chip_bench"))

@@ -7,6 +7,7 @@ import re
 import numpy as np
 import pytest
 
+from bench_cells import WHISPER
 from chip_bench import harness, spec, stats, work
 from chip_bench.peaks import peaks
 from repro.configs.registry import get_config
@@ -62,11 +63,14 @@ def test_token_and_prefill_flops_hand_count():
              + 4 * f * d                      # cross attention over 1500
              + 4 * d * ff)                    # MLP
     assert layer == 6_448_128
-    assert work.token_flops(TINY, 10, f) == 4 * layer + 2 * d * v
-    assert work.token_flops(TINY, 10, f) == 65_624_832
+    assert WHISPER.token_flops(TINY, 10, f) == 4 * layer + 2 * d * v
+    assert WHISPER.token_flops(TINY, 10, f) == 65_624_832
     enc = 2 * f * d * 4 * d + 4 * f * f * d + 4 * f * d * ff
-    assert work.prefill_flops(TINY, f) == (2 * f * 80 * d + 4 * enc
-                                           + 4 * 2 * f * d * 2 * d)
+    assert WHISPER.prefill_flops(TINY, f) == (2 * f * 80 * d + 4 * enc
+                                              + 4 * 2 * f * d * 2 * d)
+    # a clip is padded to the window: its FLOPs are the window's
+    assert WHISPER.prefill_flops(TINY, 250) == WHISPER.prefill_flops(TINY, f)
+    assert WHISPER.token_flops(TINY, 10, 250) == 65_624_832
 
 
 def test_no_metric_reads_the_offload_ledger():
@@ -93,9 +97,9 @@ def test_unknown_device_has_no_peaks():
 
 
 def _run(requests, t0=100.0, t1=110.0):
-    return harness.Run(cell={"chips": 1}, cfg=TINY, n_slots=4, frames=1500,
-                       t0=t0, t1=t1, requests=requests, steps=[], admits=[],
-                       peaks={})
+    return harness.Run(cell={"chips": 1}, cfg=TINY, family=WHISPER,
+                       n_slots=4, t0=t0, t1=t1, requests=requests, steps=[],
+                       admits=[], peaks={})
 
 
 def test_p95_counts_every_request_from_its_due_time():

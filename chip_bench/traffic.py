@@ -12,28 +12,35 @@ arrival rate, for one). Two kinds:
              window are n independent uniform times), so every seed
              offers the same number of requests.
 
-Every size (clip, output length) is a fixed table of quantiles that the
+Every size (input, output length) is a fixed table of quantiles that the
 seed puts in a random order: two seeds give the same sizes in another
-order, so the seed moves the work as little as it can. Every request's
-audio is its own: Gaussian log-mel frames from the seed and the
-request's index, made before the window opens (``Traffic.prepare``).
+order, so the seed moves the work as little as it can. A request's input
+size comes from the mix's own keys: a ``prompt_tokens`` distribution of
+token counts, or audio clips (a fixed ``frames``, or ``clip_seconds`` x
+``frames_per_second``). Every request's input is its own, made by the
+model family's ``payload`` from the seed and the request's index before
+the window opens (``Traffic.prepare``).
 """
 from __future__ import annotations
 
 import math
 import statistics
 from dataclasses import dataclass
-from typing import List, Optional
+from typing import Callable, List, Optional
 
 import numpy as np
+
+#: ``payload(seed, index, size)``: one request's input
+Payload = Callable[[int, int, int], np.ndarray]
+
 
 @dataclass
 class Request:
     index: int
-    frames: int
+    size: int
     max_new: int
     due: Optional[float] = None     # seconds after the window opens
-    mel: Optional[np.ndarray] = None
+    payload: Optional[np.ndarray] = None
 
 
 def quantiles(dist: dict, n: int) -> np.ndarray:
@@ -55,25 +62,26 @@ def quantiles(dist: dict, n: int) -> np.ndarray:
 
 class Traffic:
     """The requests of one run: ``params`` is the mix merged with the
-    cell's overrides; ``n_mels`` the model's mel channels."""
+    cell's overrides; ``payload`` the model family's maker of one
+    request's input."""
 
     def __init__(self, params: dict, seed: int, seconds: float,
-                 n_slots: int, n_mels: int):
+                 n_slots: int, payload: Payload):
         self.p = params
         self.kind = params["kind"]
         self.seed = seed % 2**64
         self.seconds = seconds
         self.n_slots = n_slots
-        self.n_mels = n_mels
+        self.payload = payload
         self.rng = np.random.default_rng([self.seed, 1])
         self._made = 0
-        self.late = 0              # backlog audio made inside the window
+        self.late = 0              # backlog inputs made inside the window
         if self.kind == "poisson":
             n = max(1, round(params["rate_per_s"] * seconds))
             due = np.sort(self.rng.uniform(0.0, seconds, n))
-            frames = self._frames(n)
+            sizes = self._sizes(n)
             outs = self.rng.permutation(quantiles(params["out_tokens"], n))
-            self.requests = [Request(i, int(frames[i]), int(outs[i]),
+            self.requests = [Request(i, int(sizes[i]), int(outs[i]),
                                      float(due[i])) for i in range(n)]
         elif self.kind == "backlog":
             self.requests = []
@@ -81,36 +89,52 @@ class Traffic:
         else:
             raise ValueError(f"unknown traffic kind {self.kind!r}")
 
-    def _frames(self, n: int) -> np.ndarray:
+    def _size_table(self, n: int) -> Optional[np.ndarray]:
+        """``n`` input sizes at the mix's quantiles, or None where every
+        request has the mix's fixed ``frames``."""
+        if "prompt_tokens" in self.p:
+            return quantiles(self.p["prompt_tokens"], n)
         clip = self.p.get("clip_seconds")
         if clip is None:
-            return np.full(n, self.p["frames"])
-        secs = self.rng.permutation(quantiles(clip, n))
-        return np.rint(secs * self.p["frames_per_second"]).astype(int)
+            return None
+        return np.rint(quantiles(clip, n)
+                       * self.p["frames_per_second"]).astype(int)
 
-    def _mel(self, index: int, frames: int) -> np.ndarray:
-        rng = np.random.default_rng([self.seed, 2, index])
-        return rng.standard_normal((frames, self.n_mels), np.float32)
+    def _sizes(self, n: int) -> np.ndarray:
+        """``n`` input sizes in a seeded order (a fixed size draws
+        nothing)."""
+        table = self._size_table(n)
+        if table is None:
+            return np.full(n, self.p["frames"])
+        return self.rng.permutation(table)
+
+    def size_values(self) -> List[int]:
+        """Every input size the run's requests can have, in order."""
+        if self.kind == "poisson":
+            return sorted({r.size for r in self.requests})
+        table = self._size_table(self.p["table"])
+        return [self.p["frames"]] if table is None else \
+            sorted({int(x) for x in table})
 
     def _backlog_block(self) -> List[Request]:
         """The next ``table`` backlog requests: the length table in a
         fresh seeded order."""
         n = self.p["table"]
         outs = self.rng.permutation(quantiles(self.p["out_tokens"], n))
-        frames = self._frames(n)
+        sizes = self._sizes(n)
         base = len(self.requests)
-        return [Request(base + i, int(frames[i]), int(outs[i]))
+        return [Request(base + i, int(sizes[i]), int(outs[i]))
                 for i in range(n)]
 
     def prepare(self, n: Optional[int] = None) -> None:
-        """Make the audio of the first ``n`` requests (all of an open-loop
+        """Make the inputs of the first ``n`` requests (all of an open-loop
         mix) ahead of the window."""
         if self.kind == "backlog":
             while len(self.requests) < n:
                 self.requests.extend(self._backlog_block())
         n = len(self.requests) if n is None else n
         for r in self.requests[self._made:n]:
-            r.mel = self._mel(r.index, r.frames)
+            r.payload = self.payload(self.seed, r.index, r.size)
         self._made = max(self._made, n)
 
     def request(self, i: int) -> Request:
@@ -121,10 +145,11 @@ class Traffic:
             self.prepare(i + 1)
         return self.requests[i]
 
-    def warm_request(self) -> Request:
-        """A request outside the measured sequence, for warming up."""
-        frames = int(self.p.get("frames", 50))
-        return Request(-1, frames, 2, mel=self._mel(2**32, frames))
+    def warm_request(self, size: int) -> Request:
+        """A request of ``size`` outside the measured sequence, for
+        warming up."""
+        return Request(-1, size, 2, payload=self.payload(self.seed, 2**32,
+                                                         size))
 
 
 def expected_backlog_requests(tokens_per_s: float, seconds: float,

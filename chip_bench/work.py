@@ -9,11 +9,8 @@ plan gives it, M and N padded as the backend pads them), read from the
 custom call's HLO text in the device trace, so a call that a later change
 reshapes is counted at its new shape.
 
-Model: the FLOPs the Whisper forward needs, whatever runs it: a prefill
-(encoder over the padded window, plus every decoder layer's cross-K/V
-projection) and one decoded token at a given context (self-attention over
-``ctx`` positions, cross-attention over the window, the MLP and the
-vocabulary readout). Free decode slots and padding are not model work.
+A model's FLOPs are its family's (``families/<name>.py``: a prefill and
+one decoded token); free decode slots are not model work.
 
 No number here comes from the offload ledger: it counts a scanned layer
 stack once per program, not once per layer.
@@ -65,30 +62,3 @@ def kernel_call(hlo: str) -> Optional[Tuple[int, int]]:
     k = x[1][-1]
     ops = 2 * math.prod(odims) * k
     return ops, sum(_nbytes(s) for s in ins[:3]) + _nbytes(out[0])
-
-
-def prefill_flops(cfg, frames: int) -> int:
-    """Encoder over ``frames`` positions plus each decoder layer's
-    cross-K/V projection of its output."""
-    d, f = cfg.d_model, frames
-    qd = cfg.num_heads * cfg.head_dim
-    kvd = cfg.num_kv_heads * cfg.head_dim
-    front = 2 * f * cfg.n_mels * d
-    layer = (2 * f * d * (qd + 2 * kvd) + 2 * f * qd * d     # q, k, v, o
-             + 2 * 2 * f * f * qd                            # scores, PV
-             + 2 * 2 * f * d * cfg.d_ff)                     # MLP
-    cross = 2 * f * d * 2 * kvd
-    return front + cfg.num_encoder_layers * layer + cfg.num_layers * cross
-
-
-def token_flops(cfg, ctx: int, frames: int) -> int:
-    """One decoded token whose self-attention sees ``ctx`` positions."""
-    d = cfg.d_model
-    qd = cfg.num_heads * cfg.head_dim
-    kvd = cfg.num_kv_heads * cfg.head_dim
-    layer = (2 * d * (qd + 2 * kvd) + 2 * qd * d              # self q,k,v,o
-             + 2 * 2 * ctx * qd                               # self attn
-             + 2 * d * qd + 2 * qd * d                        # cross q, o
-             + 2 * 2 * frames * qd                            # cross attn
-             + 2 * 2 * d * cfg.d_ff)                          # MLP
-    return cfg.num_layers * layer + 2 * d * cfg.vocab_size
